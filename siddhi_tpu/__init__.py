@@ -26,16 +26,8 @@ def _jax_backend_initialized() -> bool:
     """True when the embedding application already initialized a JAX
     backend before importing siddhi_tpu — XLA_FLAGS set below are then
     inert (XLA parsed them at backend init)."""
-    xb = getattr(_sys.modules.get("jax._src.xla_bridge"), "__dict__", None)
-    if xb is None:
-        return False
-    try:
-        fn = xb.get("backends_are_initialized")
-        if fn is not None:
-            return bool(fn())
-    except Exception:  # pragma: no cover — version-dependent introspection
-        pass
-    return bool(xb.get("_backends"))
+    xb = _sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and bool(xb.backends_are_initialized())
 
 
 _FLAG = "--xla_cpu_copy_insertion_use_region_analysis"

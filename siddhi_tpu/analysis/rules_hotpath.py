@@ -3,7 +3,7 @@
 Inside a jit-compiled step, a ``float()``/``int()``/``bool()``/
 ``.item()``/``np.asarray()`` on a traced value either fails at trace
 time or — worse, on concrete leaves that escaped tracing — forces a
-synchronous device->host transfer per batch, the exact per-pull tunnel
+synchronous device->host transfer per batch, the exact per-pull
 round trip the CompletionPump exists to amortize. The rule scans
 ``core/query``, ``core/join`` and ``parallel`` for functions that are
 jit-compiled (decorated with ``jax.jit``/``partial(jax.jit, ...)``,

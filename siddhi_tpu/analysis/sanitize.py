@@ -99,16 +99,12 @@ def _install_pull_guard() -> None:
     jax transfer guard additionally covers implicit ``np.asarray``."""
     if _PATCHED[0]:
         return
-    try:
-        # class import only — materializing an array here would
-        # initialize the backend at siddhi_tpu import (the R1 bug class)
-        from jax._src.array import ArrayImpl as cls
-    except ImportError:         # pragma: no cover — jax layout change
-        return
+    # class import only — materializing an array here would initialize
+    # the backend at siddhi_tpu import (the R1 bug class)
+    from jax._src.array import ArrayImpl as cls
+
     for name in ("__float__", "__int__", "__bool__", "item"):
-        orig = getattr(cls, name, None)
-        if orig is None:        # pragma: no cover — jaxlib layout change
-            continue
+        orig = getattr(cls, name)
 
         def guard(self, *args, __orig=orig, __name=name, **kw):
             # enabled() re-checked per call: the patch is process-wide
@@ -121,10 +117,7 @@ def _install_pull_guard() -> None:
                     f"path read in analysis.sanitize.allowed_pull())")
             return __orig(self, *args, **kw)
 
-        try:
-            setattr(cls, name, guard)
-        except TypeError:       # pragma: no cover — sealed type
-            return
+        setattr(cls, name, guard)
     _PATCHED[0] = True
 
 

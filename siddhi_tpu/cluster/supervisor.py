@@ -32,11 +32,12 @@ from siddhi_tpu.analysis.locks import make_lock
 
 def _child_env() -> dict:
     """Workers are plain-CPU engines: strip inherited accelerator state
-    (a TPU lock or an XLA flag meant for the router must not leak), and
-    make the package importable from any cwd (the tree is not
-    pip-installed)."""
+    (a TPU lock or an XLA flag meant for the router must not leak; where
+    the compile cache was placed from outside is kept), and make the
+    package importable from any cwd (the tree is not pip-installed)."""
     env = {k: v for k, v in os.environ.items()
-           if not k.startswith(("JAX_", "XLA_"))}
+           if not k.startswith(("JAX_", "XLA_"))
+           or k == "JAX_COMPILATION_CACHE_DIR"}
     env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))

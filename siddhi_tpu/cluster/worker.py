@@ -241,11 +241,9 @@ def _serve(args) -> int:
 
 
 def main(argv=None) -> int:
-    import gc
-
-    gc.disable()        # GC during jax tracing segfaults this build
+    # a cluster worker runs its engine on the CPU backend today: a chip
+    # belongs to one process, and N workers on one host cannot share it
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "")
 
     def _die(tp, v, tb):
         # an uncaught failure must EXIT (and be seen), never park the
@@ -257,6 +255,9 @@ def main(argv=None) -> int:
         os._exit(3)
 
     sys.excepthook = _die
+    from siddhi_tpu.core.util.compile_cache import place_compile_cache
+
+    place_compile_cache()
     args = _parse_args(argv)
     try:
         return _serve(args)

@@ -314,10 +314,12 @@ class DeviceJoinEngine:
         ac = runtime.app_context
         cfg_p = int(getattr(ac, "join_partitions", 0) or 0)
         if cfg_p <= 0:
-            # auto: partition pruning pays where gathers are wide and
-            # cheap (accelerators); the CPU fallback keeps the fused
+            # auto: partition pruning is meant to pay where gathers are
+            # wide and cheap (accelerators; P=8 there is NOT measured on
+            # a chip yet — PERF.md); the CPU backend keeps the fused
             # full-surface probe, which holds legacy throughput while
-            # still buying pipeline/fusion/mesh eligibility (PERF.md)
+            # still buying pipeline/fusion/mesh eligibility. A backend
+            # that cannot initialize raises here.
             import jax
 
             cfg_p = 1 if jax.default_backend() == "cpu" else 8

@@ -8,13 +8,17 @@ consumes, with native dictionary encoding for string attributes (Python
 syncs the app StringDictionary once per NEW unique string, never per
 row).
 
-The shared library builds on first use with the image's g++ and is cached
-next to the source (no pip/pybind11 dependency).
+The shared libraries build on first use with the image's g++ (no
+pip/pybind11 dependency) into a file named after a hash of the source's
+CONTENT, next to the source: a checkout copied with a stale build next
+to a changed source never loads the stale one (mtimes do not survive a
+copy), and the build outputs are ignored by git.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,13 +30,30 @@ from siddhi_tpu.query_api.definitions import AttrType
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "csv_loader.cpp")
-_SO = os.path.join(_HERE, "_csv_loader.so")
 _LOCK = threading.Lock()
 _LIB = None
 _STRDICT_SRC = os.path.join(_HERE, "strdict.cpp")
-_STRDICT_SO = os.path.join(_HERE, "_strdict.so")
 _STRDICT_LIB = None
 _STRDICT_FAILED = False
+
+
+def _built(src: str, *flags: str) -> str:
+    """Path of the shared library for ``src`` as it reads NOW, building
+    it if that exact content (and flag set) was never built here."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(flags).encode())
+    stem = os.path.splitext(os.path.basename(src))[0]
+    so = os.path.join(_HERE, f"_{stem}-{digest.hexdigest()[:16]}.so")
+    if not os.path.exists(so):
+        # build beside, then rename: another process (xdist worker,
+        # cluster worker) may be building or loading the same file
+        tmp = f"{so}.{os.getpid()}.tmp"
+        subprocess.run(
+            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", *flags,
+             src, "-o", tmp],
+            check=True, capture_output=True)
+        os.replace(tmp, so)
+    return so
 
 _TYPE_CODES = {
     AttrType.INT: 0, AttrType.LONG: 0,
@@ -47,13 +68,7 @@ def _lib():
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 _SRC, "-o", _SO],
-                check=True, capture_output=True)
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(_built(_SRC))
         lib.loader_new.restype = ctypes.c_void_p
         lib.loader_free.argtypes = [ctypes.c_void_p]
         lib.loader_dict_size.restype = ctypes.c_int64
@@ -93,15 +108,8 @@ def strdict_lib():
         try:
             import sysconfig
 
-            if (not os.path.exists(_STRDICT_SO)
-                    or os.path.getmtime(_STRDICT_SO)
-                    < os.path.getmtime(_STRDICT_SRC)):
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-I", sysconfig.get_paths()["include"],
-                     _STRDICT_SRC, "-o", _STRDICT_SO],
-                    check=True, capture_output=True)
-            lib = ctypes.PyDLL(_STRDICT_SO)
+            lib = ctypes.PyDLL(_built(
+                _STRDICT_SRC, "-I", sysconfig.get_paths()["include"]))
             lib.strdict_new.restype = ctypes.c_void_p
             lib.strdict_free.argtypes = [ctypes.c_void_p]
             lib.strdict_clear.argtypes = [ctypes.c_void_p]

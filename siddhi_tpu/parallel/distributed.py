@@ -31,32 +31,25 @@ def initialize_cluster(coordinator_address: Optional[str] = None,
     """Join this process into the cluster (``jax.distributed.initialize``);
     with no arguments, cluster-environment auto-detection applies.
 
-    ``max_missing_heartbeats`` (default: jax's 10 x 10 s) bounds how long
-    the coordination service waits before declaring a silent peer dead —
-    at which point it propagates an error that TERMINATES every healthy
-    task. A supervised deployment (``resilience/supervisor.py``) that
-    wants to recover in place rather than be torn down should raise it;
-    the supervisor's own peer monitor and the bounded device pull provide
-    the (much faster) failure detection instead."""
+    ``max_missing_heartbeats`` bounds how long the coordination service
+    waits before declaring a silent peer dead — at which point it
+    propagates an error that TERMINATES every healthy task. It counts
+    missed 10 s heartbeats (jax's default, 10, is its 100 s
+    ``heartbeat_timeout_seconds``). A supervised deployment
+    (``resilience/supervisor.py``) that wants to recover in place rather
+    than be torn down should raise it; the supervisor's own peer monitor
+    and the bounded device pull provide the (much faster) failure
+    detection instead."""
     import jax
 
-    if max_missing_heartbeats is None:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-        return
-    from jax._src import distributed as _dist
-
-    # the public wrapper does not expose the heartbeat knobs; the state
-    # object underneath it does
-    _dist.global_state.initialize(
+    kwargs = {}
+    if max_missing_heartbeats is not None:
+        kwargs["heartbeat_timeout_seconds"] = 10 * int(max_missing_heartbeats)
+    jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
-        service_max_missing_heartbeats=max_missing_heartbeats,
-        client_max_missing_heartbeats=max_missing_heartbeats,
+        **kwargs,
     )
 
 

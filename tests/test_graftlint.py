@@ -89,7 +89,8 @@ def test_fixture_findings_are_single_rule():
 def test_clean_tree_zero_findings():
     """The acceptance bar: the repaired production tree lints clean."""
     modules = load_modules(
-        ("siddhi_tpu", "tools", "bench.py", "__graft_entry__.py"), REPO)
+        ("siddhi_tpu", "tools", "bench.py", "chip_smoke.py",
+         "__graft_entry__.py"), REPO)
     findings = run_lint(modules)
     assert not findings, "\n".join(f.format() for f in findings)
 

@@ -48,16 +48,13 @@ SEG_C = [(3000 + i * 50, f"P{i % 4}", float((i * 2) % 7)) for i in range(4)]
 # ALIVE, so the death is a detected TRANSITION, not a never-seen peer.
 # Process 0's supervisor then loses the heartbeat and drives recovery.
 _WORKER = textwrap.dedent("""
-    import gc
-    gc.disable()      # GC during jax tracing segfaults this build
     import json
     import os
     import sys
     import time
     import traceback
 
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "")
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.getcwd())
 
     (coord, pid, flag, store_dir, my_port, peer_port) = (
         sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4],

@@ -1,0 +1,268 @@
+"""What the TPU's compiler says about the main steps — asked here, with
+no chip attached (on-chip-measurement guide, section 2, third rehearsal).
+
+A v5e:2x2 topology is DESCRIBED inside a module-scoped fixture (never at
+import: only one process may load libtpu, and every xdist worker imports
+this file). Each test drives the real app through the normal entry points
+on the CPU backend once, records the exact ``(state, cols, now)`` the
+engine hands its jitted step, and lowers that same jitted callable for a
+described chip. Nothing runs on a TPU here: a compile that passes is not
+a chip run.
+
+All such compiles live in this ONE file, so one worker holds the library.
+"""
+
+import time
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from siddhi_tpu import SiddhiManager
+
+_STOCK = """
+@app:precision('{precision}')
+define stream StockStream (symbol string, price float, volume long);
+{head}
+  @info(name = 'bench')
+  from StockStream#window.length({W})
+  select symbol, avg(price) as avgPrice, sum(volume) as totalVolume
+  {group}
+  insert into OutStream;
+{tail}
+"""
+
+_PATTERN = """
+@app:playback
+define stream AStream (k string, v double);
+define stream BStream (k string, v double);
+partition with (k of AStream, k of BStream)
+begin
+  @info(name = 'nfa')
+  from every e1=AStream -> e2=BStream[e2.v > e1.v] within 5 sec
+  select e1.v as v1, e2.v as v2
+  insert into MatchStream;
+end;
+"""
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spy_steps(q):
+    """Record every ``step(state, cols, now)`` the runtime dispatches:
+    the jitted callable and the avals of its arguments."""
+    seen = []
+    finish = q._finish_device_batch
+
+    def spying_finish(step, cols, overflow_msg):
+        def spy(*args):
+            seen.append((step, _avals(args)))
+            return step(*args)
+
+        return finish(spy, cols, overflow_msg)
+
+    q._finish_device_batch = spying_finish
+    return seen
+
+
+def _avals(args):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.result_type(x)), args)
+
+
+class _SpyingSteps(dict):
+    """``NFAQueryRuntime._steps`` stand-in: remembers, per (stream,
+    generic) key, the jitted step and the avals of its last call."""
+
+    def __init__(self):
+        super().__init__()
+        self.seen = {}
+
+    def __setitem__(self, key, step):
+        def spy(*args):
+            self.seen[key] = (step, _avals(args))
+            return step(*args)
+
+        super().__setitem__(key, spy)
+
+
+def _compile_for(sharding, step, avals):
+    """Lower the engine's own jitted step for a described device."""
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        avals)
+    t0 = time.perf_counter()
+    compiled = step.lower(*args).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _report(name, compiled, seconds):
+    m = compiled.memory_analysis()
+    print(f"\n[tpu-compile] {name}: {seconds:.1f} s, "
+          f"code {m.generated_code_size_in_bytes / 1e6:.1f} MB, "
+          f"args {m.argument_size_in_bytes / 1e6:.1f} MB, "
+          f"out {m.output_size_in_bytes / 1e6:.1f} MB, "
+          f"temp {m.temp_size_in_bytes / 1e6:.1f} MB")
+    assert m.generated_code_size_in_bytes > 0
+    return m
+
+
+def _stock_step(precision, partitioned, window, keys, batch):
+    """The last step dispatch of one warm batch that covers every key at
+    the measured batch shape — what chip_smoke.py compiles in warm-up."""
+    manager = SiddhiManager()
+    rt = manager.create_siddhi_app_runtime(_STOCK.format(
+        precision=precision, W=window,
+        head="partition with (symbol of StockStream)\nbegin"
+        if partitioned else "",
+        group="" if partitioned else "group by symbol",
+        tail="end;" if partitioned else ""))
+    seen = _spy_steps(rt.query_runtimes["bench"])
+    symbols = np.array([f"S{i}" for i in range(keys)], dtype=object)
+    rt.get_input_handler("StockStream").send_columns(
+        {"symbol": symbols[np.arange(batch) % keys],
+         "price": np.ones(batch, np.float32),
+         "volume": np.ones(batch, np.int64)},
+        timestamps=np.zeros(batch, np.int64))
+    manager.shutdown()
+    return seen[-1]
+
+
+# (a) phase A: global length(1000) -> avg/sum group by symbol. "fast" is
+# what the chip's users get by default; tier-1 keeps it at the largest
+# batch that compiles in about ten seconds, the full shapes are `slow`.
+@pytest.mark.parametrize("precision,keys,batch", [
+    ("fast", 1_000, 2_048),
+    pytest.param("fast", 10_000, 65_536, marks=pytest.mark.slow),
+    pytest.param("exact", 10_000, 65_536, marks=pytest.mark.slow),
+])
+def test_global_window_step_compiles(one_chip, precision, keys, batch):
+    step, avals = _stock_step(precision, False, 1_000, keys, batch)
+    compiled, seconds = _compile_for(one_chip, step, avals)
+    _report(f"A length(1000) group-by {precision} B={batch} keys={keys}",
+            compiled, seconds)
+
+
+# (c) phase B: per-key rings [K * 1000]; the tier-1 case is the size that
+# fits a test's time, the deployment size is `slow`.
+@pytest.mark.parametrize("keys,batch", [
+    (100, 1_024),
+    pytest.param(10_000, 65_536, marks=pytest.mark.slow),
+])
+def test_keyed_ring_step_compiles(one_chip, keys, batch):
+    step, avals = _stock_step("fast", True, 1_000, keys, batch)
+    compiled, seconds = _compile_for(one_chip, step, avals)
+    m = _report(f"B partitioned length(1000) B={batch} keys={keys}",
+                compiled, seconds)
+    rows = max(a.shape[0] for a in jax.tree_util.tree_leaves(avals[0]["win"]))
+    assert rows >= keys * 1_000          # the rings really are [K * W]
+    assert m.argument_size_in_bytes > rows
+
+
+# (b) phase C: the two-step NFA at K = 16,384 partition-key slots.
+@pytest.mark.parametrize("keys,batch", [
+    (10_000, 1_024),
+    pytest.param(10_000, 16_384, marks=pytest.mark.slow),
+])
+def test_nfa_steps_compile(one_chip, keys, batch):
+    manager = SiddhiManager()
+    rt = manager.create_siddhi_app_runtime(_PATTERN)
+    steps = rt.query_runtimes["nfa"]._steps = _SpyingSteps()
+    names = np.array([f"K{i}" for i in range(keys)], dtype=object)
+    # one chunk more than covers the keys: a step enters ``_steps`` on its
+    # first call and is looked up there (and seen) from its second
+    for c0 in range(0, keys + batch, batch):
+        k = names[(c0 + np.arange(batch)) % keys]
+        ts = np.full(batch, 1_000 + c0, np.int64)   # monotone feed
+        rt.get_input_handler("AStream").send_columns(
+            {"k": k, "v": np.zeros(batch)}, timestamps=ts)
+        rt.get_input_handler("BStream").send_columns(
+            {"k": k, "v": np.ones(batch)}, timestamps=ts + 1)
+    manager.shutdown()
+    # the loop-free two-step kernel, not the serial engine
+    assert sorted(steps.seen) == [("AStream", False), ("BStream", False)]
+    for (stream, _generic), (step, avals) in sorted(steps.seen.items()):
+        assert avals[0]["nfa"]["consumed"].shape[0] == 16_384
+        compiled, seconds = _compile_for(one_chip, step, avals)
+        _report(f"C nfa {stream} step B={batch} K=16384", compiled, seconds)
+
+
+# (d) the routed path on a mesh of the four described chips.
+def _mesh4(topo):
+    from siddhi_tpu.parallel.mesh import KEY_AXIS
+
+    return Mesh(np.asarray(topo.devices[:4]), (KEY_AXIS,))
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64, np.bool_])
+def test_pallas_ring_exchange_compiles(topo, dtype):
+    from siddhi_tpu.parallel.mesh import KEY_AXIS, _pallas_ring_exchange
+
+    mesh = _mesh4(topo)
+    fn = jax.jit(jax.shard_map(
+        lambda buf: _pallas_ring_exchange(buf, 4), mesh=mesh,
+        in_specs=P(KEY_AXIS), out_specs=P(KEY_AXIS), check_vma=False))
+    x = jax.ShapeDtypeStruct((4 * 4 * 1_024,), dtype,
+                             sharding=NamedSharding(mesh, P(KEY_AXIS)))
+    t0 = time.perf_counter()
+    compiled = fn.lower(x).compile()
+    _report(f"pallas_ring exchange n=4 Q=1024 {np.dtype(dtype)}", compiled,
+            time.perf_counter() - t0)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("exchange,marker", [
+    ("all_to_all", "all-to-all"),
+    pytest.param("pallas_ring", "tpu_custom_call", marks=pytest.mark.slow),
+])
+def test_device_routed_step_compiles(topo, exchange, marker):
+    """The whole ``device_route_query_step`` body on the described mesh:
+    install routing on a CPU mesh of the same width to size the layout,
+    then lower the routed program for the four described chips."""
+    from siddhi_tpu.parallel import mesh as M
+
+    manager = SiddhiManager()
+    rt = manager.create_siddhi_app_runtime(_STOCK.format(
+        precision="fast", W=100, group="", tail="end;",
+        head="partition with (symbol of StockStream)\nbegin"))
+    rt.start()
+    q = rt.query_runtimes["bench"]
+    batch, keys = 4_096, 1_000
+    M.device_route_query_step(q, M.make_mesh(4), rows_per_shard=4_096,
+                              exchange="all_to_all")
+    seen = _spy_steps(q)
+    symbols = np.array([f"S{i}" for i in range(keys)], dtype=object)
+    rt.get_input_handler("StockStream").send_columns(
+        {"symbol": symbols[np.arange(batch) % keys],
+         "price": np.ones(batch, np.float32),
+         "volume": np.ones(batch, np.int64)},
+        timestamps=np.zeros(batch, np.int64))
+    _step, (state, cols, now) = seen[-1]
+    luts = _avals(q._route_layout.device_luts())
+    # the same layout and body over the described chips: shard_map takes
+    # its devices from the mesh, so plain avals are enough
+    q._route_layout.mesh = _mesh4(topo)
+    q._route_layout.exchange = exchange
+    routed = M.routed_step_for(q)._routed_raw
+    manager.shutdown()
+    t0 = time.perf_counter()
+    compiled = routed.lower(state, cols, luts, now).compile()
+    _report(f"device-routed step n=4 {exchange}", compiled,
+            time.perf_counter() - t0)
+    assert marker in compiled.as_text()

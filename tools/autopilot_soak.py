@@ -1,6 +1,9 @@
 """Autopilot soak: 3 bursty tenants, an induced pack bottleneck, and
 the controller clearing it live — with zero output divergence.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Scripted closed-loop scenario (ISSUE 16 acceptance):
 
 1. three tenant apps (projection / group-by sum / windowed avg) on one
@@ -30,7 +33,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "")
 
 import numpy as np  # noqa: E402
 

@@ -1,5 +1,8 @@
 """Cluster-fabric soak: real worker processes, sustained load, a kill.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Drives a partitioned (key-local, split-exact) window app through the
 full fabric — router ingest sequencing, crc32 key split, wire relay,
 worker engines, ordered egress re-merge — at soak volume, with a
@@ -7,9 +10,8 @@ checkpoint barrier early and (by default) a SIGKILL of one worker at
 the halfway mark. Asserts effectively-once end to end: the merged
 egress stream must EXACTLY equal the uninterrupted single-process run
 (zero lost rows, zero duplicated rows, identical order — an exact
-recount, not a statistical one). Also records the throughput of each
-fabric width, the scaling curve ``bench.py --section cluster`` ships
-into BENCH_r09.json:
+recount, not a statistical one). Also prints the throughput of each
+fabric width (``--no-kill`` for the pure scaling curve):
 
     JAX_PLATFORMS=cpu python tools/cluster_soak.py                # 2,4 + kill
     JAX_PLATFORMS=cpu python tools/cluster_soak.py --workers 1,2,4 --no-kill

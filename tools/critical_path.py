@@ -80,9 +80,6 @@ def render(report: dict) -> str:
 
 def _demo_report() -> dict:
     """Deploy a tiny app, plant a slow pack stage, return its report."""
-    import gc
-
-    gc.disable()          # GC during jax tracing segfaults this build
     import numpy as np
 
     from siddhi_tpu import SiddhiManager

@@ -1,5 +1,8 @@
 """Multi-tenant fleet soak for the process-global compiled-program cache.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Churns a fleet of PR-14 fuzz-generated apps (seeded corpus — same seed,
 same fleet, byte for byte) through one process as tenants: every case is
 deployed T times under distinct app names, fed its deterministic event
@@ -19,8 +22,7 @@ The cache claims under test (core/util/program_cache.py, ISSUE 20):
 - install wall-time curve: per-app deploy+first-feed milliseconds in
   deployment order — the cache-on curve flattens after app 1
   (``--compare-off`` reruns the fleet with ``program_cache: off`` for
-  the honest ratio; ``bench.py --section programs`` records that
-  comparison into BENCH_r10.json).
+  the honest ratio).
 
 Usage:
     JAX_PLATFORMS=cpu python tools/fleet_soak.py                # default

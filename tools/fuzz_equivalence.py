@@ -1,5 +1,8 @@
 """Semantic fuzzing soak: generated SiddhiQL corpus vs the strategy matrix.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Generates a seeded corpus of typed, random-but-valid SiddhiQL apps
 (``siddhi_tpu/fuzz/generator.py``), runs each case's deterministic feed
 through EVERY live strategy combination — fan-out fusion on/off x

@@ -1,5 +1,8 @@
 """Collective-op / host-transfer audit of every jitted step's HLO.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Round 4 shipped this as a hand-kept pair of lowerings; it is now a
 REGISTRY-driven audit: every entry in
 ``siddhi_tpu/analysis/step_registry.py`` (the declarative list of all

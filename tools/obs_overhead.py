@@ -117,9 +117,6 @@ def _measure(mode: str, seconds: float) -> float:
 
 
 def main() -> int:
-    import gc
-
-    gc.disable()          # GC during jax tracing segfaults this build
     import jax
 
     seconds = float(os.environ.get("BENCH_SECONDS", 4.0))

@@ -1,5 +1,8 @@
 """Overload soak: N tenant apps, one flooded 10x — victims stay healthy.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 The multi-tenant acceptance scenario for the overload layer
 (``siddhi_tpu/resilience/overload.py``):
 
@@ -34,7 +37,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "")
 
 import numpy as np  # noqa: E402
 

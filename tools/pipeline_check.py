@@ -1,5 +1,8 @@
 """Quick dispatch-pipeline check: pipelined output == synchronous output.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Replays the bench shape (string ingest -> length-window group-by fan-out)
 through an @Async junction — the producer shape where the CompletionPump
 actually pipelines (the worker delivers back-to-back, so up to

@@ -1,5 +1,8 @@
 """Quick sharded-aggregation check: sharded == unsharded, bit-identical.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Feeds one fixed random corpus (columnar bulk sends) through the same
 multi-granularity aggregation app four times — unsharded and with the
 serving tier's mesh sharding at 2/4/8 shards — then runs a battery of

@@ -1,5 +1,8 @@
 """Run the whole pre-commit quick tier with ONE command and ONE exit code.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Each check is a standalone script that asserts bit-identity (or audits
 the HLO) and exits nonzero on failure; this runner executes them as
 subprocesses (each needs its own fresh jax process — several reconfigure
@@ -79,7 +82,6 @@ def main() -> int:
         return 2
     base_env = dict(os.environ)
     base_env.setdefault("JAX_PLATFORMS", "cpu")
-    base_env.setdefault("JAX_COMPILATION_CACHE_DIR", "")
     if not explicit and base_env.get(
             "SIDDHI_TPU_SANITIZE", "").strip().lower() in (
             "1", "true", "on", "yes"):     # same spellings sanitize.enabled()

@@ -1,5 +1,8 @@
 """Quick cluster-fabric check: 2 worker processes, one exact answer.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Drives the SAME columnar batch feed through (1) a plain in-process
 runtime and (2) a 2-worker ``ClusterRuntime`` — router decode, crc32
 key split into contiguous same-owner runs, relay re-encode on each

@@ -1,5 +1,8 @@
 """Quick fan-out fusion check: fused == unfused outputs on a 3-query app.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Runs the same event feed through a 3-query single-stream app twice —
 once with fan-out fusion on (one jitted dispatch + one meta pull per
 batch, asserted via telemetry) and once with the knob off — and

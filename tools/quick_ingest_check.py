@@ -1,5 +1,8 @@
 """Quick ingest front-door check: three ingest paths, one exact answer.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Drives the SAME event sequence through an ``@app:enforceOrder`` windowed
 group-by app three ways and asserts bit-identical outputs in identical
 order:

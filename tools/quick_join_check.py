@@ -1,5 +1,8 @@
 """Quick device-join check: engine output == legacy synchronous output.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Drives one app whose queries cover the eligibility matrix — inner x
 length windows, left-outer x time window (+ residual condition),
 unidirectional x length x grouped selector — through the PanJoin-style

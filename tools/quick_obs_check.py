@@ -3,6 +3,9 @@ bit-identically, plus report/registry sanity — and (ISSUE 12) device
 instruments on == off bit-identically across the routed / fused / join
 / NFA step shapes. ~40 s.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Part 1 runs the same deterministic input sequence through two fresh
 runtimes of a 2-query app (the fused fan-out path — the default engine
 shape):
@@ -174,9 +177,6 @@ def _shape_run(instruments_on: bool, shape: str):
 
 
 def main() -> int:
-    import gc
-
-    gc.disable()          # GC during jax tracing segfaults this build
     # the routed shape needs a multi-device (virtual CPU) mesh — must
     # precede any jax backend touch
     from siddhi_tpu.parallel.mesh import force_host_devices

@@ -1,5 +1,8 @@
 """Quick proof of the process-global compiled-program cache (~10 s).
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Three facts, each asserted exactly (core/util/program_cache.py,
 ISSUE 20):
 

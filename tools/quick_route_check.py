@@ -1,5 +1,8 @@
 """Quick device-routing check: device-routed == unrouted, bit-identical.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Runs the same feed through a partitioned query with a DISTINCT group-by
 key (the case the legacy host router rejected outright) twice — once
 unsharded, once with on-device repartitioning over a 4-device virtual CPU

@@ -1,5 +1,8 @@
 """Kill-one-of-two-peers recovery smoke: end-to-end in under a minute.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Spawns a REAL 2-process ``jax.distributed`` cluster running the
 partitioned-NFA app, checkpoints to a shared
 FileSystemPersistenceStore, kills process 1 abruptly (``os._exit``, no
@@ -59,10 +62,7 @@ def _pairs(handler_a, handler_b, seg):
 
 def worker(coord: str, pid: int, flag: str, store_dir: str,
            my_port: int, peer_port: int) -> None:
-    import gc
     import traceback
-
-    gc.disable()      # GC during jax tracing segfaults this build
 
     def _die(tp, v, tb):
         # a failed worker must EXIT, not park in jax.distributed's

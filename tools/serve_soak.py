@@ -2,6 +2,9 @@
 live ingest — the "millions of users refreshing dashboards" workload
 (ROADMAP item 3), plus a kill-one-shard restore mid-soak.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Drives one mesh-sharded aggregation app through the REST surface:
 
 - an ingest thread pumps columnar batches into the aggregation the whole

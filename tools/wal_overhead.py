@@ -62,9 +62,6 @@ def _measure(with_wal: bool, seconds: float) -> float:
 
 
 def main() -> int:
-    import gc
-
-    gc.disable()          # GC during jax tracing segfaults this build
     import jax
 
     seconds = float(os.environ.get("BENCH_SECONDS", 4.0))

@@ -1,5 +1,8 @@
 """Wire-format ingest bench: the client-side encoder + the front door.
 
+CPU tool: forces the CPU backend and is never on the chip path
+(``chip_smoke.py`` is).
+
 Two modes, both runnable from a clean shell on the CPU backend:
 
     JAX_PLATFORMS=cpu python tools/wire_bench.py          # pack paths
@@ -25,7 +28,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "")
 
 import numpy as np  # noqa: E402
 
