@@ -27,8 +27,7 @@ declaration:
 
 With sanitize off (the default) ``guarded()`` validates the rank names
 and returns the class untouched: declared fields stay plain instance
-attributes — zero descriptors, zero indirection, zero cost (the
-``tools/obs_overhead.py`` bar covers this).
+attributes — zero descriptors, zero indirection, zero cost.
 
 ``__init__`` is exempt: construction happens before the instance is
 shared, so the constructor populates fields without the lock (the same

@@ -1065,22 +1065,39 @@ class SiddhiAppRuntime:  # graftlint: disable=R8 — the junction/query/
         ``log_dir`` — the TPU-native answer to the reference's latency
         tracker detail level: per-op device timings come from the XLA
         profiler rather than per-processor stopwatches. View with
-        TensorBoard or xprof."""
+        TensorBoard or xprof. For its duration the engine's spans and
+        batch journeys are on (``journey.enable``: the one switch; the
+        stage histograms on ``/metrics`` and the journey ring fill as a
+        side effect), so the
+        trace holds the host stages (``siddhi.pack``, ``siddhi.query.step``,
+        ``siddhi.meta_pull``, ``siddhi.emit``, ``siddhi.pull``, ...) on
+        ``/host:CPU`` beside the device's timeline."""
         import jax
+
+        from siddhi_tpu.observability import journey
 
         if getattr(self, "_tracing", False):
             raise RuntimeError("a trace is already running")
         jax.profiler.start_trace(log_dir)
+        journey.enable()
         self._tracing = True
         return log_dir
 
     def stop_trace(self):
         import jax
 
+        from siddhi_tpu.observability import journey
+
         if not getattr(self, "_tracing", False):
             raise RuntimeError("no trace is running")
-        jax.profiler.stop_trace()
+        # this trace's hold is released exactly once, whatever the
+        # profiler does: a retry after a failed stop must not give back
+        # somebody else's (profile_journeys, /profile/journeys)
         self._tracing = False
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            journey.disable()
 
     def shutdown(self):
         self.app_context.stopped = True
@@ -1161,6 +1178,14 @@ class SiddhiAppRuntime:  # graftlint: disable=R8 — the junction/query/
         # this app's wall-tracking must die with it (a redeployed
         # same-named app starts a fresh observation window)
         journey.forget_app(self.app_context.name)
+        if getattr(self, "_tracing", False):
+            try:
+                self.stop_trace()     # and with it the trace's hold
+            except Exception:  # noqa: BLE001 — the profiler's own fault
+                import logging
+
+                logging.getLogger(__name__).exception(
+                    "stopping the device trace at shutdown")
         if self._profiling_on:
             # release this runtime's refcount on the process collectors
             from siddhi_tpu.observability import costmodel

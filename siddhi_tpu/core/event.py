@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from siddhi_tpu.observability import journey
+from siddhi_tpu.observability.tracing import span, spans_on
 from siddhi_tpu.ops.expressions import TS_KEY, TYPE_KEY, VALID_KEY
 from siddhi_tpu.ops.types import dtype_of
 from siddhi_tpu.query_api.definitions import AbstractDefinition, AttrType
@@ -274,24 +275,35 @@ def pack_pool_of(app_context):
     return getattr(app_context, "ingest_pack_pool", None)
 
 
-def _journey_t0() -> Optional[float]:
-    """Pack-stage stamp: perf_counter at pack start when batch-journey
-    tracing is on, else None — one module-flag check per BATCH pack
-    (observability/journey.py; maybe_delay is the tests' planted-pack-
-    bottleneck injection point, a no-op unless armed)."""
-    if not journey.enabled():
-        return None
-    t0 = time.perf_counter()
-    journey.maybe_delay("pack")   # inside the timed window by design
-    return t0
-
-
 def _pad_len(n: int, minimum: int = 8) -> int:
     """Pad batch length to a power of two to bound jit recompiles."""
     b = minimum
     while b < n:
         b *= 2
     return b
+
+
+def _pull(refs: list, rows: bool) -> list:
+    """The output's device->host pull (``LazyColumns``): one
+    ``jax.device_get`` of ``refs``, under a ``siddhi.pull`` span while
+    spans are on, and charged to the journey whose emit stage is open on
+    this thread. ``rows``: the pull is of output columns, whose padded
+    length counts as ``rows_padded`` (a popped control scalar's does
+    not)."""
+    import jax
+
+    if not spans_on():
+        return jax.device_get(refs)
+    nbytes = sum(int(getattr(r, "nbytes", 0)) for r in refs)
+    jr = journey.emitting_journey()
+    with span("pull", bytes=nbytes, arrays=len(refs),
+              batch=jr.batch if jr is not None else None) as sp:
+        out = jax.device_get(refs)
+    if jr is not None:
+        jr.pulled(sp.ms or 0.0, max(
+            (r.shape[0] for r in refs if getattr(r, "ndim", 0)),
+            default=0) if rows else 0)
+    return out
 
 
 class LazyColumns(dict):
@@ -312,13 +324,11 @@ class LazyColumns(dict):
         return v
 
     def _materialize_all(self):
-        import jax
-
         pending = [(key, val) for key, val in super().items()
                    if not isinstance(val, np.ndarray)]
         if not pending:
             return
-        pulled = jax.device_get([v for _k, v in pending])
+        pulled = _pull([v for _k, v in pending], rows=True)
         for (key, _v), arr in zip(pending, pulled):
             super().__setitem__(key, np.asarray(arr))
 
@@ -336,9 +346,7 @@ class LazyColumns(dict):
             v = super().__getitem__(k)
             dict.pop(self, k)
             if not isinstance(v, np.ndarray):
-                import jax
-
-                v = np.asarray(jax.device_get(v))
+                v = np.asarray(_pull([v], rows=False)[0])
             return v
         if default:
             return default[0]
@@ -381,6 +389,18 @@ class HostBatch:
         event_type: int = CURRENT,
         pool=None,
     ) -> "HostBatch":
+        # the pack stage: one span, and the journey's stamp from it
+        with journey.pack_span() as sp:
+            batch, pack_ms = HostBatch._pack_events(
+                events, definition, dictionary, pad_to, event_type, pool)
+        journey.stamp_pack(batch, sp, pack_ms)
+        return batch
+
+    @staticmethod
+    def _pack_events(events, definition, dictionary, pad_to, event_type,
+                     pool):
+        """The batch, and the pack service time where it is not the
+        caller's span (the parallel pack's max-not-sum), else None."""
         if pool is not None:
             chunks = pool.plan_events(len(events), definition)
             if chunks is not None:
@@ -394,7 +414,8 @@ class HostBatch:
                 return _parallel_from_events(pool, chunks, events,
                                              definition, dictionary,
                                              pad_to, event_type)
-        t0 = _journey_t0()
+        if journey.enabled():
+            journey.maybe_delay("pack")   # tests' planted pack bottleneck
         n = len(events)
         b = pad_to if pad_to is not None else _pad_len(n)
         cols: Dict[str, np.ndarray] = {
@@ -484,10 +505,7 @@ class HostBatch:
                         arr[:n] = col
             cols[attr.name] = arr
             cols[attr.name + "?"] = mask
-        batch = HostBatch(cols)
-        if t0 is not None:
-            journey.stamp_pack(batch, t0)
-        return batch
+        return HostBatch(cols), None
 
     @staticmethod
     def from_columns(
@@ -503,6 +521,16 @@ class HostBatch:
         skips per-event objects entirely. ``data`` maps attribute names to
         arrays (strings may be numpy object/str arrays, encoded here, or
         pre-encoded int ids). ``<name>?`` null-mask arrays are optional."""
+        with journey.pack_span() as sp:
+            batch, pack_ms = HostBatch._pack_columns(
+                data, definition, dictionary, timestamps, default_ts,
+                pad_to, pool)
+        journey.stamp_pack(batch, sp, pack_ms)
+        return batch
+
+    @staticmethod
+    def _pack_columns(data, definition, dictionary, timestamps, default_ts,
+                      pad_to, pool):
         if pool is not None:
             chunks = pool.plan_columns(data, definition)
             if chunks is not None:
@@ -510,7 +538,8 @@ class HostBatch:
                                               definition, dictionary,
                                               timestamps, default_ts,
                                               pad_to)
-        t0 = _journey_t0()
+        if journey.enabled():
+            journey.maybe_delay("pack")
         first = next(iter(data.values()))
         n = len(first)
         b = pad_to if pad_to is not None else _pad_len(n)
@@ -547,10 +576,7 @@ class HostBatch:
                 mask[:n] |= np.asarray(user_mask, bool)[:n]
             cols[attr.name] = arr
             cols[attr.name + "?"] = mask
-        batch = HostBatch(cols)
-        if t0 is not None:
-            journey.stamp_pack(batch, t0)
-        return batch
+        return HostBatch(cols), None
 
     def to_events(
         self,
@@ -666,7 +692,7 @@ class HostBatch:
 # packer), plus the serial merge.
 
 def _parallel_from_events(pool, chunks, events, definition, dictionary,
-                          pad_to, event_type) -> "HostBatch":
+                          pad_to, event_type):
     jt = journey.enabled()
     n = len(events)
     b = pad_to if pad_to is not None else _pad_len(n)
@@ -734,15 +760,13 @@ def _parallel_from_events(pool, chunks, events, definition, dictionary,
     batch = HostBatch(cols)
     merge_ms = (time.perf_counter() - t_merge) * 1000.0
     pool.record_merge(merge_ms)
-    if jt:
-        # max-not-sum: sub-batches packed concurrently — the pack stage's
-        # service is the slowest packer plus the serial merge
-        journey.stamp_pack_ms(batch, max(chunk_ms, default=0.0) + merge_ms)
-    return batch
+    # max-not-sum: sub-batches packed concurrently — the pack stage's
+    # service is the slowest packer plus the serial merge
+    return batch, max(chunk_ms, default=0.0) + merge_ms
 
 
 def _parallel_from_columns(pool, chunks, data, definition, dictionary,
-                           timestamps, default_ts, pad_to) -> "HostBatch":
+                           timestamps, default_ts, pad_to):
     jt = journey.enabled()
     first = next(iter(data.values()))
     n = len(first)
@@ -813,6 +837,4 @@ def _parallel_from_columns(pool, chunks, data, definition, dictionary,
     batch = HostBatch(cols)
     merge_ms = (time.perf_counter() - t_merge) * 1000.0
     pool.record_merge(merge_ms)
-    if jt:
-        journey.stamp_pack_ms(batch, max(chunk_ms, default=0.0) + merge_ms)
-    return batch
+    return batch, max(chunk_ms, default=0.0) + merge_ms

@@ -43,10 +43,13 @@ from __future__ import annotations
 import logging
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.core.plan.selector_plan import FLUSH_KEY, GK_KEY, STR_RANK
+from siddhi_tpu.observability.instruments import (META_SCOPE, SELECT_SCOPE,
+                                                  STATE_SCOPE)
 from siddhi_tpu.ops.expressions import (
     OKEY_KEY, TS_KEY, TYPE_KEY, VALID_KEY)
 from siddhi_tpu.ops.windows import (
@@ -721,8 +724,9 @@ class DeviceJoinEngine:
             new_state = dict(state)
             win_before = state[splan.win_key]
             conformed = conform_cols(side.window_stage, cols)
-            new_win, wout = side.window_stage.apply(win_before, conformed,
-                                                    ctx)
+            with jax.named_scope(STATE_SCOPE):
+                new_win, wout = side.window_stage.apply(win_before,
+                                                        conformed, ctx)
             new_state[splan.win_key] = new_win
             wout = dict(wout)
             notify = wout.pop("__notify__", None)
@@ -743,95 +747,96 @@ class DeviceJoinEngine:
                 ovbits = ovbits | jnp.where(
                     jnp.asarray(overflow).astype(jnp.int32) > 0, 1, 0)
 
-            # ---- insert this batch into OUR OWN partition directory
-            if splan.use_pidx:
-                new_state[splan.pidx_key], pov = _pidx_insert(
-                    state[splan.pidx_key], conformed, win_before, new_win)
-                ovbits = ovbits | (pov * 4)
+            with jax.named_scope(STATE_SCOPE):   # directory insert + probe
+                # ---- insert this batch into OUR OWN partition directory
+                if splan.use_pidx:
+                    new_state[splan.pidx_key], pov = _pidx_insert(
+                        state[splan.pidx_key], conformed, win_before, new_win)
+                    ovbits = ovbits | (pov * 4)
 
-            N = wout[VALID_KEY].shape[0]
-            W = oplan.W if oplan.kind != "none" else None
-            row_live = wout[VALID_KEY] & (
-                (wout[TYPE_KEY] == CURRENT) | (wout[TYPE_KEY] == EXPIRED))
-            gathered = oplan.use_pidx and side.triggers
+                N = wout[VALID_KEY].shape[0]
+                W = oplan.W if oplan.kind != "none" else None
+                row_live = wout[VALID_KEY] & (
+                    (wout[TYPE_KEY] == CURRENT) | (wout[TYPE_KEY] == EXPIRED))
+                gathered = oplan.use_pidx and side.triggers
 
-            if gathered:
-                # ---- masked partition-local probe: gather only the
-                # trigger row's hash partition of the other side
-                opidx = state[oplan.pidx_key]
-                oring = state[oplan.win_key]["buf"]
-                ofloor = oplan.live_floor(state[oplan.win_key])
-                vals, mask = splan.key_fn(wout, ctx)
-                vals = jnp.broadcast_to(jnp.asarray(vals), (N,))
-                p_i = hash_partition_dev(vals, P)
-                if mask is not None:
-                    p_i = jnp.where(
-                        jnp.broadcast_to(jnp.asarray(mask, bool), (N,)),
-                        jnp.int32(0), p_i)
-                cand_g = opidx["gseq"][p_i]                     # [N, Wp]
-                cand_live = (cand_g >= ofloor) & (cand_g >= 0)
-                cand_slot = (jnp.clip(cand_g, 0) % W).astype(jnp.int32)
-                Wp = oplan.Wp
-                ev: Dict[str, jnp.ndarray] = {TS_KEY: wout[TS_KEY][:, None]}
-                for a in other.definition.attributes:
-                    ev[other.prefix + a.name] = oring[a.name][cand_slot]
-                    ev[other.prefix + a.name + "?"] = \
-                        oring[a.name + "?"][cand_slot]
-                for a in side.definition.attributes:
-                    ev[side.prefix + a.name] = wout[a.name][:, None]
-                    ev[side.prefix + a.name + "?"] = \
-                        wout[a.name + "?"][:, None]
-                cond = (on_cond(ev, ctx) if on_cond is not None
-                        else jnp.ones((N, Wp), bool))
-                cond = jnp.broadcast_to(cond, (N, Wp))
-                match = row_live[:, None] & cand_live & cond
-                no_match = (row_live & ~jnp.any(match, axis=1)
-                            & side.outer & side.triggers)
-                one_sided = no_match | (
-                    wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
-                NW = N * (Wp + 1)
-                joined = _materialize(wout, ev, match, one_sided, N, Wp)
-                # emission-order key: (trigger row, LEGACY ring slot) —
-                # sorting by it reproduces the [N, W+1] row-major order
-                # of the broadcast probe exactly (one-sided rows at W)
-                stride = jnp.int64(W + 1)
-                slot_cols = jnp.concatenate(
-                    [cand_slot.astype(jnp.int64),
-                     jnp.full((N, 1), W, jnp.int64)], axis=1)
-                okey = (jnp.arange(N, dtype=jnp.int64)[:, None] * stride
-                        + slot_cols).reshape(NW)
-                okey = jnp.where(joined[VALID_KEY], okey, _BIG)
-                order = jnp.argsort(okey, stable=True)
-                joined = {k: v[order] for k, v in joined.items()}
-            else:
-                # ---- legacy-layout probe (P=1 / untriggering side /
-                # passthrough other side): identical to the broadcast path
-                pcols, pvalid_o = other.window_stage.contents(
-                    state[oplan.win_key])
-                Wo = pvalid_o.shape[0]
-                ev = {TS_KEY: wout[TS_KEY][:, None]}
-                for a in other.definition.attributes:
-                    ev[other.prefix + a.name] = pcols[a.name][None, :]
-                    ev[other.prefix + a.name + "?"] = \
-                        pcols[a.name + "?"][None, :]
-                for a in side.definition.attributes:
-                    ev[side.prefix + a.name] = wout[a.name][:, None]
-                    ev[side.prefix + a.name + "?"] = \
-                        wout[a.name + "?"][:, None]
-                pv = pvalid_o[None, :]
-                if side.triggers:
+                if gathered:
+                    # ---- masked partition-local probe: gather only the
+                    # trigger row's hash partition of the other side
+                    opidx = state[oplan.pidx_key]
+                    oring = state[oplan.win_key]["buf"]
+                    ofloor = oplan.live_floor(state[oplan.win_key])
+                    vals, mask = splan.key_fn(wout, ctx)
+                    vals = jnp.broadcast_to(jnp.asarray(vals), (N,))
+                    p_i = hash_partition_dev(vals, P)
+                    if mask is not None:
+                        p_i = jnp.where(
+                            jnp.broadcast_to(jnp.asarray(mask, bool), (N,)),
+                            jnp.int32(0), p_i)
+                    cand_g = opidx["gseq"][p_i]                     # [N, Wp]
+                    cand_live = (cand_g >= ofloor) & (cand_g >= 0)
+                    cand_slot = (jnp.clip(cand_g, 0) % W).astype(jnp.int32)
+                    Wp = oplan.Wp
+                    ev: Dict[str, jnp.ndarray] = {TS_KEY: wout[TS_KEY][:, None]}
+                    for a in other.definition.attributes:
+                        ev[other.prefix + a.name] = oring[a.name][cand_slot]
+                        ev[other.prefix + a.name + "?"] = \
+                            oring[a.name + "?"][cand_slot]
+                    for a in side.definition.attributes:
+                        ev[side.prefix + a.name] = wout[a.name][:, None]
+                        ev[side.prefix + a.name + "?"] = \
+                            wout[a.name + "?"][:, None]
                     cond = (on_cond(ev, ctx) if on_cond is not None
-                            else jnp.ones((N, Wo), bool))
-                    cond = jnp.broadcast_to(cond, (N, Wo))
-                    match = row_live[:, None] & jnp.broadcast_to(
-                        pv, (N, Wo)) & cond
+                            else jnp.ones((N, Wp), bool))
+                    cond = jnp.broadcast_to(cond, (N, Wp))
+                    match = row_live[:, None] & cand_live & cond
+                    no_match = (row_live & ~jnp.any(match, axis=1)
+                                & side.outer & side.triggers)
+                    one_sided = no_match | (
+                        wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
+                    NW = N * (Wp + 1)
+                    joined = _materialize(wout, ev, match, one_sided, N, Wp)
+                    # emission-order key: (trigger row, LEGACY ring slot) —
+                    # sorting by it reproduces the [N, W+1] row-major order
+                    # of the broadcast probe exactly (one-sided rows at W)
+                    stride = jnp.int64(W + 1)
+                    slot_cols = jnp.concatenate(
+                        [cand_slot.astype(jnp.int64),
+                         jnp.full((N, 1), W, jnp.int64)], axis=1)
+                    okey = (jnp.arange(N, dtype=jnp.int64)[:, None] * stride
+                            + slot_cols).reshape(NW)
+                    okey = jnp.where(joined[VALID_KEY], okey, _BIG)
+                    order = jnp.argsort(okey, stable=True)
+                    joined = {k: v[order] for k, v in joined.items()}
                 else:
-                    match = jnp.zeros((N, Wo), bool)
-                no_match = (row_live & ~jnp.any(match, axis=1)
-                            & side.outer & side.triggers)
-                one_sided = no_match | (
-                    wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
-                joined = _materialize(wout, ev, match, one_sided, N, Wo)
+                    # ---- legacy-layout probe (P=1 / untriggering side /
+                    # passthrough other side): identical to the broadcast path
+                    pcols, pvalid_o = other.window_stage.contents(
+                        state[oplan.win_key])
+                    Wo = pvalid_o.shape[0]
+                    ev = {TS_KEY: wout[TS_KEY][:, None]}
+                    for a in other.definition.attributes:
+                        ev[other.prefix + a.name] = pcols[a.name][None, :]
+                        ev[other.prefix + a.name + "?"] = \
+                            pcols[a.name + "?"][None, :]
+                    for a in side.definition.attributes:
+                        ev[side.prefix + a.name] = wout[a.name][:, None]
+                        ev[side.prefix + a.name + "?"] = \
+                            wout[a.name + "?"][:, None]
+                    pv = pvalid_o[None, :]
+                    if side.triggers:
+                        cond = (on_cond(ev, ctx) if on_cond is not None
+                                else jnp.ones((N, Wo), bool))
+                        cond = jnp.broadcast_to(cond, (N, Wo))
+                        match = row_live[:, None] & jnp.broadcast_to(
+                            pv, (N, Wo)) & cond
+                    else:
+                        match = jnp.zeros((N, Wo), bool)
+                    no_match = (row_live & ~jnp.any(match, axis=1)
+                                & side.outer & side.triggers)
+                    one_sided = no_match | (
+                        wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
+                    joined = _materialize(wout, ev, match, one_sided, N, Wo)
 
             if strrank is not None:
                 joined[STR_RANK] = strrank
@@ -848,12 +853,14 @@ class DeviceJoinEngine:
                 if notify is not None:
                     joined["__notify__"] = notify
                 joined["__overflow__"] = ovbits
-                out = pack_meta(joined)
-                out["__meta__"] = jnp.concatenate(
-                    [out["__meta__"]] + _meta_suffix(new_state, seq))
+                with jax.named_scope(META_SCOPE):
+                    out = pack_meta(joined)
+                    out["__meta__"] = jnp.concatenate(
+                        [out["__meta__"]] + _meta_suffix(new_state, seq))
                 return new_state, out
 
-            new_state["sel"], out = sel.apply(state["sel"], joined, ctx)
+            with jax.named_scope(SELECT_SCOPE):
+                new_state["sel"], out = sel.apply(state["sel"], joined, ctx)
             sel_ov = out.pop("__overflow__", None)
             if sel_ov is not None:
                 ovbits = ovbits | jnp.where(
@@ -861,9 +868,10 @@ class DeviceJoinEngine:
             out["__overflow__"] = ovbits
             if notify is not None:
                 out["__notify__"] = notify
-            out = pack_meta(out)
-            out["__meta__"] = jnp.concatenate(
-                [out["__meta__"]] + _meta_suffix(new_state, seq))
+            with jax.named_scope(META_SCOPE):
+                out = pack_meta(out)
+                out["__meta__"] = jnp.concatenate(
+                    [out["__meta__"]] + _meta_suffix(new_state, seq))
             return new_state, out
 
         return step

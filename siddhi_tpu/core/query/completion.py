@@ -72,6 +72,7 @@ from siddhi_tpu.analysis.guards import guarded
 from siddhi_tpu.analysis.locks import make_lock
 from siddhi_tpu.core.stream.junction import FatalQueryError
 from siddhi_tpu.observability import journey as journey_mod
+from siddhi_tpu.observability.tracing import span
 
 log = logging.getLogger(__name__)
 
@@ -143,12 +144,10 @@ class QueryCompletion:
                 return FatalQueryError(
                     f"query '{q.name}': {msg} before "
                     f"creating the runtime")
-            jr = self.journey
-            t_e = time.perf_counter() if jr is not None else None
-            q._emit(HostBatch(self.out, size=size))
-            if jr is not None:
-                jr.emit_ms = (time.perf_counter() - t_e) * 1000.0
-                jr.finish(q.app_context, (q.name,))
+            # the owner's emit stage (siddhi.emit span + journey), as in
+            # the synchronous tail
+            q._timed_emit(HostBatch(self.out, size=size), self.journey,
+                          rows_out=size)
             if notify >= 0 and q.scheduler is not None:
                 q.scheduler.notify_at(
                     notify, self.timer_cb
@@ -385,18 +384,20 @@ class CompletionPump:
         draining.add(id(owner))
         try:
             refs = [r for e in take for r in e.meta_refs()]
-            jt = journey_mod.enabled()
-            if jt:
-                # device-stage pivot: is_ready BEFORE the blocking pull
-                # tells whether the device was still busy for the ride
-                # (service) or the output sat parked (slack) — journey.py
-                for e in take:
-                    jr = getattr(e, "journey", None)
-                    if jr is not None:
-                        jr.pre_drain(e.ready())
-                t_pull0 = time.perf_counter()
+            riding = [e for e in take if e.journey is not None]
+            # device-stage pivot: is_ready BEFORE the blocking pull
+            # tells whether the device was still busy for the ride
+            # (service) or the output sat parked (slack) — journey.py
+            for e in riding:
+                e.journey.pre_drain(e.ready())
             try:
-                metas = self._pull(owner, refs)
+                # one batched round trip serves the whole round: the
+                # span names the round's first batch, and each entry's
+                # journey is attributed the round's pull
+                with span("meta_pull", query=self._label_of(owner),
+                          batch=riding[0].journey.batch if riding else None,
+                          batches=len(take)) as sp:
+                    metas = self._pull(owner, refs)
             except Exception as pull_err:  # noqa: BLE001 — dead peer etc.
                 # the pull itself failed (a dead peer's ClusterPeerError
                 # from guarded_pull): route it exactly like the old
@@ -418,14 +419,8 @@ class CompletionPump:
                 if not routed:
                     raise
                 return
-            if jt:
-                pull_ms = (time.perf_counter() - t_pull0) * 1000.0
-                for e in take:
-                    jr = getattr(e, "journey", None)
-                    if jr is not None:
-                        # one batched round trip serves the whole round;
-                        # each entry is attributed the round's pull
-                        jr.drained(pull_ms)
+            for e in riding:
+                e.journey.meta_pulled(sp.ms)
             errors: List[Exception] = []
             i = 0
             for e in take:

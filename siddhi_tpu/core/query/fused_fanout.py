@@ -58,7 +58,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -67,7 +66,8 @@ import numpy as np
 
 from siddhi_tpu.analysis.locks import make_lock
 from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
-from siddhi_tpu.observability import journey
+from siddhi_tpu.observability import instruments, journey
+from siddhi_tpu.observability.tracing import span
 from siddhi_tpu.core.plan.selector_plan import GK_KEY, STR_RANK
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
 from siddhi_tpu.ops.expressions import VALID_KEY
@@ -279,12 +279,11 @@ class FusedFanoutRuntime(Receiver):
     def process_batch(self, batch: HostBatch, junction=None):
         from siddhi_tpu.core.stream.junction import \
             current_delivering_junction
-        from siddhi_tpu.observability.tracing import span
 
         if junction is None:
             junction = current_delivering_junction()
-        with span("fanout.step", stream=self.stream_id,
-                  members=len(self.members)):
+        with span("fanout.step", batch=journey.batch_of(batch),
+                  stream=self.stream_id, members=len(self.members)):
             with self._lock, contextlib.ExitStack() as stack:
                 # member locks in subscription order (snapshot takes them
                 # one at a time — no cycle)
@@ -425,13 +424,15 @@ class FusedFanoutRuntime(Receiver):
                 metas.append(meta if ins_on else meta[:3])
                 new_states.append(st)
                 outs.append(out)
-            width = max(m.shape[0] for m in metas)
-            metas = [m if m.shape[0] == width else jnp.concatenate(
-                [m, jnp.zeros(width - m.shape[0], m.dtype)])
-                for m in metas]
-            return tuple(new_states), (tuple(outs), jnp.stack(metas))
+            with jax.named_scope(instruments.META_SCOPE):
+                width = max(m.shape[0] for m in metas)
+                metas = [m if m.shape[0] == width else jnp.concatenate(
+                    [m, jnp.zeros(width - m.shape[0], m.dtype)])
+                    for m in metas]
+                return tuple(new_states), (tuple(outs), jnp.stack(metas))
 
-        jitted = jax.jit(fused, donate_argnums=0)
+        jitted = jax.jit(instruments.named_step(fused, "fused_fanout"),
+                         donate_argnums=0)
         return self.app_context.telemetry.instrument_jit(
             jitted, f"fanout.{self.stream_id}.step", family="fused_fanout")
 
@@ -475,18 +476,15 @@ class FusedFanoutRuntime(Receiver):
         # single device->host round trip this layer exists to amortize
         if jr is not None:
             jr.pre_drain(journey.ready_of(metas))
-            _tp = time.perf_counter()
+        with span("meta_pull", query=f"fanout.{self.stream_id}",
+                  batch=jr.batch if jr is not None else None) as sp:
             metas_host = np.asarray(jax.device_get(metas))
-            jr.drained((time.perf_counter() - _tp) * 1000.0)
-        else:
-            metas_host = np.asarray(jax.device_get(metas))
-        tel.count(f"fanout.{self.stream_id}.meta_pulls")
-        t_e = time.perf_counter() if jr is not None else None
-        fatal = self._emit_members(list(members), list(self._cluster_of),
-                                   outs, metas_host, batch, t0sm=t0)
         if jr is not None:
-            jr.emit_ms = (time.perf_counter() - t_e) * 1000.0
-            jr.finish(self.app_context, tuple(m.name for m in members))
+            jr.meta_pulled(sp.ms)
+        tel.count(f"fanout.{self.stream_id}.meta_pulls")
+        fatal = self._timed_emit_members(jr, list(members),
+                                         list(self._cluster_of), outs,
+                                         metas_host, batch, t0sm=t0)
         if fatal is not None:
             # surfaced AFTER every member emitted: the junction's
             # handle_error stores it so later sends re-raise, exactly as
@@ -503,16 +501,23 @@ class FusedFanoutRuntime(Receiver):
         with self._lock, contextlib.ExitStack() as stack:
             for m in entry.members:
                 stack.enter_context(m._lock)
-            jr = entry.journey
-            t_e = time.perf_counter() if jr is not None else None
-            fatal = self._emit_members(entry.members, entry.cluster_of,
-                                       entry.outs, np.asarray(metas_host),
-                                       entry.batch, t0sm=None)
-            if jr is not None:
-                jr.emit_ms = (time.perf_counter() - t_e) * 1000.0
-                jr.finish(self.app_context,
-                          tuple(m.name for m in entry.members))
-            return fatal
+            return self._timed_emit_members(
+                entry.journey, entry.members, entry.cluster_of, entry.outs,
+                np.asarray(metas_host), entry.batch, t0sm=None)
+
+    def _timed_emit_members(self, jr, members, cluster_of, outs, metas_host,
+                            batch, t0sm) -> Optional[Exception]:
+        """``_emit_members`` inside the group journey's emit stage: one
+        ``siddhi.emit`` span for the group batch, recorded under every
+        member's name; ``rows_out`` is the members' row counts summed."""
+        if jr is None:
+            return self._emit_members(members, cluster_of, outs, metas_host,
+                                      batch, t0sm)
+        rows_out = sum(int(metas_host[c][2]) for c in cluster_of)
+        with jr.emitting(self.app_context, tuple(m.name for m in members),
+                         rows_out):
+            return self._emit_members(members, cluster_of, outs, metas_host,
+                                      batch, t0sm)
 
     def _emit_members(self, members, cluster_of, outs, metas_host, batch,
                       t0sm) -> Optional[Exception]:

@@ -35,6 +35,7 @@ from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
 from siddhi_tpu.core.plan.selector_plan import FLUSH_KEY, GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
+from siddhi_tpu.observability import instruments, journey
 from siddhi_tpu.ops.expressions import (
     OKEY_KEY,
     PK_KEY,
@@ -625,9 +626,10 @@ class JoinQueryRuntime(QueryRuntime):
                 valid = valid & (f(cols, ctx) | timer)
             cols[VALID_KEY] = valid
             new_state = dict(state)
-            new_win, wout = side.window_stage.apply(
-                state.get(win_key),
-                conform_cols(side.window_stage, cols), ctx)
+            with jax.named_scope(instruments.STATE_SCOPE):
+                new_win, wout = side.window_stage.apply(
+                    state.get(win_key),
+                    conform_cols(side.window_stage, cols), ctx)
             if win_key in state:
                 new_state[win_key] = new_win
             wout = dict(wout)
@@ -647,147 +649,148 @@ class JoinQueryRuntime(QueryRuntime):
                 pvalid = pvalid & (f(wout, ctx) | ptimer)
             wout[VALID_KEY] = pvalid
 
-            N = wout[VALID_KEY].shape[0]
-            if not other_external:
-                probe_cols, probe_valid = other.window_stage.contents(state[other_key])
+            with jax.named_scope(instruments.STATE_SCOPE):   # the probe
+                N = wout[VALID_KEY].shape[0]
+                if not other_external:
+                    probe_cols, probe_valid = other.window_stage.contents(state[other_key])
 
-            # joined eval dict: this side [N,1]; other side [1,W]
-            # (or, partitioned, this row's key's ring gathered to [N,W];
-            # or, INDEXED, per-row candidate windows gathered to [N,G])
-            ev: Dict[str, jnp.ndarray] = {}
-            idx_overflow = None
-            if use_index:
-                # sort the probe column once (invalid/null rows to the
-                # end), then per-event searchsorted gives a contiguous
-                # candidate range — O(W log W + N log W + N*G) instead of
-                # the O(N*W) broadcast compare, and the join materializes
-                # [N, G+1] instead of [N, W+1]
-                attr = iprobe["attr"]
-                ev0 = {TS_KEY: wout[TS_KEY][:, None]}
-                for a in side.definition.attributes:
-                    ev0[side.prefix + a.name] = wout[a.name][:, None]
-                    ev0[side.prefix + a.name + "?"] = wout[a.name + "?"][:, None]
-                v, vmask = iprobe["val_fn"](ev0, ctx)
-                pvals = probe_cols[attr]
-                pnull = probe_cols.get(attr + "?")
-                ok = probe_valid
-                if pnull is not None:
-                    ok = ok & ~pnull
-                if jnp.issubdtype(pvals.dtype, jnp.floating):
-                    big = jnp.asarray(jnp.inf, pvals.dtype)
+                # joined eval dict: this side [N,1]; other side [1,W]
+                # (or, partitioned, this row's key's ring gathered to [N,W];
+                # or, INDEXED, per-row candidate windows gathered to [N,G])
+                ev: Dict[str, jnp.ndarray] = {}
+                idx_overflow = None
+                if use_index:
+                    # sort the probe column once (invalid/null rows to the
+                    # end), then per-event searchsorted gives a contiguous
+                    # candidate range — O(W log W + N log W + N*G) instead of
+                    # the O(N*W) broadcast compare, and the join materializes
+                    # [N, G+1] instead of [N, W+1]
+                    attr = iprobe["attr"]
+                    ev0 = {TS_KEY: wout[TS_KEY][:, None]}
+                    for a in side.definition.attributes:
+                        ev0[side.prefix + a.name] = wout[a.name][:, None]
+                        ev0[side.prefix + a.name + "?"] = wout[a.name + "?"][:, None]
+                    v, vmask = iprobe["val_fn"](ev0, ctx)
+                    pvals = probe_cols[attr]
+                    pnull = probe_cols.get(attr + "?")
+                    ok = probe_valid
+                    if pnull is not None:
+                        ok = ok & ~pnull
+                    if jnp.issubdtype(pvals.dtype, jnp.floating):
+                        big = jnp.asarray(jnp.inf, pvals.dtype)
+                    else:
+                        big = jnp.asarray(jnp.iinfo(pvals.dtype).max, pvals.dtype)
+                    sortkey = jnp.where(ok, pvals, big)
+                    order = jnp.argsort(sortkey)
+                    sk = sortkey[order]
+                    Wfull = sk.shape[0]
+                    vv = jnp.broadcast_to(jnp.asarray(v), (N, 1))[:, 0] \
+                        .astype(pvals.dtype)
+                    lo = jnp.searchsorted(sk, vv, side="left")
+                    hi = jnp.searchsorted(sk, vv, side="right")
+                    G = min(probe_width, Wfull)
+                    grid = lo[:, None] + jnp.arange(G)[None, :]
+                    cmask = grid < hi[:, None]
+                    if vmask is not None:
+                        cmask = cmask & ~jnp.broadcast_to(
+                            jnp.asarray(vmask), (N, 1))
+                    idx_overflow = jnp.any((hi - lo) > G).astype(jnp.int32)
+                    cand = order[jnp.clip(grid, 0, Wfull - 1)]        # [N, G]
+                    W = G
+                    for a in other.definition.attributes:
+                        ev[other.prefix + a.name] = probe_cols[a.name][cand]
+                        ev[other.prefix + a.name + "?"] = \
+                            probe_cols[a.name + "?"][cand]
+                    # belt-and-braces equality re-check on the gathered rows:
+                    # guards the dtype-max/inf sentinel (a probe value equal
+                    # to it would otherwise sweep deleted/null rows in) and
+                    # any residual dtype edge case
+                    pv = (cmask & ok[cand]
+                          & (pvals[cand] == vv[:, None]))
+                elif partitioned and not other_external:
+                    pk_rows = jnp.clip(wout[PK_KEY].astype(jnp.int32), 0,
+                                       probe_valid.shape[0] - 1)
+                    probe_cols = {a: v[pk_rows] for a, v in probe_cols.items()}
+                    probe_valid = probe_valid[pk_rows]          # [N, W]
+                    W = probe_valid.shape[1]
+                    for a in other.definition.attributes:
+                        ev[other.prefix + a.name] = probe_cols[a.name]
+                        ev[other.prefix + a.name + "?"] = probe_cols[a.name + "?"]
+                    pv = probe_valid
                 else:
-                    big = jnp.asarray(jnp.iinfo(pvals.dtype).max, pvals.dtype)
-                sortkey = jnp.where(ok, pvals, big)
-                order = jnp.argsort(sortkey)
-                sk = sortkey[order]
-                Wfull = sk.shape[0]
-                vv = jnp.broadcast_to(jnp.asarray(v), (N, 1))[:, 0] \
-                    .astype(pvals.dtype)
-                lo = jnp.searchsorted(sk, vv, side="left")
-                hi = jnp.searchsorted(sk, vv, side="right")
-                G = min(probe_width, Wfull)
-                grid = lo[:, None] + jnp.arange(G)[None, :]
-                cmask = grid < hi[:, None]
-                if vmask is not None:
-                    cmask = cmask & ~jnp.broadcast_to(
-                        jnp.asarray(vmask), (N, 1))
-                idx_overflow = jnp.any((hi - lo) > G).astype(jnp.int32)
-                cand = order[jnp.clip(grid, 0, Wfull - 1)]        # [N, G]
-                W = G
-                for a in other.definition.attributes:
-                    ev[other.prefix + a.name] = probe_cols[a.name][cand]
-                    ev[other.prefix + a.name + "?"] = \
-                        probe_cols[a.name + "?"][cand]
-                # belt-and-braces equality re-check on the gathered rows:
-                # guards the dtype-max/inf sentinel (a probe value equal
-                # to it would otherwise sweep deleted/null rows in) and
-                # any residual dtype edge case
-                pv = (cmask & ok[cand]
-                      & (pvals[cand] == vv[:, None]))
-            elif partitioned and not other_external:
-                pk_rows = jnp.clip(wout[PK_KEY].astype(jnp.int32), 0,
-                                   probe_valid.shape[0] - 1)
-                probe_cols = {a: v[pk_rows] for a, v in probe_cols.items()}
-                probe_valid = probe_valid[pk_rows]          # [N, W]
-                W = probe_valid.shape[1]
-                for a in other.definition.attributes:
-                    ev[other.prefix + a.name] = probe_cols[a.name]
-                    ev[other.prefix + a.name + "?"] = probe_cols[a.name + "?"]
-                pv = probe_valid
-            else:
-                W = probe_valid.shape[0]
-                for a in other.definition.attributes:
-                    ev[other.prefix + a.name] = probe_cols[a.name][None, :]
-                    ev[other.prefix + a.name + "?"] = probe_cols[a.name + "?"][None, :]
-                pv = probe_valid[None, :]
-            for a in side.definition.attributes:
-                ev[side.prefix + a.name] = wout[a.name][:, None]
-                ev[side.prefix + a.name + "?"] = wout[a.name + "?"][:, None]
-            ev[TS_KEY] = wout[TS_KEY][:, None]
+                    W = probe_valid.shape[0]
+                    for a in other.definition.attributes:
+                        ev[other.prefix + a.name] = probe_cols[a.name][None, :]
+                        ev[other.prefix + a.name + "?"] = probe_cols[a.name + "?"][None, :]
+                    pv = probe_valid[None, :]
+                for a in side.definition.attributes:
+                    ev[side.prefix + a.name] = wout[a.name][:, None]
+                    ev[side.prefix + a.name + "?"] = wout[a.name + "?"][:, None]
+                ev[TS_KEY] = wout[TS_KEY][:, None]
 
-            row_live = wout[VALID_KEY] & ((wout[TYPE_KEY] == CURRENT) | (wout[TYPE_KEY] == EXPIRED))
-            if use_index:
-                # the probed equality holds by construction; only the
-                # residual conjuncts (if any) still need evaluating
-                rfn = iprobe["residual_fn"]
-                cond = rfn(ev, ctx) if rfn is not None else jnp.ones((N, W), bool)
-                cond = jnp.broadcast_to(cond, (N, W))
-                match = row_live[:, None] & jnp.broadcast_to(pv, (N, W)) & cond
-            elif side.triggers:
-                cond = on_cond(ev, ctx) if on_cond is not None else jnp.ones((N, W), bool)
-                cond = jnp.broadcast_to(cond, (N, W))
-                match = row_live[:, None] & jnp.broadcast_to(pv, (N, W)) & cond
-            else:
-                match = jnp.zeros((N, W), bool)
+                row_live = wout[VALID_KEY] & ((wout[TYPE_KEY] == CURRENT) | (wout[TYPE_KEY] == EXPIRED))
+                if use_index:
+                    # the probed equality holds by construction; only the
+                    # residual conjuncts (if any) still need evaluating
+                    rfn = iprobe["residual_fn"]
+                    cond = rfn(ev, ctx) if rfn is not None else jnp.ones((N, W), bool)
+                    cond = jnp.broadcast_to(cond, (N, W))
+                    match = row_live[:, None] & jnp.broadcast_to(pv, (N, W)) & cond
+                elif side.triggers:
+                    cond = on_cond(ev, ctx) if on_cond is not None else jnp.ones((N, W), bool)
+                    cond = jnp.broadcast_to(cond, (N, W))
+                    match = row_live[:, None] & jnp.broadcast_to(pv, (N, W)) & cond
+                else:
+                    match = jnp.zeros((N, W), bool)
 
-            # column W carries the one-sided row: outer no-match + RESET
-            no_match = row_live & ~jnp.any(match, axis=1) & side.outer & side.triggers
-            one_sided = no_match | (wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
+                # column W carries the one-sided row: outer no-match + RESET
+                no_match = row_live & ~jnp.any(match, axis=1) & side.outer & side.triggers
+                one_sided = no_match | (wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
 
-            NW = N * (W + 1)
-            joined: Dict[str, jnp.ndarray] = {}
-            for a in side.definition.attributes:
-                v = jnp.broadcast_to(wout[a.name][:, None], (N, W + 1))
-                mk = jnp.broadcast_to(wout[a.name + "?"][:, None], (N, W + 1))
-                joined[side.prefix + a.name] = v.reshape(NW)
-                joined[side.prefix + a.name + "?"] = mk.reshape(NW)
-            for a in other.definition.attributes:
-                pc = ev[other.prefix + a.name]
-                pm = ev[other.prefix + a.name + "?"]
-                v = jnp.concatenate(
-                    [jnp.broadcast_to(pc, (N, W)),
-                     jnp.zeros((N, 1), pc.dtype)], axis=1)
-                mk = jnp.concatenate(
-                    [jnp.broadcast_to(pm, (N, W)),
-                     jnp.ones((N, 1), bool)], axis=1)
-                joined[other.prefix + a.name] = v.reshape(NW)
-                joined[other.prefix + a.name + "?"] = mk.reshape(NW)
-            joined[VALID_KEY] = jnp.concatenate(
-                [match, one_sided[:, None]], axis=1).reshape(NW)
-            joined[TS_KEY] = jnp.repeat(wout[TS_KEY], W + 1)
-            joined[TYPE_KEY] = jnp.repeat(wout[TYPE_KEY], W + 1)
-            if partitioned:
-                pk_out = jnp.repeat(wout[PK_KEY].astype(jnp.int32), W + 1)
-                joined[PK_KEY] = pk_out
-                joined[GK_KEY] = pk_out
-            else:
-                joined[GK_KEY] = jnp.zeros(NW, jnp.int32)
-            # one reference chunk per trigger event (JoinProcessor.execute):
-            # the selector's batch collapse keys on (trigger row, group)
-            joined[FLUSH_KEY] = jnp.repeat(
-                jnp.arange(N, dtype=jnp.int32), W + 1)
-            if okey_w is not None:
-                # joined emission-order key: trigger okey stridden by the
-                # probe width reproduces the legacy [N, W+1] row-major
-                # order ACROSS shards (one-sided rows at column W); the
-                # invalid-row _BIG sentinel is zeroed before the multiply
-                # (the route wrapper re-masks invalid rows itself)
-                okw = jnp.asarray(okey_w, jnp.int64)
-                okw = jnp.where(okw >= jnp.int64(2 ** 61), jnp.int64(0), okw)
-                joined[OKEY_KEY] = (
-                    okw[:, None] * jnp.int64(W + 1)
-                    + jnp.arange(W + 1, dtype=jnp.int64)[None, :]
-                ).reshape(NW)
+                NW = N * (W + 1)
+                joined: Dict[str, jnp.ndarray] = {}
+                for a in side.definition.attributes:
+                    v = jnp.broadcast_to(wout[a.name][:, None], (N, W + 1))
+                    mk = jnp.broadcast_to(wout[a.name + "?"][:, None], (N, W + 1))
+                    joined[side.prefix + a.name] = v.reshape(NW)
+                    joined[side.prefix + a.name + "?"] = mk.reshape(NW)
+                for a in other.definition.attributes:
+                    pc = ev[other.prefix + a.name]
+                    pm = ev[other.prefix + a.name + "?"]
+                    v = jnp.concatenate(
+                        [jnp.broadcast_to(pc, (N, W)),
+                         jnp.zeros((N, 1), pc.dtype)], axis=1)
+                    mk = jnp.concatenate(
+                        [jnp.broadcast_to(pm, (N, W)),
+                         jnp.ones((N, 1), bool)], axis=1)
+                    joined[other.prefix + a.name] = v.reshape(NW)
+                    joined[other.prefix + a.name + "?"] = mk.reshape(NW)
+                joined[VALID_KEY] = jnp.concatenate(
+                    [match, one_sided[:, None]], axis=1).reshape(NW)
+                joined[TS_KEY] = jnp.repeat(wout[TS_KEY], W + 1)
+                joined[TYPE_KEY] = jnp.repeat(wout[TYPE_KEY], W + 1)
+                if partitioned:
+                    pk_out = jnp.repeat(wout[PK_KEY].astype(jnp.int32), W + 1)
+                    joined[PK_KEY] = pk_out
+                    joined[GK_KEY] = pk_out
+                else:
+                    joined[GK_KEY] = jnp.zeros(NW, jnp.int32)
+                # one reference chunk per trigger event (JoinProcessor.execute):
+                # the selector's batch collapse keys on (trigger row, group)
+                joined[FLUSH_KEY] = jnp.repeat(
+                    jnp.arange(N, dtype=jnp.int32), W + 1)
+                if okey_w is not None:
+                    # joined emission-order key: trigger okey stridden by the
+                    # probe width reproduces the legacy [N, W+1] row-major
+                    # order ACROSS shards (one-sided rows at column W); the
+                    # invalid-row _BIG sentinel is zeroed before the multiply
+                    # (the route wrapper re-masks invalid rows itself)
+                    okw = jnp.asarray(okey_w, jnp.int64)
+                    okw = jnp.where(okw >= jnp.int64(2 ** 61), jnp.int64(0), okw)
+                    joined[OKEY_KEY] = (
+                        okw[:, None] * jnp.int64(W + 1)
+                        + jnp.arange(W + 1, dtype=jnp.int64)[None, :]
+                    ).reshape(NW)
 
             if idx_overflow is not None:
                 # candidate window saturated: surfacing it beats silently
@@ -808,14 +811,17 @@ class JoinQueryRuntime(QueryRuntime):
                     joined["__notify__"] = notify
                 if overflow is not None:
                     joined["__overflow__"] = overflow
-                return new_state, pack_meta(joined)
+                with jax.named_scope(instruments.META_SCOPE):
+                    return new_state, pack_meta(joined)
 
-            new_state["sel"], out = sel.apply(state["sel"], joined, ctx)
+            with jax.named_scope(instruments.SELECT_SCOPE):
+                new_state["sel"], out = sel.apply(state["sel"], joined, ctx)
             if notify is not None:
                 out["__notify__"] = notify
             if overflow is not None:
                 out["__overflow__"] = overflow
-            return new_state, pack_meta(out)
+            with jax.named_scope(instruments.META_SCOPE):
+                return new_state, pack_meta(out)
 
         return step
 
@@ -831,9 +837,8 @@ class JoinQueryRuntime(QueryRuntime):
         from siddhi_tpu.observability.tracing import span
 
         t_host0 = _time.perf_counter()
-        with span("query.step", query=self.name, side=side_key), self._lock:
-            from siddhi_tpu.observability import journey
-
+        with span("query.step", batch=journey.batch_of(batch),
+                  query=self.name, side=side_key), self._lock:
             # pipelined completions need the delivering junction (error
             # attribution + latency feedback) and the SIDE's own timer
             # callback (per-side notify attribution at drain)
@@ -917,8 +922,9 @@ class JoinQueryRuntime(QueryRuntime):
                     jitted = routed_step_for(self, side_key=side_key)
                 else:
                     jitted = self.app_context.telemetry.instrument_jit(
-                        jax.jit(self.build_side_step_fn(side_key),
-                                donate_argnums=0),
+                        jax.jit(instruments.named_step(
+                            self.build_side_step_fn(side_key),
+                            f"device_join.{side_key}"), donate_argnums=0),
                         f"query.{self.name}.join.{side_key}",
                         family=f"device_join.{side_key}")
                 self._steps[side_key] = jitted

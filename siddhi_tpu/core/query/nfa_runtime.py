@@ -25,6 +25,10 @@ from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
 from siddhi_tpu.core.plan.selector_plan import GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
+from siddhi_tpu.observability import journey
+from siddhi_tpu.observability.instruments import (META_SCOPE, SELECT_SCOPE,
+                                                  STATE_SCOPE, named_step)
+from siddhi_tpu.observability.tracing import span
 from siddhi_tpu.ops.expressions import PK_KEY, TS_KEY, TYPE_KEY, VALID_KEY
 from siddhi_tpu.ops.nfa import NFAStage
 from siddhi_tpu.query_api.definitions import StreamDefinition
@@ -222,9 +226,7 @@ class NFAQueryRuntime(QueryRuntime):
         break buffer donation (XLA copies the whole [K, S] state through
         conditionals — measured 11 big copies/step)."""
         stage = self.stage
-        sel = self.selector_plan
-        split = self.keyer is not None
-        ins_on = self._instruments_on()
+        _select_and_meta = self._select_and_meta_fn()
 
         def step(state, cols, current_time):
             from siddhi_tpu.core.plan.selector_plan import STR_RANK
@@ -232,56 +234,63 @@ class NFAQueryRuntime(QueryRuntime):
             ctx = {"xp": jnp, "current_time": current_time}
             cols = dict(cols)
             strrank = cols.pop(STR_RANK, None)   # selector-only side input
-            if force_generic:
-                new_nfa, out_cols = stage._apply_stream_generic(
-                    stream_id, state["nfa"], cols, ctx)
-            else:
-                new_nfa, out_cols = stage.apply_stream(
-                    stream_id, state["nfa"], cols, ctx)
+            with jax.named_scope(STATE_SCOPE):
+                if force_generic:
+                    new_nfa, out_cols = stage._apply_stream_generic(
+                        stream_id, state["nfa"], cols, ctx)
+                else:
+                    new_nfa, out_cols = stage.apply_stream(
+                        stream_id, state["nfa"], cols, ctx)
             out_cols = dict(out_cols)
             overflow = out_cols.pop("__overflow__", None)
             notify = out_cols.pop("__notify__", None)
             if strrank is not None:
                 out_cols[STR_RANK] = strrank
-            if split:
-                out_cols["__overflow__"] = overflow
-                out_cols["__notify__"] = notify
-                return ({"nfa": new_nfa, "sel": state["sel"]},
-                        _nfa_meta(pack_meta(out_cols), new_nfa, ins_on))
-            new_sel, out = sel.apply(state["sel"], out_cols, ctx)
-            if overflow is not None:
-                out["__overflow__"] = overflow
-            if notify is not None:
-                out["__notify__"] = notify
-            return ({"nfa": new_nfa, "sel": new_sel},
-                    _nfa_meta(pack_meta(out), new_nfa, ins_on))
+            return _select_and_meta(state, new_nfa, out_cols, overflow,
+                                    notify, ctx)
 
         return step
 
-    def build_timer_step_fn(self):
-        stage = self.stage
+    def _select_and_meta_fn(self):
+        """The tail every NFA step shares (stream and timer): the
+        selector over the stage's emissions, unless a host group-by keyer
+        has to run between them, then the packed meta with the
+        ``nfa_runs`` lane."""
         sel = self.selector_plan
         split = self.keyer is not None
         ins_on = self._instruments_on()
 
+        def tail(state, new_nfa, out_cols, overflow, notify, ctx):
+            if split:
+                out, new_sel = out_cols, state["sel"]
+                out["__overflow__"] = overflow
+                out["__notify__"] = notify
+            else:
+                with jax.named_scope(SELECT_SCOPE):
+                    new_sel, out = sel.apply(state["sel"], out_cols, ctx)
+                if overflow is not None:
+                    out["__overflow__"] = overflow
+                if notify is not None:
+                    out["__notify__"] = notify
+            with jax.named_scope(META_SCOPE):
+                return ({"nfa": new_nfa, "sel": new_sel},
+                        _nfa_meta(pack_meta(out), new_nfa, ins_on))
+
+        return tail
+
+    def build_timer_step_fn(self):
+        stage = self.stage
+        _select_and_meta = self._select_and_meta_fn()
+
         def step(state, now):
             ctx = {"xp": jnp, "current_time": now}
-            new_nfa, out_cols = stage.apply_timer(state["nfa"], now, ctx)
+            with jax.named_scope(STATE_SCOPE):
+                new_nfa, out_cols = stage.apply_timer(state["nfa"], now, ctx)
             out_cols = dict(out_cols)
             overflow = out_cols.pop("__overflow__", None)
             notify = out_cols.pop("__notify__", None)
-            if split:
-                out_cols["__overflow__"] = overflow
-                out_cols["__notify__"] = notify
-                return ({"nfa": new_nfa, "sel": state["sel"]},
-                        _nfa_meta(pack_meta(out_cols), new_nfa, ins_on))
-            new_sel, out = sel.apply(state["sel"], out_cols, ctx)
-            if overflow is not None:
-                out["__overflow__"] = overflow
-            if notify is not None:
-                out["__notify__"] = notify
-            return ({"nfa": new_nfa, "sel": new_sel},
-                    _nfa_meta(pack_meta(out), new_nfa, ins_on))
+            return _select_and_meta(state, new_nfa, out_cols, overflow,
+                                    notify, ctx)
 
         return step
 
@@ -293,10 +302,8 @@ class NFAQueryRuntime(QueryRuntime):
 
     def process_stream_batch(self, stream_id: str, batch: HostBatch,
                              junction=None):
-        from siddhi_tpu.observability.tracing import span
-
-        with span("query.step", query=self.name, stream=stream_id), \
-                self._lock:
+        with span("query.step", batch=journey.batch_of(batch),
+                  query=self.name, stream=stream_id), self._lock:
             from siddhi_tpu.core.stream.junction import \
                 current_delivering_junction
 
@@ -305,6 +312,10 @@ class NFAQueryRuntime(QueryRuntime):
             self._cur_fault_batch = batch if (
                 j is not None and j.on_error_action == "STREAM"
                 and j.fault_junction is not None) else None
+            # batch-journey, as QueryRuntime.process_batch: fork the pack
+            # stamp, open the dispatch stage; _run_nfa_step consumes it
+            self._cur_journey = journey.begin(batch) \
+                if journey.enabled() else None
             cols = batch.cols
             partitioned = self.partition_ctx is not None
             if partitioned:
@@ -326,8 +337,11 @@ class NFAQueryRuntime(QueryRuntime):
                        + (".generic" if force_generic else ""))
             step = self._steps.get((stream_id, force_generic))
             if step is None:
-                fn = self.build_stream_step_fn(stream_id,
-                                               force_generic=force_generic)
+                fn = named_step(
+                    self.build_stream_step_fn(stream_id,
+                                              force_generic=force_generic),
+                    f"nfa_step_{stream_id}"
+                    + ("_generic" if force_generic else ""))
                 if self._shard_mesh is not None:
                     from siddhi_tpu.parallel.mesh import sharded_jit_for
 
@@ -434,8 +448,10 @@ class NFAQueryRuntime(QueryRuntime):
                 pump.flush_owner(self)
             if self._state is None:
                 self._state = self._init_state()
+            self._cur_journey = journey.begin() \
+                if journey.enabled() else None
             if self._timer_step is None:
-                fn = self.build_timer_step_fn()
+                fn = named_step(self.build_timer_step_fn(), "nfa_timer")
                 if self._shard_mesh is not None:
                     from siddhi_tpu.parallel.mesh import sharded_jit_for
 
@@ -460,7 +476,11 @@ class NFAQueryRuntime(QueryRuntime):
 
         sm = self.app_context.statistics_manager
         t0 = latency_t0(sm)
+        jr = self._cur_journey
+        self._cur_journey = None
         self._state, out = run()
+        if jr is not None:
+            jr.end_dispatch()
         out_host = LazyColumns(out)
         size_hint = None
         # raw device ref — LazyColumns.pop would PULL it (one ~70ms round
@@ -484,7 +504,8 @@ class NFAQueryRuntime(QueryRuntime):
                     "pattern match-slot capacity exceeded — raise "
                     "app_context.nfa_slots",
                     junction=self._cur_junction,
-                    batch=getattr(self, "_cur_fault_batch", None)))
+                    batch=getattr(self, "_cur_fault_batch", None),
+                    journey=jr))
                 return None
             defer = getattr(self.app_context, "defer_meta", 1)
             if defer > 1 and self.keyer is None and not any(
@@ -494,6 +515,10 @@ class NFAQueryRuntime(QueryRuntime):
                 # only wait-free plans defer (dispatch-side latency only —
                 # emission is deferred)
                 record_elapsed_ms(sm, self.name, t0)
+                if jr is not None:
+                    # legacy hold-N path, as in _finish_device_batch: the
+                    # deferred drain is not instrumented
+                    jr.finish(self.app_context, (self.name,))
                 self._deferred.append((
                     out_host,
                     "pattern match-slot capacity exceeded — raise "
@@ -502,7 +527,9 @@ class NFAQueryRuntime(QueryRuntime):
                     return None
                 return self.flush_deferred()
             dict.pop(out_host, "__meta__")
-            meta = self._pull_meta(meta)
+            if jr is not None:
+                jr.pre_drain(journey.ready_of(meta))
+            meta = self._pull_meta(meta, jr)
             self.decode_meta_suffix(meta)
             overflow, notify, size_hint = int(meta[0]), int(meta[1]), int(meta[2])
         else:
@@ -521,7 +548,8 @@ class NFAQueryRuntime(QueryRuntime):
             out_host.pop("__notify__", None)
             out_host = self._host_keyed_select(out_host)
             size_hint = None
-        self._emit(HostBatch(out_host, size=size_hint))
+        self._timed_emit(HostBatch(out_host, size=size_hint), jr,
+                         rows_out=size_hint)
         if notify >= 0:
             return notify
         return None

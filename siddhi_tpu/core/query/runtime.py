@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 import uuid
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -25,6 +24,7 @@ import numpy as np
 from siddhi_tpu.analysis.locks import make_lock
 from siddhi_tpu.core.event import CURRENT, EXPIRED, TIMER as TIMER_TYPE, Event, HostBatch, LazyColumns, StringDictionary, pack_pool_of
 from siddhi_tpu.observability import instruments, journey
+from siddhi_tpu.observability.tracing import span
 from siddhi_tpu.observability.instruments import Slot
 from siddhi_tpu.core.plan.selector_plan import GK_KEY, SelectorPlan
 from siddhi_tpu.core.query.ratelimit import OutputRateLimiter
@@ -377,7 +377,9 @@ class QueryRuntime(Receiver):
             from siddhi_tpu.parallel.mesh import routed_step_for
 
             return routed_step_for(self)
-        jitted = jax.jit(self.build_step_fn(), donate_argnums=0)
+        jitted = jax.jit(
+            instruments.named_step(self.build_step_fn(), "query_step"),
+            donate_argnums=0)
         return self.app_context.telemetry.instrument_jit(
             jitted, f"query.{self.name}.step", family="query_step")
 
@@ -552,9 +554,9 @@ class QueryRuntime(Receiver):
             notify = None
             overflow = None
             if win is not None:
-                new_state["win"], cols = win.apply(state["win"],
-                                                   conform_cols(win, cols),
-                                                   ctx)
+                with jax.named_scope(instruments.STATE_SCOPE):
+                    new_state["win"], cols = win.apply(
+                        state["win"], conform_cols(win, cols), ctx)
                 cols = dict(cols)
                 notify = cols.pop("__notify__", None)
                 overflow = cols.pop("__overflow__", None)
@@ -569,21 +571,24 @@ class QueryRuntime(Receiver):
                             obj(cols, ctx) | ptimer)
             if strrank is not None:
                 cols[STR_RANK] = strrank
-            new_state["sel"], out = sel.apply(state["sel"], cols, ctx)
-            if notify is not None:
-                out["__notify__"] = notify
-            if overflow is not None:
-                sel_ov = out.get("__overflow__")
-                out["__overflow__"] = overflow if sel_ov is None else jnp.maximum(
-                    jnp.asarray(overflow).astype(jnp.int32),
-                    jnp.asarray(sel_ov).astype(jnp.int32))
-            out = pack_meta(out)
-            if islots:
-                # device instruments ride behind the [ov, notify, count]
-                # prefix — decoded by spec at drain (decode_meta_suffix)
-                out["__meta__"] = jnp.concatenate(
-                    [out["__meta__"]]
-                    + self._instrument_values(islots, new_state, cols))
+            with jax.named_scope(instruments.SELECT_SCOPE):
+                new_state["sel"], out = sel.apply(state["sel"], cols, ctx)
+            with jax.named_scope(instruments.META_SCOPE):
+                if notify is not None:
+                    out["__notify__"] = notify
+                if overflow is not None:
+                    sel_ov = out.get("__overflow__")
+                    out["__overflow__"] = overflow if sel_ov is None else jnp.maximum(
+                        jnp.asarray(overflow).astype(jnp.int32),
+                        jnp.asarray(sel_ov).astype(jnp.int32))
+                out = pack_meta(out)
+                if islots:
+                    # device instruments ride behind the [ov, notify,
+                    # count] prefix — decoded by spec at drain
+                    # (decode_meta_suffix)
+                    out["__meta__"] = jnp.concatenate(
+                        [out["__meta__"]]
+                        + self._instrument_values(islots, new_state, cols))
             return new_state, out
 
         return step
@@ -700,9 +705,9 @@ class QueryRuntime(Receiver):
 
     def process_batch(self, batch: HostBatch, junction=None):
         from siddhi_tpu.core.stream.junction import current_delivering_junction
-        from siddhi_tpu.observability.tracing import span
 
-        with span("query.step", query=self.name), self._lock:
+        with span("query.step", batch=journey.batch_of(batch),
+                  query=self.name), self._lock:
             # Event-path deliveries (Receiver.receive) carry no junction
             # parameter — fall back to the delivery-loop thread-local so
             # pipelined completions keep their error attribution and
@@ -870,11 +875,15 @@ class QueryRuntime(Receiver):
             sel = self.selector_plan
 
             def fn(sel_state, cols, now):
-                st2, out2 = sel.apply(sel_state, cols, {"xp": jnp, "current_time": now})
-                return st2, pack_meta(out2)
+                with jax.named_scope(instruments.SELECT_SCOPE):
+                    st2, out2 = sel.apply(
+                        sel_state, cols, {"xp": jnp, "current_time": now})
+                with jax.named_scope(instruments.META_SCOPE):
+                    return st2, pack_meta(out2)
 
             self._sel_step = self.app_context.telemetry.instrument_jit(
-                jax.jit(fn, donate_argnums=0),
+                jax.jit(instruments.named_step(fn, "selector"),
+                        donate_argnums=0),
                 f"query.{self.name}.selector", family="selector")
         else:
             self.app_context.telemetry.record_jit(
@@ -961,11 +970,7 @@ class QueryRuntime(Receiver):
                 # immediately), so device service is the blocking pull
                 jr.end_dispatch()
                 jr.pre_drain(journey.ready_of(meta))
-                _tp = time.perf_counter()
-                meta = self._pull_meta(meta)
-                jr.drained((time.perf_counter() - _tp) * 1000.0)
-            else:
-                meta = self._pull_meta(meta)
+            meta = self._pull_meta(meta, jr)
             self.decode_meta_suffix(meta)
             overflow = int(meta[0])
             notify = int(meta[1])
@@ -978,7 +983,8 @@ class QueryRuntime(Receiver):
                 raise FatalQueryError(
                     f"query '{self.name}': {msg} before creating the runtime")
             record_elapsed_ms(sm, self.name, t0)
-            self._timed_emit(HostBatch(out_host, size=size_hint), jr)
+            self._timed_emit(HostBatch(out_host, size=size_hint), jr,
+                             rows_out=size_hint)
             if notify >= 0:
                 return notify
             return None
@@ -998,34 +1004,40 @@ class QueryRuntime(Receiver):
             return int(notify)
         return None
 
-    def _timed_emit(self, out: HostBatch, jr) -> None:
-        """``_emit`` with the journey's emit stage timed and the journey
-        finished (histograms + ring) — the synchronous tail; pipelined
-        batches run the same accounting at drain (completion.py)."""
+    def _timed_emit(self, out: HostBatch, jr, rows_out=None) -> None:
+        """``_emit`` inside the journey's emit stage (``siddhi.emit``
+        span; at its close the journey is finished: histograms + ring) —
+        the synchronous tail; pipelined batches run the same accounting
+        at drain (completion.py)."""
         if jr is None:
             self._emit(out)
             return
-        t_e = time.perf_counter()
-        try:
+        with jr.emitting(self.app_context, (self.name,), rows_out):
             self._emit(out)
-        finally:
-            jr.emit_ms = (time.perf_counter() - t_e) * 1000.0
-            jr.finish(self.app_context, (self.name,))
 
-    def _pull_meta(self, meta):
-        """Pull the packed meta array; on a multi-process mesh with
-        ``siddhi_tpu.cluster_step_timeout`` set, bound the wait so a dead
-        peer surfaces as a labeled ClusterPeerError through the fault
-        machinery instead of hanging the coordinator (SURVEY.md §5.3)."""
-        timeout = getattr(self.app_context, "cluster_step_timeout", None)
-        if timeout is not None and self._shard_mesh is not None:
-            from siddhi_tpu.parallel.distributed import guarded_pull
+    def _pull_meta(self, meta, jr=None):
+        """Pull the packed meta array, under the ``siddhi.meta_pull``
+        span whose duration the batch's journey ``jr`` keeps; on a
+        multi-process mesh with ``siddhi_tpu.cluster_step_timeout`` set,
+        bound the wait so a dead peer surfaces as a labeled
+        ClusterPeerError through the fault machinery instead of hanging
+        the coordinator (SURVEY.md §5.3)."""
+        with span("meta_pull", query=self.name,
+                  batch=jr.batch if jr is not None else None) as sp:
+            timeout = getattr(self.app_context, "cluster_step_timeout", None)
+            if timeout is not None and self._shard_mesh is not None:
+                from siddhi_tpu.parallel.distributed import guarded_pull
 
-            return guarded_pull(meta, timeout,
-                                what=f"query '{self.name}' step")
-        # explicit pull: this is THE sanctioned per-batch round trip —
-        # the sanitizer's transfer guard rejects implicit d2h transfers
-        return np.asarray(jax.device_get(meta))
+                meta = guarded_pull(meta, timeout,
+                                    what=f"query '{self.name}' step")
+            else:
+                # explicit pull: this is THE sanctioned per-batch round
+                # trip — the sanitizer's transfer guard rejects implicit
+                # d2h transfers
+                meta = np.asarray(jax.device_get(meta))
+        if jr is not None:
+            jr.meta_pulled(sp.ms)
+        return meta
 
     @property
     def _defer_ok(self) -> bool:

@@ -402,7 +402,8 @@ class StreamJunction:
 
         if self._pending_mutations:
             self._drain_mutations()
-        with span("junction.dispatch", stream=self.definition.id,
+        with span("junction.dispatch", batch=journey.batch_of(batch),
+                  stream=self.definition.id,
                   rows=int(batch._size) if batch._size is not None else -1):
             prev = current_delivering_junction()
             _DELIVERING.junction = self

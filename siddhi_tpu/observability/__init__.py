@@ -10,12 +10,14 @@ show — and "Scaling Ordered Stream Processing on Shared-Memory
 Multicores" (PAPERS.md) makes the same point for ordered pipelines:
 diagnosis needs per-stage queue and latency instrumentation. Four parts:
 
-- ``tracing``:   lightweight structured spans (``span("compile")``,
-                 ``span("jit", key=...)``) — nested, thread-safe,
-                 ring-buffered, exported as Chrome-trace JSON
-                 (``chrome://tracing`` / Perfetto). Wired through
-                 compile → plan → jit → junction dispatch → query step →
-                 sink publish → persist.
+- ``tracing``:   ``span(...)``, the one span primitive — nested,
+                 thread-safe; ring-buffered and exported as Chrome-trace
+                 JSON (``chrome://tracing`` / Perfetto), and entered as
+                 ``siddhi.<name>`` annotations of a ``jax.profiler``
+                 trace, on the device's clock. Wired through compile →
+                 plan → jit → pack → junction dispatch → query step →
+                 meta pull → emit → output pull → sink publish →
+                 persist.
 - ``histogram``: fixed-bucket log-spaced (HDR-style) latency histograms
                  with p50/p95/p99, embedded in ``LatencyTracker`` so the
                  query/join/NFA runtimes, the @Async junction batcher,
@@ -30,8 +32,11 @@ diagnosis needs per-stage queue and latency instrumentation. Four parts:
                  (``service/rest.py``), with ``POST /trace/start|stop``
                  dumping a span file.
 
-Always-on-capable: ``tools/obs_overhead.py`` holds the e2e throughput
-with full instrumentation at >= 0.9x uninstrumented (PERF.md).
+Off, every instrumented site pays a flag check a batch. What spans and
+journeys cost while they are on was measured on the chip with the
+benchmark's cells: ``journey.enable()`` with no profiler session against
+everything off, and beside it what a profiler trace costs (PERF.md,
+section 6, PR 25).
 """
 
 from siddhi_tpu.observability.histogram import Histogram

@@ -43,11 +43,33 @@ to a drain consumer, bidirectionally.
 
 from __future__ import annotations
 
+import re
 import threading
 from collections import deque
 from typing import Dict, Optional
 
 import numpy as np
+
+# Names on the device. A profiler trace names a program after the
+# function that was jitted and an operation after the ``jax.named_scope``
+# it was traced in, so every step builder names its function from the
+# ``family`` it declares to ``instrument_jit`` (``named_step``) and
+# traces its body in the same three scopes: the window / NFA / join
+# stage, the selector, and the packed meta with these instrument lanes.
+# Metadata only: the compiled code is the same with and without them.
+# On the v5e the scope reaches the trace as the ``tf_op`` stat of an
+# ``XLA Ops`` event's metadata; the benchmark's ``step_state_ms`` and
+# ``step_select_ms`` read it (``benchmarks/metrics/_spans.py``).
+STATE_SCOPE = "siddhi.state"
+SELECT_SCOPE = "siddhi.select"
+META_SCOPE = "siddhi.meta"
+
+
+def named_step(fn, family: str):
+    """``fn`` named ``siddhi_<family>`` (before ``jax.jit`` sees it): XLA
+    then calls the program ``jit_siddhi_<family>``."""
+    fn.__name__ = fn.__qualname__ = "siddhi_" + re.sub(r"\W", "_", family)
+    return fn
 
 # data slot name -> human-readable structure label, used by
 # journey.critical_path_report to NAME the saturated device structure

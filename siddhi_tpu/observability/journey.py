@@ -1,5 +1,13 @@
 """Batch-journey tracing + critical-path attribution.
 
+A journey is the per-batch record; a span (``tracing.span``) is the
+per-stage one. Each stage below is opened with ``tracing.span`` at ONE
+site, which also stamps the journey from that span's duration, and
+``enable`` is the one switch (it sets ``tracing.profiler_spans`` for as
+long as anybody holds it): while journeys are on, every span is also a ``siddhi.<name>`` event of a profiler
+trace, carrying the journey's ``batch`` id (a per-process sequence
+number given at pack and kept by every fork of the journey).
+
 The spans of ``tracing.py`` time components in isolation; once the
 dispatch pipeline overlaps stages (``core/query/completion.py``,
 depth >= 2) they cannot say where a batch's END-TO-END latency actually
@@ -33,7 +41,22 @@ histograms on ``GET /metrics``):
                  ``device`` queueing/slack, NOT service). This is the
                  max-not-sum rule: when the host is the bottleneck the
                  ride must not ALSO count as device service.
-- ``emit``     — output decode + downstream publish (sink/junction).
+- ``emit``     — output decode + downstream publish (sink/junction),
+                 the user's callback and the output pull inside it.
+
+Two pulls cross from the device to the host, and the journey keeps them
+apart (neither is a stage of the glossary; both are counters of the
+ring record):
+
+- ``meta_pull`` — the packed ``__meta__`` array (overflow, notify, row
+                 count, instrument lanes): ``_pull_meta`` in the
+                 synchronous tail, the pump's one batched pull at drain.
+                 It is the round trip that waits for the step.
+- ``pull``      — the OUTPUT columns, pulled by ``LazyColumns`` when a
+                 consumer first reads one (inside ``emit``): ``pull_ms``
+                 and ``rows_padded`` (the length the columns were pulled
+                 at) beside ``rows_out`` (the meta's count of valid
+                 rows). The bytes are on the ``siddhi.pull`` span.
 
 Cost model: near-zero when off — every instrumented site checks one
 module flag and does nothing else. When on, a batch carries one small
@@ -52,10 +75,14 @@ Utilization = stage busy time / observed wall. Rendered by
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+from siddhi_tpu.observability import tracing
+from siddhi_tpu.observability.tracing import span
 
 STAGES = ("pack", "queue", "dispatch", "device", "emit")
 
@@ -82,8 +109,14 @@ _DELAYS: Dict[str, float] = {}
 
 # per-delivery-thread context: the @Async worker stamps the queue wait
 # of the unit it is about to deliver; every receiving query's journey
-# picks it up (one delivery fans out to N receivers)
+# picks it up (one delivery fans out to N receivers). ``emitting`` is
+# the journey whose emit stage is open on this thread: the output pull
+# inside it (LazyColumns) is charged there.
 _TLS = threading.local()
+
+# the batch id: one per packed batch (and per journey begun without a
+# pack stamp), shared by every span and every fork of that batch
+_BATCH_SEQ = itertools.count(1)
 
 
 def enabled() -> bool:
@@ -96,6 +129,7 @@ def enable(ring_capacity: Optional[int] = None) -> None:
     global _ENABLED, _enable_count, _RING
     with _lock:
         _enable_count += 1
+        tracing.profiler_spans(True)
         if not _ENABLED:
             _RING = deque(maxlen=int(ring_capacity or _DEFAULT_RING))
             _WALL.clear()
@@ -110,6 +144,7 @@ def disable(force: bool = False) -> None:
         _enable_count = 0 if force else max(0, _enable_count - 1)
         if _enable_count == 0:
             _ENABLED = False
+            tracing.profiler_spans(False)
 
 
 def forget_app(app_name: str) -> None:
@@ -193,10 +228,13 @@ class Journey:
     riding the batch's ``QueryCompletion``/``FusedCompletion`` through
     the pump, finished after emit. All timestamps host-monotonic."""
 
-    __slots__ = ("pack_ms", "queue_ms", "_t_disp0", "dispatch_ms",
-                 "_t_disp1", "_t_drain0", "ready", "pull_ms", "emit_ms")
+    __slots__ = ("batch", "pack_ms", "queue_ms", "_t_disp0", "dispatch_ms",
+                 "_t_disp1", "_t_drain0", "ready", "meta_pull_ms",
+                 "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded")
 
-    def __init__(self, pack_ms: Optional[float] = None):
+    def __init__(self, pack_ms: Optional[float] = None,
+                 batch: Optional[int] = None):
+        self.batch = next(_BATCH_SEQ) if batch is None else batch
         self.pack_ms = pack_ms
         self.queue_ms: Optional[float] = None
         self._t_disp0: Optional[float] = None
@@ -204,13 +242,17 @@ class Journey:
         self._t_disp1: Optional[float] = None
         self._t_drain0: Optional[float] = None
         self.ready: Optional[bool] = None
-        self.pull_ms = 0.0
+        self.meta_pull_ms = 0.0
         self.emit_ms = 0.0
+        self.pull_ms = 0.0
+        self.pulls = 0
+        self.rows_out: Optional[int] = None
+        self.rows_padded = 0
 
     # one journey object is stamped on the batch at pack time; each
     # receiving query forks its own (stage times are per query)
     def fork(self) -> "Journey":
-        return Journey(pack_ms=self.pack_ms)
+        return Journey(pack_ms=self.pack_ms, batch=self.batch)
 
     def begin_dispatch(self) -> None:
         self.queue_ms = _delivery_queue_ms()
@@ -227,8 +269,23 @@ class Journey:
         self._t_drain0 = time.perf_counter()
         self.ready = bool(ready)
 
-    def drained(self, pull_ms: float) -> None:
-        self.pull_ms = float(pull_ms)
+    def meta_pulled(self, ms: Optional[float]) -> None:
+        """The ``siddhi.meta_pull`` span's duration (one batched round
+        trip may serve several entries: each is attributed the round)."""
+        self.meta_pull_ms = float(ms or 0.0)
+
+    def pulled(self, ms: float, rows: int) -> None:
+        """One ``siddhi.pull`` inside this journey's emit stage
+        (``LazyColumns``), of columns ``rows`` long."""
+        self.pull_ms += ms
+        self.pulls += 1
+        self.rows_padded += rows
+
+    def emitting(self, app_context, names, rows_out: Optional[int] = None):
+        """The emit stage as a context manager: ``siddhi.emit`` span,
+        this journey current on the thread for the pulls inside it, and
+        at the close ``emit_ms`` stamped and the journey finished."""
+        return _EmitStage(self, app_context, names, rows_out)
 
     def device_times(self) -> Tuple[float, float]:
         """(service_ms, queue_ms) of the device stage — see the module
@@ -237,10 +294,10 @@ class Journey:
         if self._t_drain0 is not None and self._t_disp1 is not None:
             ride = max(0.0, (self._t_drain0 - self._t_disp1) * 1000.0)
         if self.ready is False:
-            return ride + self.pull_ms, 0.0
+            return ride + self.meta_pull_ms, 0.0
         # ready (or never observed): only the pull is known device work;
         # the ride was the finished output parked waiting for the host
-        return self.pull_ms, ride
+        return self.meta_pull_ms, ride
 
     def finish(self, app_context, names) -> None:
         """Record this journey's stage times into the app's telemetry
@@ -286,31 +343,89 @@ class Journey:
                 "device_service_ms": dev_service,
                 "device_queue_ms": dev_queue,
                 "emit_ms": self.emit_ms, "t": now,
+                # emit_ms includes the output pull: its self time is
+                # emit_ms - pull_ms. pull_ms is None where nothing was
+                # pulled (no consumer read a device column)
+                "batch": self.batch,
+                "meta_pull_ms": self.meta_pull_ms,
+                "pull_ms": self.pull_ms if self.pulls else None,
+                "rows_out": self.rows_out,
+                "rows_padded": self.rows_padded,
             })
 
 
-def stamp_pack(batch, t0: float) -> None:
-    """Attach a fresh journey (pack service = now - t0) to a batch just
-    built by ``HostBatch.from_events``/``from_columns``. Caller already
-    checked :func:`enabled` — this is the pack-stage stamp the rest of
-    the pipeline carries forward."""
-    batch.journey = Journey(pack_ms=(time.perf_counter() - t0) * 1000.0)
+class _EmitStage:
+    """``Journey.emitting``: the one place the emit stage is timed."""
+
+    __slots__ = ("jr", "app_context", "names", "_span", "_prev")
+
+    def __init__(self, jr, app_context, names, rows_out):
+        self.jr, self.app_context, self.names = jr, app_context, names
+        jr.rows_out = rows_out
+        self._span = span("emit", query=names[0], batch=jr.batch)
+
+    def __enter__(self):
+        self._prev = getattr(_TLS, "emitting", None)
+        _TLS.emitting = self.jr
+        self._span.__enter__()
+        return self.jr
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        _TLS.emitting = self._prev
+        self.jr.emit_ms = self._span.ms or 0.0
+        self.jr.finish(self.app_context, self.names)
+        return False
 
 
-def stamp_pack_ms(batch, pack_ms: float) -> None:
-    """Pack stamp with a caller-computed service time — the parallel
-    ingest pack path (``core/event._parallel_from_events``) attributes
-    max-over-sub-batches plus the serial merge, per the max-not-sum rule
-    (concurrent packer time must not count once per worker)."""
-    batch.journey = Journey(pack_ms=float(pack_ms))
+def emitting_journey() -> Optional[Journey]:
+    """The journey whose emit stage is open on this thread, if any."""
+    return getattr(_TLS, "emitting", None)
 
 
-def begin(batch) -> Journey:
+def pack_span():
+    """The ``siddhi.pack`` span of a batch about to be built
+    (``HostBatch.from_events``/``from_columns``), carrying the batch id
+    its journey will keep; the shared no-op when spans are off."""
+    if not tracing.spans_on():
+        return tracing.NOOP
+    return span("pack", batch=next(_BATCH_SEQ))
+
+
+def stamp_pack(batch, sp, pack_ms: Optional[float] = None) -> None:
+    """Attach the pack-stage stamp to the batch that ``sp`` (a closed
+    :func:`pack_span`) covered. Pack service is the span's duration,
+    unless the caller computed its own: the parallel ingest pack
+    (``core/event._parallel_from_events``) attributes max-over-sub-batches
+    plus the serial merge, per the max-not-sum rule (concurrent packer
+    time must not count once per worker)."""
+    if _ENABLED and sp.ms is not None:
+        batch.journey = Journey(
+            pack_ms=sp.ms if pack_ms is None else float(pack_ms),
+            batch=sp.args["batch"])
+
+
+def batch_of(batch) -> Optional[int]:
+    """The batch id a delivered batch carries from its pack, if any: what
+    the spans opened before the receiver's own journey begins
+    (``junction.dispatch``, ``query.step``) give as ``batch=``."""
+    jr = getattr(batch, "journey", None)
+    return jr.batch if jr is not None else None
+
+
+def begin(batch=None) -> Journey:
     """Per-receiver journey for a delivered batch: forks the batch's
     pack stamp (N receivers must not share mutable stage state) and
-    opens the dispatch stage."""
+    opens the dispatch stage. A batch without a stamp was not packed
+    here: re-published by the query whose emit stage is open on this
+    thread, it keeps that journey's batch id; with neither (an NFA timer
+    sweep) the journey starts here, with a batch id of its own."""
     src = getattr(batch, "journey", None)
-    jr = src.fork() if src is not None else Journey()
+    if src is not None:
+        jr = src.fork()
+    else:
+        up = emitting_journey()
+        jr = Journey(batch=up.batch if up is not None else None)
     jr.begin_dispatch()
     return jr
 

@@ -1,17 +1,32 @@
-"""Structured tracing spans with Chrome-trace export.
+"""Structured tracing spans: one primitive, two records.
 
 A span covers one host-side stage of the pipeline (compile, plan, jit,
-junction dispatch, query step, sink publish, persist) at batch
-granularity — the host-side complement of the XLA profiler trace
-(``SiddhiAppRuntime.start_trace``), which sees device ops but not the
-host pipeline between them.
+pack, junction dispatch, query step, meta pull, emit, output pull, sink
+publish, persist) at batch granularity. ``span(...)`` is the ONLY way
+the engine opens one. While it is on, a span
+
+- lands in the Chrome-trace ring of ``TRACER`` (when ``TRACER`` is
+  started: ``POST /trace/start``), and
+- enters ``jax.profiler.TraceAnnotation("siddhi.<name>", **args)``, so
+  that a profiler trace (``SiddhiAppRuntime.start_trace``, the
+  benchmark's ``--trace 1``) holds it on ``/host:CPU`` on the clock of
+  the device planes: an idle gap of the device can be put down to the
+  engine stage the host was in. Outside a profiler session the
+  annotation is a flag check inside the profiler.
+
+Spans are on while ``TRACER`` is started or batch journeys are enabled
+(``journey.enable`` sets ``profiler_spans`` for as long as anybody holds
+it: the benchmark's traced run, ``start_trace`` and the REST profiler
+routes). ``batch=`` in a span's arguments is the per-process sequence
+number of the batch (``journey.py``): the spans of one batch share it.
 
 Design constraints, in priority order:
 
-1. **Near-zero cost when disabled.** ``span(...)`` checks one module
-   flag and returns a shared no-op context manager — no allocation
-   beyond the kwargs dict, no locks. The hot path (junction dispatch,
-   query step) runs it per *batch*, not per event.
+1. **Near-zero cost when disabled.** ``span(...)`` checks the two
+   switches in one expression and returns a shared no-op context
+   manager — no allocation beyond the kwargs dict, no locks. The hot
+   path (pack, junction dispatch, query step) runs it per *batch*, not
+   per event.
 2. **Thread-safe when enabled.** Spans finish in LIFO order per thread
    (context managers), so nesting is correct by construction; the ring
    buffer is a ``deque(maxlen=...)`` whose appends are atomic under the
@@ -29,6 +44,8 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 _DEFAULT_CAPACITY = 65_536
 
@@ -48,6 +65,7 @@ class _NoopSpan:
     """Shared do-nothing context manager for the disabled path."""
 
     __slots__ = ()
+    ms = None     # a real span's duration once closed; None: not timed
 
     def __enter__(self):
         return self
@@ -56,23 +74,38 @@ class _NoopSpan:
         return False
 
 
-_NOOP = _NoopSpan()
+NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    """One open span. ``ms`` is its duration once it has closed: the
+    site that opened it stamps the batch's journey from it, so the two
+    records cannot drift."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann", "ms")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict,
+                 annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self.ms = None
+        self._ann = None
+        if annotate:
+            self._ann = TraceAnnotation("siddhi." + name, **{
+                k: _jsonable(v) for k, v in args.items() if v is not None})
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.ms = (t1 - self._t0) / 1e6
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
 
@@ -119,8 +152,9 @@ class Tracer:
     # ---------------------------------------------------------- recording
 
     def span(self, name: str, **args):
+        """A span of this tracer's ring only (no profiler annotation)."""
         if not self.enabled:
-            return _NOOP
+            return NOOP
         return _Span(self, name, args)
 
     def _record(self, name: str, t0_ns: int, t1_ns: int, args: dict):
@@ -183,10 +217,23 @@ def _jsonable(v):
 # POST /trace/start|stop on the REST service or Tracer.start()/stop()
 TRACER = Tracer()
 
+# set by journey.enable/disable from their own count of holders: while
+# true, spans enter their TraceAnnotation whether or not TRACER is started
+_profiler_spans = False
+
+
+def profiler_spans(on: bool) -> None:
+    global _profiler_spans
+    _profiler_spans = bool(on)
+
+
+def spans_on() -> bool:
+    return TRACER.enabled or _profiler_spans
+
 
 def span(name: str, **args):
-    """``with span("jit", query="q1"): ...`` — records a structured span
-    on the global tracer; a shared no-op when tracing is off."""
-    if not TRACER.enabled:
-        return _NOOP
-    return _Span(TRACER, name, args)
+    """``with span("jit", key="q1") as sp: ...`` — the engine's one span
+    primitive (module docstring); the shared no-op when spans are off."""
+    if not (TRACER.enabled or _profiler_spans):
+        return NOOP
+    return _Span(TRACER, name, args, annotate=True)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from siddhi_tpu.observability.instruments import named_step
+
 KEY_AXIS = "keys"
 
 
@@ -147,7 +149,7 @@ def shard_query_step(runtime, mesh: Mesh, donate: bool = True):
     state = jax.device_put(runtime._state, st_sh)
     out_sh = _out_shardings(mesh, st_sh)
     jitted = jax.jit(
-        step,
+        named_step(step, "gspmd_replicated_batch"),
         in_shardings=(st_sh, None, None),
         out_shardings=out_sh,
         donate_argnums=(0,) if donate else (),
@@ -331,7 +333,8 @@ def shard_keyed_query_step(runtime, mesh: Mesh, rows_per_shard: int):
         out_specs=(st_specs, P(KEY_AXIS)),
         check_vma=False,
     )
-    jitted = jax.jit(sharded, donate_argnums=(0,))
+    jitted = jax.jit(named_step(sharded, "shard_map_routed"),
+                     donate_argnums=(0,))
     tel = getattr(runtime.app_context, "telemetry", None)
     if tel is not None:
         jitted = tel.instrument_jit(
@@ -924,6 +927,8 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
         OKEY_KEY, PK_KEY, RIDX_KEY, VALID_KEY)
 
     layout = runtime._route_layout
+    # the program's name on the device: its instrument_jit family
+    routed_family = "device_routed" + (f".{side_key}" if side_key else "")
     n, Q = layout.n, layout.quota
     localK = layout.localK
     partitioned, use_lut = layout.partitioned, layout.use_lut
@@ -963,7 +968,8 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
             out["__meta__"] = jnp.concatenate(parts)
             return st, out
 
-        jitted = jax.jit(one_dev, donate_argnums=(0,))
+        jitted = jax.jit(named_step(one_dev, routed_family),
+                         donate_argnums=(0,))
         return _finish_routed_install(runtime, layout, jitted, side_key)
 
     axes = _routed_axes(runtime, layout, runtime._state)
@@ -1085,7 +1091,8 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
         out_specs=(st_specs, P()),
         check_vma=False,
     )
-    jitted = jax.jit(sharded, donate_argnums=(0,))
+    jitted = jax.jit(named_step(sharded, routed_family),
+                     donate_argnums=(0,))
     return _finish_routed_install(runtime, layout, jitted, side_key)
 
 

@@ -40,7 +40,10 @@ Observability (``siddhi_tpu/observability/``):
   disable it, dump Chrome-trace JSON under the trace base, return it inline
 
 (The per-app ``POST /apps/<name>/trace`` endpoint remains the XLA device
-profiler; ``/trace/*`` is the host-side span timeline.)
+profiler; ``/trace/*`` is the host-side span timeline. While a device
+profile runs, from either profiler route, the engine's spans are in it
+too: ``siddhi.<stage>`` events on ``/host:CPU``, each with the ``batch``
+sequence number of the batch it served.)
 
 Critical-path profiler (``observability/journey.py`` + ``costmodel.py``):
 
@@ -532,6 +535,8 @@ class SiddhiRestService:
         if what == "device":
             import jax
 
+            from siddhi_tpu.observability import journey
+
             if action == "start":
                 if self._device_tracing:
                     h._send(409, {"error": "a device profile is already "
@@ -546,14 +551,18 @@ class SiddhiRestService:
                                            "configured trace base"})
                     return
                 jax.profiler.start_trace(target)
+                journey.enable()    # the host spans, for the duration
                 self._device_tracing = target
                 h._send(200, {"device_profile": target})
             else:
                 if not self._device_tracing:
                     h._send(409, {"error": "no device profile is running"})
                     return
-                jax.profiler.stop_trace()
                 target, self._device_tracing = self._device_tracing, None
+                try:
+                    jax.profiler.stop_trace()
+                finally:
+                    journey.disable()    # released once, whatever happens
                 h._send(200, {"device_profile": None, "dir": target})
             return
         h._send(404, {"error": f"unknown path {h.path}"})

@@ -235,17 +235,26 @@ def test_sync_cascade_does_not_inherit_queue_wait():
 
 
 def test_journey_off_leaves_no_trace():
-    """Default config: no Journey objects ride the batches and no stage
-    histograms appear — the off path is one flag check."""
+    """Default config: no Journey objects ride the batches, no stage
+    histograms appear, the ring gains no record and the span primitive
+    hands out its shared no-op (so no profiler annotation is entered) —
+    the off path is one flag check."""
+    from siddhi_tpu.observability import tracing
+
     m = _manager(2)
     rt = m.create_siddhi_app_runtime(APP)
     rt.add_callback("Out", Collector())
     h = rt.get_input_handler("S")
+    ring_before = journey.ring()
+    assert not tracing.spans_on()
+    assert tracing.span("pack") is tracing.span("query.step", query="pq")
+    assert journey.pack_span() is tracing.NOOP is tracing.span("emit")
     for i in range(3):
         h.send(["A", i])
     hists = rt.app_context.telemetry.snapshot().get("histograms", {})
     assert not any(k.startswith("stage.") for k in hists)
     assert journey.critical_path_report(m)["apps"][rt.name]["queries"] == {}
+    assert journey.ring() == ring_before
     m.shutdown()
 
 

@@ -1,0 +1,375 @@
+"""The one span primitive (observability/tracing.py) on the profiler's
+clock: while tracing is on, the engine's stages are ``siddhi.<name>``
+events of a ``jax.profiler`` trace, nested as the layers are, sharing a
+``batch`` id; the journey's counters say what the pulled arrays say;
+step programs are named from their family and trace their body in the
+three scopes. CPU backend: what is in the trace, never how long it took.
+"""
+
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from siddhi_tpu import SiddhiManager, StreamCallback
+from siddhi_tpu.core.util.config import InMemoryConfigManager
+from siddhi_tpu.observability import journey
+from siddhi_tpu.observability.instruments import (META_SCOPE, SELECT_SCOPE,
+                                                  STATE_SCOPE)
+from siddhi_tpu.observability.tracing import TRACER, spans_on
+
+RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
+             "device_service_ms", "device_queue_ms", "emit_ms", "t",
+             "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded"}
+
+TWO_QUERIES = """
+define stream S (k string, v long);
+@info(name='q1')
+from S#window.length(8) select k, sum(v) as total group by k insert into O;
+@info(name='q2')
+from S[v > 1] select k, v insert into P;
+"""
+
+PATTERN = """
+@app:playback
+define stream AStream (k string, v double);
+define stream BStream (k string, v double);
+partition with (k of AStream, k of BStream)
+begin
+  @info(name = 'nfa')
+  from every e1=AStream -> e2=BStream[e2.v > e1.v] within 5 sec
+  select e1.v as v1, e2.v as v2
+  insert into MatchStream;
+end;
+"""
+
+JOIN = """
+define stream L (k string, v long);
+define stream R (k string, w long);
+@info(name='jq')
+from L#window.length(4) join R#window.length(4) on L.k == R.k
+select L.k as k, v, w insert into J;
+"""
+
+
+class Columns(StreamCallback):
+    """Reads every output column of every delivery, as the benchmark's
+    collector does: the pull happens here, inside the engine's emit."""
+
+    def __init__(self):
+        self.pulled = []
+
+    def receive_batch(self, batch, junction=None):
+        self.pulled.append({k: np.asarray(batch.cols[k])
+                            for k in list(batch.cols)})
+
+
+@pytest.fixture(autouse=True)
+def _tracing_off():
+    yield
+    journey.disable(force=True)
+    TRACER.enabled = False
+    TRACER.clear()
+
+
+def _manager(**config):
+    m = SiddhiManager()
+    m.set_config_manager(InMemoryConfigManager(
+        {f"siddhi_tpu.{k}": str(v) for k, v in config.items()}))
+    return m
+
+
+def _send(handler, i, n=3):
+    handler.send_columns(
+        {"k": np.array(["a", "b", "c"][:n], object),
+         "v": np.arange(1, n + 1) + i})
+
+
+def _engine_spans(trace_dir):
+    """{thread line: [(start, end, name, stats)]} of the ``siddhi.*``
+    events in the trace written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    by_line = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            got = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                          dict(e.stats)) for e in line.events
+                         if e.name.startswith("siddhi."))
+            if got:
+                by_line[line.name] = got
+    return by_line
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_profiler_trace_holds_the_engines_stages(tmp_path, depth):
+    m = _manager(pipeline_depth=depth, fuse_fanout="false")
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)
+    rt.add_callback("O", Columns())
+    rt.add_callback("P", Columns())
+    h = rt.get_input_handler("S")
+    _send(h, 0)                     # compiles, outside the trace
+    rt.start_trace(str(tmp_path))
+    assert journey.enabled() and spans_on()
+    for i in range(1, 4):
+        _send(h, i)
+    rt.stop_trace()
+    assert not journey.enabled() and not spans_on()
+    m.shutdown()
+
+    (spans,) = _engine_spans(tmp_path).values()   # the sender's thread
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp[2], []).append(sp)
+    assert {"siddhi.pack", "siddhi.junction.dispatch", "siddhi.query.step",
+            "siddhi.meta_pull", "siddhi.emit",
+            "siddhi.pull"} <= set(by_name)
+    # one batch id a batch, given at pack and shared by its spans
+    packs = by_name["siddhi.pack"]
+    ids = [sp[3]["batch"] for sp in packs]
+    assert len(ids) == 3 and len(set(ids)) == 3
+    for name in ("siddhi.query.step", "siddhi.meta_pull", "siddhi.emit",
+                 "siddhi.pull"):
+        assert {sp[3]["batch"] for sp in by_name[name]} == set(ids), name
+    # both queries step, pull their meta and emit for every batch
+    assert len(by_name["siddhi.query.step"]) == 6
+    assert len(by_name["siddhi.emit"]) == 6
+    assert sorted(sp[3]["query"] for sp in by_name["siddhi.emit"]) == \
+        ["q1"] * 3 + ["q2"] * 3
+    # nesting: pack before the junction; a step inside the input
+    # junction's dispatch; the output pull inside an emit; the meta pull
+    # and the emit in the step's synchronous tail at depth 1, at the
+    # pump's drain once the delivery has returned at depth 2
+    in_dispatch = [sp for sp in by_name["siddhi.junction.dispatch"]
+                   if sp[3]["stream"] == "S"]
+    assert len(in_dispatch) == 3
+    for pack, disp in zip(packs, in_dispatch):
+        assert pack[1] <= disp[0] and pack[3]["batch"] == disp[3]["batch"]
+    for step in by_name["siddhi.query.step"]:
+        assert any(_inside(step, d) for d in in_dispatch)
+    for pull in by_name["siddhi.pull"]:
+        assert any(_inside(pull, e) for e in by_name["siddhi.emit"])
+        assert pull[3]["bytes"] > 0 and pull[3]["arrays"] > 0
+    for tail in by_name["siddhi.meta_pull"] + by_name["siddhi.emit"]:
+        in_step = any(_inside(tail, s) for s in by_name["siddhi.query.step"])
+        assert in_step == (depth == 1), tail
+
+
+def test_an_nfa_app_leaves_journeys_with_every_ring_key():
+    m = _manager(pipeline_depth=2)
+    rt = m.create_siddhi_app_runtime(PATTERN)
+    out = Columns()
+    rt.add_callback("MatchStream", out)
+    a, b = rt.get_input_handler("AStream"), rt.get_input_handler("BStream")
+    keys = np.array([f"K{i}" for i in range(6)], object)
+
+    def round_(t, v):
+        a.send_columns({"k": keys, "v": np.zeros(6)},
+                       timestamps=np.full(6, t, np.int64))
+        b.send_columns({"k": keys, "v": np.full(6, v)},
+                       timestamps=np.full(6, t + 1, np.int64))
+
+    round_(1_000, 1.0)              # compiles both steps
+    journey.enable()
+    round_(2_000, 1.0)
+    round_(3_000, 1.0)
+    ring = journey.ring()
+    journey.disable()
+    m.shutdown()
+    assert len(ring) == 4           # A, B, A, B
+    for rec in ring:
+        assert set(rec) == RING_KEYS
+        assert rec["queries"] == ["nfa"] and rec["pack_ms"] > 0
+        assert rec["dispatch_ms"] > 0 and rec["meta_pull_ms"] > 0
+    assert len({rec["batch"] for rec in ring}) == 4
+    heads, tails = ring[0::2], ring[1::2]
+    # a head batch arms and emits nothing: no callback, so no pull
+    assert all(r["rows_out"] == 0 and r["pull_ms"] is None for r in heads)
+    assert all(r["rows_out"] == 6 and r["pull_ms"] > 0 for r in tails)
+    assert sum(len(p["v2"]) > 0 for p in out.pulled) >= 2
+
+
+def test_pull_counters_equal_what_the_arrays_say():
+    m = _manager(pipeline_depth=2)
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES.split("@info(name='q2')")[0])
+    out = Columns()
+    rt.add_callback("O", out)
+    h = rt.get_input_handler("S")
+    _send(h, 0, n=3)
+    journey.enable()
+    TRACER.start()                  # the span's arguments, in its ring
+    _send(h, 1, n=3)
+    (rec,) = journey.ring()
+    (pull,) = [e for e in TRACER.stop()["traceEvents"]
+               if e["name"] == "pull"]
+    journey.disable()
+    m.shutdown()
+    cols = out.pulled[-1]
+    lengths = {len(v) for v in cols.values()}
+    assert len(lengths) == 1        # every column at the padded length
+    assert rec["rows_padded"] == lengths.pop()
+    assert rec["rows_out"] == int(cols["__valid__"].sum()) == 3
+    assert pull["args"]["bytes"] == sum(v.nbytes for v in cols.values())
+    assert pull["args"]["arrays"] == len(cols)
+    assert pull["args"]["batch"] == rec["batch"]
+    # emit holds the pull: its self time is emit_ms - pull_ms
+    assert 0 < rec["pull_ms"] <= rec["emit_ms"]
+
+
+def test_a_trace_gives_back_its_own_hold_once(tmp_path, monkeypatch):
+    """``stop_trace`` releases the hold ``start_trace`` took even when
+    the profiler fails to stop, and a retry cannot release a second one
+    (another holder's); shutdown stops a trace left running."""
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)
+    journey.enable()                # somebody else's hold
+    rt.start_trace(str(tmp_path))
+    stop = jax.profiler.stop_trace
+
+    def failing_stop():
+        stop()
+        raise RuntimeError("the profiler could not write")
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", failing_stop)
+    with pytest.raises(RuntimeError, match="could not write"):
+        rt.stop_trace()
+    with pytest.raises(RuntimeError, match="no trace is running"):
+        rt.stop_trace()
+    assert journey.enabled() and spans_on()      # the other hold stands
+    journey.disable()
+    assert not journey.enabled() and not spans_on()
+    monkeypatch.undo()
+    rt.start_trace(str(tmp_path / "second"))
+    m.shutdown()
+    assert not journey.enabled() and not spans_on()
+
+
+def test_off_the_columns_pull_without_a_span_or_a_journey(tmp_path):
+    """Spans off while a profiler trace runs (``jax.profiler`` taken
+    directly): no ``siddhi.*`` event in it, no ring record."""
+    m = _manager(pipeline_depth=2)
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)
+    out = Columns()
+    rt.add_callback("O", out)
+    h = rt.get_input_handler("S")
+    _send(h, 0)
+    assert not spans_on()
+    ring_before = journey.ring()     # what an earlier enable left
+    jax.profiler.start_trace(str(tmp_path))
+    _send(h, 1)
+    jax.profiler.stop_trace()
+    m.shutdown()
+    assert len(out.pulled) == 2 and _engine_spans(tmp_path) == {}
+    assert journey.ring() == ring_before
+
+
+def _spy(q, seen, key=None):
+    """Records the jitted step and the arguments of every dispatch of a
+    (single-stream or join) runtime."""
+    finish = q._finish_device_batch
+
+    def spying_finish(step, cols, overflow_msg):
+        def spy(*args):
+            seen[key or q.name] = (step, args)
+            return step(*args)
+
+        return finish(spy, cols, overflow_msg)
+
+    q._finish_device_batch = spying_finish
+
+
+class _SpyingSteps(dict):
+    """``NFAQueryRuntime._steps`` stand-in: remembers the last call of
+    each jitted step (from its second lookup on)."""
+
+    def __init__(self, seen):
+        super().__init__()
+        self.seen = seen
+
+    def __setitem__(self, key, step):
+        def spy(*args):
+            self.seen[key] = (step, args)
+            return step(*args)
+
+        super().__setitem__(key, spy)
+
+
+def _abstract(seen):
+    return {k: (step, jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.result_type(x)),
+        args)) for k, (step, args) in seen.items()}
+
+
+def _query_step():
+    m = _manager(fuse_fanout="false")
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)
+    seen = {}
+    _spy(rt.query_runtimes["q1"], seen)
+    _send(rt.get_input_handler("S"), 0)
+    m.shutdown()
+    return _abstract(seen), {"q1": "jit_siddhi_query_step"}
+
+
+def _nfa_steps():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(PATTERN)
+    seen = {}
+    rt.query_runtimes["nfa"]._steps = _SpyingSteps(seen)
+    keys = np.array(["x", "y"], object)
+    for t in (1_000, 2_000):
+        rt.get_input_handler("AStream").send_columns(
+            {"k": keys, "v": np.zeros(2)}, timestamps=np.full(2, t))
+        rt.get_input_handler("BStream").send_columns(
+            {"k": keys, "v": np.ones(2)}, timestamps=np.full(2, t + 1))
+    m.shutdown()
+    return _abstract(seen), {
+        ("AStream", False): "jit_siddhi_nfa_step_AStream",
+        ("BStream", False): "jit_siddhi_nfa_step_BStream"}
+
+
+def _join_sides():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(JOIN)
+    q = rt.query_runtimes["jq"]
+    one = np.array(["a"], object)
+    rt.get_input_handler("L").send_columns({"k": one, "v": np.array([1])})
+    rt.get_input_handler("R").send_columns({"k": one, "w": np.array([2])})
+    m.shutdown()
+    return dict(q._steps), {"left": "siddhi_device_join_left",
+                            "right": "siddhi_device_join_right"}
+
+
+@pytest.mark.parametrize("family", ["query_step", "nfa_step"])
+def test_step_programs_are_named_by_family_and_carry_the_scopes(family):
+    steps, want = {"query_step": _query_step, "nfa_step": _nfa_steps}[family]()
+    assert set(steps) == set(want)
+    for key, (step, args) in steps.items():
+        lowered = step.lower(*args)
+        text = lowered.as_text(debug_info=True)
+        assert f"module @{want[key]}" in text, key
+        # the three scopes, the same in every family
+        for scope in (STATE_SCOPE, SELECT_SCOPE, META_SCOPE):
+            assert scope in text, (key, scope)
+        # and in what XLA keeps after optimisation: op_name metadata
+        assert STATE_SCOPE in lowered.compile().as_text()
+
+
+def test_fused_and_join_programs_are_named_by_family():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)     # fuses q1 and q2
+    _send(rt.get_input_handler("S"), 0)
+    (group,) = {q._fanout_group for q in rt.query_runtimes.values()}
+    assert group._step.__name__ == "siddhi_fused_fanout"
+    m.shutdown()
+    steps, want = _join_sides()
+    assert {side: step.__name__ for side, step in steps.items()} == want
