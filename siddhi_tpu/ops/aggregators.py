@@ -15,7 +15,7 @@ with:
 The scan sorts the batch by (group, position), pre-folds persistent state
 into each group's first row, marks segment starts / in-batch RESET epochs as
 "blocked" rows, runs ``lax.associative_scan`` with the aggregator's combine
-op, and scatters the last-row-per-group values back into the state.
+op, and has each group read its last row's value back into the state.
 
 Invertible aggregators (sum/count/avg/stdDev/and/or) encode EXPIRED as
 negative deltas. min/max over windows that emit EXPIRED events need the
@@ -406,6 +406,16 @@ def _apply_distinct(spec: AggSpec, st: dict, cols: dict, ctx: dict,
     return new_st, cols
 
 
+# Key capacity per batch row above which the write-back scatters the batch
+# instead of reading K-wide. TPU v5e, two [2, K] 64-bit aggregates (PR 26,
+# PERF.md): the scatter costs 71 ns a row and aggregate, the read 5 ns a
+# row once plus 22 ns a key and aggregate above 32,768 keys. Scatter / read
+# ms at B = 4,096: 0.72 / 0.42 (K = 32,768), 1.11 / 1.44 (65,536), 3.35 /
+# 5.87 (131,072); at K = 131,072 they meet at B = 16,384 (5.36 / 5.95) and
+# the read wins beyond (B = 131,072: 29.4 / 15.6).
+_SCATTER_ABOVE_KEYS_PER_ROW = 8
+
+
 def apply_aggregators(specs: List[AggSpec], state: dict, cols: dict, ctx: dict,
                       num_keys: int) -> Tuple[dict, dict]:
     """Run all aggregator scans for one batch.
@@ -413,6 +423,12 @@ def apply_aggregators(specs: List[AggSpec], state: dict, cols: dict, ctx: dict,
     Requires cols['__gk__'] (int32 group ids; all-zero when no group-by).
     Adds per-spec output columns spec.out_key (+ '?' null masks) with the
     post-event running value for every row. Returns (new_state, cols).
+
+    The new [slots, K] state is formed K-wide: every key reads the scanned
+    value at its group's last sorted row (found by one 32-bit scatter of
+    row positions shared by all aggregates) or keeps its old value. Only
+    where the key capacity dwarfs the batch (the static shapes, see
+    ``_SCATTER_ABOVE_KEYS_PER_ROW``) are the batch's values scattered.
     """
     xp = ctx["xp"]
     gk = cols["__gk__"]
@@ -448,6 +464,20 @@ def apply_aggregators(specs: List[AggSpec], state: dict, cols: dict, ctx: dict,
 
     last_of_group = jnp.concatenate([gk_sorted[1:] != gk_sorted[:-1], jnp.ones(1, bool)])
     in_final_epoch = epoch_sorted == final_epoch
+    # Write-back. ``landing[i]`` is the key whose new state is sorted row
+    # i's scanned value (a group's last row, in the final epoch), else the
+    # drop index ``num_keys``: at most one row lands per key, so a key can
+    # READ its row. Scattering the B scanned values themselves is, for
+    # 64-bit state (emulated as two 32-bit planes), a two-operand scatter,
+    # which the chip's compiler does not sort first.
+    landing = jnp.where(last_of_group & in_final_epoch & (gk_sorted < num_keys),
+                        gk_sorted, num_keys)
+    read_landing_rows = num_keys <= _SCATTER_ABOVE_KEYS_PER_ROW * B
+    if read_landing_rows:
+        last = jnp.full(num_keys + 1, -1, jnp.int32).at[landing].set(
+            jnp.arange(B, dtype=jnp.int32))[:num_keys]
+        touched = last >= 0
+        last = jnp.maximum(last, 0)
 
     new_state = dict(state)
     cols = dict(cols)
@@ -485,9 +515,10 @@ def apply_aggregators(specs: List[AggSpec], state: dict, cols: dict, ctx: dict,
         base = jnp.where(any_reset,
                          jnp.broadcast_to(idents[:, None], st.shape).astype(dtype),
                          st)
-        upd_mask = last_of_group & in_final_epoch & (gk_sorted < num_keys)
-        scatter_idx = jnp.where(upd_mask, gk_sorted, num_keys)  # drop non-updates
-        new_state[key] = base.at[:, scatter_idx].set(scanned, mode="drop")
+        if read_landing_rows:
+            new_state[key] = jnp.where(touched[None, :], scanned[:, last], base)
+        else:
+            new_state[key] = base.at[:, landing].set(scanned, mode="drop")
 
         value, null_mask = _output(spec, [out[s] for s in range(spec.slots)], ctx)
         value = value.astype(T.dtype_of(spec.out_type))

@@ -47,7 +47,13 @@ def _per_key_layout(pk, valid_cur, num_keys: int):
     """Group batch rows by key: returns (order, inv_order, occ, counts,
     start_pos) where occ[i] is row i's arrival rank within its key this
     batch, counts is [K] per-key insert count, and start_pos[i] is the
-    sorted-array position of the first row of row i's key."""
+    sorted-array position of the first row of row i's key.
+
+    counts is read off the sorted batch: a key's count is its last row's
+    rank + 1, written by one 32-bit scatter from the segment ends (unique
+    keys) and widened after. A histogram ``zeros(int64).at[pk].add(1)`` is
+    a two-plane scatter-add on the chip: 5.55 ms for 65,536 rows against
+    0.33 (TPU v5e, PR 26)."""
     B = pk.shape[0]
     safe_pk = jnp.where(valid_cur, pk, num_keys).astype(jnp.int32)
     order = jnp.argsort(safe_pk, stable=True)
@@ -59,7 +65,10 @@ def _per_key_layout(pk, valid_cur, num_keys: int):
     occ_sorted = sidx - start_pos_sorted
     occ = occ_sorted[inv_order]
     start_pos = start_pos_sorted[inv_order]
-    counts = jnp.zeros(num_keys + 1, jnp.int64).at[safe_pk].add(1)[:num_keys]
+    seg_end = jnp.concatenate([seg_start[1:], jnp.ones(1, bool)])
+    counts = jnp.zeros(num_keys + 1, jnp.int32).at[
+        jnp.where(seg_end, pk_sorted, num_keys)].set(
+            occ_sorted.astype(jnp.int32) + 1)[:num_keys].astype(jnp.int64)
     return order, inv_order, occ, counts, start_pos
 
 

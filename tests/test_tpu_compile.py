@@ -12,6 +12,7 @@ a chip run.
 All such compiles live in this ONE file, so one worker holds the library.
 """
 
+import re
 import time
 
 import jax
@@ -123,6 +124,18 @@ def _report(name, compiled, seconds):
     return m
 
 
+_SCATTER = re.compile(
+    r'= (.*?) (?:scatter|fusion)\(.*op_name="[^"]*/(siddhi\.\w+)/(scatter(?:-add)?)"')
+
+
+def _scatters(hlo_text):
+    """``(scope, primitive, result type)`` of every scatter of a compiled
+    step, layouts stripped: ``("siddhi.state", "scatter", "s32[129]")``;
+    an emulated 64-bit one reads ``(u32[128000], u32[128000])``."""
+    return [(m[2], m[3], re.sub(r"\{[^}]*\}", "", m[1]))
+            for m in map(_SCATTER.search, hlo_text.splitlines()) if m]
+
+
 def _stock_step(precision, partitioned, window, keys, batch):
     """The last step dispatch of one warm batch that covers every key at
     the measured batch shape — what chip_smoke.py compiles in warm-up."""
@@ -173,6 +186,22 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch):
     rows = max(a.shape[0] for a in jax.tree_util.tree_leaves(avals[0]["win"]))
     assert rows >= keys * 1_000          # the rings really are [K * W]
     assert m.argument_size_in_bytes > rows
+    # K-wide state (the aggregates, the per-key counts) is written back
+    # from the sorted batch's segment ends: at most ONE one-operand 32-bit
+    # scatter of positions or counts into [K + 1]. A tuple result is an
+    # emulated 64-bit value (two 32-bit planes), which gets no sorted path
+    # on the chip: 71-96 ns an update against 5 (PERF.md, PR 26).
+    capacity = avals[0]["sel"]["a0"].shape[1]
+    scatters = _scatters(compiled.as_text())
+    assert any(scope == "siddhi.state" for scope, _, _ in scatters)
+    for scope, primitive, result in scatters:
+        if scope == "siddhi.select":
+            assert (primitive, result) == ("scatter", f"s32[{capacity + 1}]"), (
+                f"a batch-wide write-back into selector state is back: "
+                f"{scope}/{primitive} -> {result}")
+        elif primitive == "scatter-add":
+            assert not result.startswith("("), (
+                f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
 
 
 # (b) phase C: the two-step NFA at K = 16,384 partition-key slots.
