@@ -130,7 +130,7 @@ class QueryCompletion:
                 # an exchange overflow is fatal for this batch exactly
                 # like a capacity overflow
                 try:
-                    check(meta)
+                    check(meta, self.journey)
                 except FatalQueryError as routed_err:
                     return routed_err
             if overflow > 0:

@@ -460,7 +460,7 @@ class QueryRuntime(Receiver):
             return float(rl.n * rl.quota) if rl is not None else None
         return None
 
-    def decode_meta_suffix(self, meta) -> None:
+    def decode_meta_suffix(self, meta, jr=None) -> None:
         """Drain-side decoder of the meta suffix, shared by the
         synchronous tail, the CompletionPump drain, the deferred flush
         and the fused fan-out per-member path: walk the spec, record
@@ -500,15 +500,19 @@ class QueryRuntime(Receiver):
             if slot.kind == "check":
                 checks.append((slot, vals))
             else:
-                self._record_instrument(slot, vals, telemetry=ins_on)
+                self._record_instrument(slot, vals, telemetry=ins_on, jr=jr)
         for slot, vals in checks:
             self._consume_check_slot(slot.name, vals)
 
-    def _record_instrument(self, slot: Slot, vals, telemetry: bool) -> None:
+    def _record_instrument(self, slot: Slot, vals, telemetry: bool,
+                           jr=None) -> None:
         self._instr_last[slot.name] = vals
         if slot.name == "shard_rows" and self._route_layout is not None:
             # back-compat mirror (skew debugging reads it directly)
             self._route_layout.last_shard_rows = vals
+            if jr is not None:
+                jr.shards_filled(int(vals.max(initial=0)),
+                                 self._instrument_capacity(slot.name))
         if telemetry:
             instruments.record(self, slot, vals,
                                capacity=self._instrument_capacity(slot.name))
@@ -816,7 +820,13 @@ class QueryRuntime(Receiver):
                 from siddhi_tpu.parallel.mesh import prepare_routed_batches
 
                 notify = None
-                for piece in prepare_routed_batches(self, cols):
+                jr = self._cur_journey
+                with span("route.prepare", query=self.name,
+                          batch=jr.batch if jr is not None else None) as sp:
+                    pieces = prepare_routed_batches(self, cols)
+                if jr is not None:
+                    jr.route_prepared(sp.ms, len(pieces))
+                for piece in pieces:
                     nt = self._finish_device_batch(
                         self._step, piece, self.overflow_knob_msg())
                     if nt is not None:
@@ -971,7 +981,7 @@ class QueryRuntime(Receiver):
                 jr.end_dispatch()
                 jr.pre_drain(journey.ready_of(meta))
             meta = self._pull_meta(meta, jr)
-            self.decode_meta_suffix(meta)
+            self.decode_meta_suffix(meta, jr)
             overflow = int(meta[0])
             notify = int(meta[1])
             size_hint = int(meta[2])

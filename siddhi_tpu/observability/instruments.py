@@ -63,6 +63,14 @@ import numpy as np
 STATE_SCOPE = "siddhi.state"
 SELECT_SCOPE = "siddhi.select"
 META_SCOPE = "siddhi.meta"
+# The device-routed step (``parallel/mesh.py`` ``routed_step_for``) wraps
+# that body in two more, beside the three and never around them: ingress
+# (owner, bucketing, the exchange, the id rewrite) and egress (the order
+# keys' gather and sort, the columns' gather and permutation, the meta's
+# cross-shard reductions). The benchmark's ``step_route_ms`` and
+# ``step_merge_ms`` read them (``benchmarks/metrics/_route.py``).
+ROUTE_SCOPE = "siddhi.route"
+MERGE_SCOPE = "siddhi.merge"
 
 
 def named_step(fn, family: str):

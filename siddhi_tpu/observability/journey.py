@@ -58,6 +58,15 @@ ring record):
                  at) beside ``rows_out`` (the meta's count of valid
                  rows). The bytes are on the ``siddhi.pull`` span.
 
+A device-routed query (``parallel/mesh.py``) stamps four more counters
+of the ring record, ``None`` for every other query: ``route_prep_ms`` and
+``route_pieces`` from the ``siddhi.route.prepare`` span around
+``prepare_routed_batches`` (host work inside ``dispatch``; pieces > 1: a
+source-destination pair exceeded its quota and the batch was split), and
+at the drain ``shard_rows_max`` (the fullest shard's received rows, from
+the meta's ``rows_0..n-1`` lanes) beside ``shard_capacity`` (``n x Q``).
+A split batch's journey rides its first piece.
+
 Cost model: near-zero when off — every instrumented site checks one
 module flag and does nothing else. When on, a batch carries one small
 ``Journey`` object (a handful of floats); finished journeys land in
@@ -230,7 +239,9 @@ class Journey:
 
     __slots__ = ("batch", "pack_ms", "queue_ms", "_t_disp0", "dispatch_ms",
                  "_t_disp1", "_t_drain0", "ready", "meta_pull_ms",
-                 "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded")
+                 "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded",
+                 "route_prep_ms", "route_pieces", "shard_rows_max",
+                 "shard_capacity")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -248,6 +259,10 @@ class Journey:
         self.pulls = 0
         self.rows_out: Optional[int] = None
         self.rows_padded = 0
+        self.route_prep_ms: Optional[float] = None
+        self.route_pieces: Optional[int] = None
+        self.shard_rows_max: Optional[int] = None
+        self.shard_capacity: Optional[float] = None
 
     # one journey object is stamped on the batch at pack time; each
     # receiving query forks its own (stage times are per query)
@@ -280,6 +295,18 @@ class Journey:
         self.pull_ms += ms
         self.pulls += 1
         self.rows_padded += rows
+
+    def route_prepared(self, ms: Optional[float], pieces: int) -> None:
+        """The ``siddhi.route.prepare`` span's duration and the pieces
+        the routed batch went to the device in."""
+        self.route_prep_ms = ms
+        self.route_pieces = pieces
+
+    def shards_filled(self, rows_max: int, capacity: Optional[float]) -> None:
+        """The drained meta's ``shard_rows`` lanes: the fullest shard's
+        received rows, and what a shard can receive."""
+        self.shard_rows_max = rows_max
+        self.shard_capacity = capacity
 
     def emitting(self, app_context, names, rows_out: Optional[int] = None):
         """The emit stage as a context manager: ``siddhi.emit`` span,
@@ -351,6 +378,11 @@ class Journey:
                 "pull_ms": self.pull_ms if self.pulls else None,
                 "rows_out": self.rows_out,
                 "rows_padded": self.rows_padded,
+                # a device-routed query's; None for every other
+                "route_prep_ms": self.route_prep_ms,
+                "route_pieces": self.route_pieces,
+                "shard_rows_max": self.shard_rows_max,
+                "shard_capacity": self.shard_capacity,
             })
 
 

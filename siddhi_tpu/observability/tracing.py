@@ -1,9 +1,9 @@
 """Structured tracing spans: one primitive, two records.
 
 A span covers one host-side stage of the pipeline (compile, plan, jit,
-pack, junction dispatch, query step, meta pull, emit, output pull, sink
-publish, persist) at batch granularity. ``span(...)`` is the ONLY way
-the engine opens one. While it is on, a span
+pack, junction dispatch, query step, route prepare, meta pull, emit,
+output pull, sink publish, persist) at batch granularity. ``span(...)``
+is the ONLY way the engine opens one. While it is on, a span
 
 - lands in the Chrome-trace ring of ``TRACER`` (when ``TRACER`` is
   started: ``POST /trace/start``), and

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from siddhi_tpu.observability.instruments import named_step
+from siddhi_tpu.observability.instruments import (
+    MERGE_SCOPE, ROUTE_SCOPE, named_step)
 
 KEY_AXIS = "keys"
 
@@ -927,9 +928,14 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
         OKEY_KEY, PK_KEY, RIDX_KEY, VALID_KEY)
 
     layout = runtime._route_layout
-    # the program's name on the device: its instrument_jit family
-    routed_family = "device_routed" + (f".{side_key}" if side_key else "")
     n, Q = layout.n, layout.quota
+    # the program's name on the device: its instrument_jit family and the
+    # mesh's width. The name is part of JAX's compile-cache key and the
+    # scopes below are not (debug info is stripped from the key), so a
+    # program cached before it was traced in ``siddhi.route`` /
+    # ``siddhi.merge`` would come back without them in a profiler trace
+    routed_family = ("device_routed" + (f".{side_key}" if side_key else "")
+                     + f"_x{n}")
     localK = layout.localK
     partitioned, use_lut = layout.partitioned, layout.use_lut
     # device instruments (observability/instruments.py): the inner step
@@ -954,18 +960,21 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
     if n == 1:
         def one_dev(state, cols, luts, now):
             cols = dict(cols)
-            B = cols[VALID_KEY].shape[0]
-            cols[RIDX_KEY] = jnp.arange(B, dtype=jnp.int64)
-            rows = jnp.sum(cols[VALID_KEY], dtype=jnp.int64)
+            with jax.named_scope(ROUTE_SCOPE):
+                B = cols[VALID_KEY].shape[0]
+                cols[RIDX_KEY] = jnp.arange(B, dtype=jnp.int64)
+                rows = jnp.sum(cols[VALID_KEY], dtype=jnp.int64)
             st, out = step(state, cols, now)
             out = dict(out)
             meta = out.pop("__meta__")
             out.pop(OKEY_KEY, None)   # single shard: already in order
-            parts = [meta[:3], jnp.zeros(1, jnp.int64), rows[None]]
-            if ins_on:
-                parts.append(jnp.full((1,), n * Q, jnp.int64) - rows[None])
-            parts.append(meta[3:])    # inner step's instrument lanes
-            out["__meta__"] = jnp.concatenate(parts)
+            with jax.named_scope(MERGE_SCOPE):
+                parts = [meta[:3], jnp.zeros(1, jnp.int64), rows[None]]
+                if ins_on:
+                    parts.append(
+                        jnp.full((1,), n * Q, jnp.int64) - rows[None])
+                parts.append(meta[3:])    # inner step's instrument lanes
+                out["__meta__"] = jnp.concatenate(parts)
             return st, out
 
         jitted = jax.jit(named_step(one_dev, routed_family),
@@ -985,101 +994,113 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
     def wrapped(state, cols, luts, now):
         state = jax.tree_util.tree_map(
             lambda leaf, ax: leaf[0] if ax < 0 else leaf, state, axes)
-        me = jax.lax.axis_index(KEY_AXIS)
-        valid = cols[VALID_KEY]
-        Bl = valid.shape[0]
-        ridx = me.astype(jnp.int64) * Bl + jnp.arange(Bl, dtype=jnp.int64)
-        # owner shard per local row (invalid rows route nowhere)
-        owner = jnp.where(valid, cols[key_name].astype(jnp.int64) % n,
-                          jnp.int64(n))
-        dest = jnp.arange(n, dtype=jnp.int64)[:, None]
-        maskd = owner[None, :] == dest                        # [n, Bl]
-        pos = jnp.cumsum(maskd.astype(jnp.int64), axis=1) - 1
-        # per-ROW slot: each row has exactly one destination, so every
-        # column scatters once at [Bl] cost (an [n*Bl] broadcast-scatter
-        # here would n-fold the hot loop's scatter bandwidth)
-        owner_c = jnp.clip(owner, 0, n - 1).astype(jnp.int32)
-        pos_row = jnp.take_along_axis(pos, owner_c[None, :], axis=0)[0]
-        sendable = owner < n                                  # valid rows
-        sent_row = sendable & (pos_row < Q)
-        route_ov = jnp.sum((sendable & ~sent_row).astype(jnp.int64))
-        slot_row = jnp.where(sent_row, owner * Q + pos_row, jnp.int64(n * Q))
+        with jax.named_scope(ROUTE_SCOPE):
+            me = jax.lax.axis_index(KEY_AXIS)
+            valid = cols[VALID_KEY]
+            Bl = valid.shape[0]
+            ridx = (me.astype(jnp.int64) * Bl
+                    + jnp.arange(Bl, dtype=jnp.int64))
+            # owner shard per local row (invalid rows route nowhere)
+            owner = jnp.where(valid, cols[key_name].astype(jnp.int64) % n,
+                              jnp.int64(n))
+            dest = jnp.arange(n, dtype=jnp.int64)[:, None]
+            maskd = owner[None, :] == dest                    # [n, Bl]
+            pos = jnp.cumsum(maskd.astype(jnp.int64), axis=1) - 1
+            # per-ROW slot: each row has exactly one destination, so
+            # every column scatters once at [Bl] cost (an [n*Bl]
+            # broadcast-scatter here would n-fold the hot loop's scatter
+            # bandwidth)
+            owner_c = jnp.clip(owner, 0, n - 1).astype(jnp.int32)
+            pos_row = jnp.take_along_axis(pos, owner_c[None, :], axis=0)[0]
+            sendable = owner < n                              # valid rows
+            sent_row = sendable & (pos_row < Q)
+            route_ov = jnp.sum((sendable & ~sent_row).astype(jnp.int64))
+            slot_row = jnp.where(sent_row, owner * Q + pos_row,
+                                 jnp.int64(n * Q))
 
-        def exch(col):
-            buf = jnp.zeros((n * Q,) + col.shape[1:], col.dtype)
-            buf = buf.at[slot_row].set(col, mode="drop")
-            return exchange(buf)
+            def exch(col):
+                buf = jnp.zeros((n * Q,) + col.shape[1:], col.dtype)
+                buf = buf.at[slot_row].set(col, mode="drop")
+                return exchange(buf)
 
-        rcols = {k: exch(v) for k, v in cols.items()}
-        rcols[RIDX_KEY] = exch(ridx)
-        rows_here = jnp.sum(rcols[VALID_KEY], dtype=jnp.int64)
-        # global -> per-shard local ids (two separate dense spaces)
-        if partitioned:
-            pk = rcols[PK_KEY]
-            rcols[PK_KEY] = (pk.astype(jnp.int64) // n).astype(pk.dtype)
-        gk = rcols[GK_KEY]
-        if use_lut:
-            lut = luts[0]
-            gl = lut[jnp.clip(gk.astype(jnp.int64), 0, lut.shape[0] - 1)]
-            gl = jnp.clip(gl, 0, localK - 1)
-        else:
-            gl = gk.astype(jnp.int64) // n
-        rcols[GK_KEY] = gl.astype(gk.dtype)
+            rcols = {k: exch(v) for k, v in cols.items()}
+            rcols[RIDX_KEY] = exch(ridx)
+            rows_here = jnp.sum(rcols[VALID_KEY], dtype=jnp.int64)
+            # global -> per-shard local ids (two separate dense spaces)
+            if partitioned:
+                pk = rcols[PK_KEY]
+                rcols[PK_KEY] = (
+                    pk.astype(jnp.int64) // n).astype(pk.dtype)
+            gk = rcols[GK_KEY]
+            if use_lut:
+                lut = luts[0]
+                gl = lut[jnp.clip(gk.astype(jnp.int64), 0,
+                                  lut.shape[0] - 1)]
+                gl = jnp.clip(gl, 0, localK - 1)
+            else:
+                gl = gk.astype(jnp.int64) // n
+            rcols[GK_KEY] = gl.astype(gk.dtype)
 
         st, out = step(state, rcols, now)
         out = dict(out)
         meta = out.pop("__meta__")
-        okey = jnp.asarray(out.pop(OKEY_KEY), jnp.int64)
-        valid_o = out[VALID_KEY]
-        okey = jnp.where(valid_o, okey, _ROUTE_BIG)
-        # local -> global ids on the emitted rows
-        if partitioned and PK_KEY in out:
-            pko = out[PK_KEY]
-            out[PK_KEY] = (pko.astype(jnp.int64) * n
-                           + me.astype(jnp.int64)).astype(pko.dtype)
-        if GK_KEY in out:
-            gko = out[GK_KEY]
-            if use_lut:
-                inv = luts[1]
-                gg = inv[me, jnp.clip(gko.astype(jnp.int64), 0, localK - 1)]
-            else:
-                gg = gko.astype(jnp.int64) * n + me.astype(jnp.int64)
-            out[GK_KEY] = gg.astype(gko.dtype)
-        # ordered re-merge: gather every shard's emitted rows and sort
-        # once by the global emission-order key (invalid rows sort last,
-        # exactly like _order_emit does within one step)
-        okg = jax.lax.all_gather(okey, KEY_AXIS, axis=0, tiled=True)
-        order = jnp.argsort(okg, stable=True)
-        merged = {
-            k: jax.lax.all_gather(v, KEY_AXIS, axis=0, tiled=True)[order]
-            for k, v in out.items()
-        }
-        ov = jax.lax.psum(meta[0], KEY_AXIS)
-        ntb = jnp.where(meta[1] < 0, _ROUTE_BIG, meta[1])
-        # 64-bit min/max across shards go through all_gather: the TPU
-        # lowers an s64 all-reduce only for Sum ("UNIMPLEMENTED: Supported
-        # lowering only of Sum all reduce" on pmin/pmax)
-        nt = jnp.min(jax.lax.all_gather(ntb, KEY_AXIS))
-        nt = jnp.where(nt >= _ROUTE_BIG, jnp.int64(-1), nt)
-        cnt = jax.lax.psum(meta[2], KEY_AXIS)
-        rov = jax.lax.psum(route_ov, KEY_AXIS)
-        rows = jax.lax.all_gather(rows_here, KEY_AXIS)
-        parts = [jnp.stack([ov, nt, cnt, rov]), rows.astype(jnp.int64)]
-        if ins_on:
-            # exchange residual: receive capacity left on the FULLEST
-            # shard this batch (0 = one more skewed batch overflows)
-            parts.append(jnp.full((1,), n * Q, jnp.int64)
-                         - jnp.max(rows).astype(jnp.int64)[None])
-        # inner step's instrument lanes, aggregated per declared reduce
-        # (sum for shard-owned counts, max for fill levels)
-        lane = 3
-        for slot in inner_slots:
-            v = meta[lane:lane + slot.width]
-            lane += slot.width
-            parts.append(
-                jnp.max(jax.lax.all_gather(v, KEY_AXIS), axis=0)
-                if slot.reduce == "max" else jax.lax.psum(v, KEY_AXIS))
-        merged["__meta__"] = jnp.concatenate(parts)
+        with jax.named_scope(MERGE_SCOPE):
+            okey = jnp.asarray(out.pop(OKEY_KEY), jnp.int64)
+            valid_o = out[VALID_KEY]
+            okey = jnp.where(valid_o, okey, _ROUTE_BIG)
+            # local -> global ids on the emitted rows
+            if partitioned and PK_KEY in out:
+                pko = out[PK_KEY]
+                out[PK_KEY] = (pko.astype(jnp.int64) * n
+                               + me.astype(jnp.int64)).astype(pko.dtype)
+            if GK_KEY in out:
+                gko = out[GK_KEY]
+                if use_lut:
+                    inv = luts[1]
+                    gg = inv[me, jnp.clip(gko.astype(jnp.int64), 0,
+                                          localK - 1)]
+                else:
+                    gg = gko.astype(jnp.int64) * n + me.astype(jnp.int64)
+                out[GK_KEY] = gg.astype(gko.dtype)
+            # ordered re-merge: gather every shard's emitted rows and
+            # sort once by the global emission-order key (invalid rows
+            # sort last, exactly like _order_emit does within one step)
+            okg = jax.lax.all_gather(okey, KEY_AXIS, axis=0, tiled=True)
+            order = jnp.argsort(okg, stable=True)
+            merged = {
+                k: jax.lax.all_gather(
+                    v, KEY_AXIS, axis=0, tiled=True)[order]
+                for k, v in out.items()
+            }
+            ov = jax.lax.psum(meta[0], KEY_AXIS)
+            ntb = jnp.where(meta[1] < 0, _ROUTE_BIG, meta[1])
+            # 64-bit min/max across shards go through all_gather: the
+            # TPU lowers an s64 all-reduce only for Sum ("UNIMPLEMENTED:
+            # Supported lowering only of Sum all reduce" on pmin/pmax)
+            nt = jnp.min(jax.lax.all_gather(ntb, KEY_AXIS))
+            nt = jnp.where(nt >= _ROUTE_BIG, jnp.int64(-1), nt)
+            cnt = jax.lax.psum(meta[2], KEY_AXIS)
+            rov = jax.lax.psum(route_ov, KEY_AXIS)
+            rows = jax.lax.all_gather(rows_here, KEY_AXIS)
+            parts = [jnp.stack([ov, nt, cnt, rov]),
+                     rows.astype(jnp.int64)]
+            if ins_on:
+                # exchange residual: receive capacity left on the FULLEST
+                # shard this batch (0 = one more skewed batch overflows)
+                parts.append(jnp.full((1,), n * Q, jnp.int64)
+                             - jnp.max(rows).astype(jnp.int64)[None])
+            # inner step's instrument lanes, aggregated per declared
+            # reduce
+            # (sum for shard-owned counts, max for fill levels)
+            lane = 3
+            for slot in inner_slots:
+                v = meta[lane:lane + slot.width]
+                lane += slot.width
+                parts.append(
+                    jnp.max(jax.lax.all_gather(v, KEY_AXIS), axis=0)
+                    if slot.reduce == "max"
+                    else jax.lax.psum(v, KEY_AXIS))
+            merged["__meta__"] = jnp.concatenate(parts)
         st = jax.tree_util.tree_map(
             lambda leaf, ax: jnp.asarray(leaf)[None] if ax < 0 else leaf,
             st, axes)

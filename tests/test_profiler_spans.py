@@ -22,7 +22,11 @@ from siddhi_tpu.observability.tracing import TRACER, spans_on
 
 RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "device_service_ms", "device_queue_ms", "emit_ms", "t",
-             "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded"}
+             "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded",
+             "route_prep_ms", "route_pieces", "shard_rows_max",
+             "shard_capacity"}
+ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
+              "shard_capacity")
 
 TWO_QUERIES = """
 define stream S (k string, v long);
@@ -42,6 +46,15 @@ begin
   from every e1=AStream -> e2=BStream[e2.v > e1.v] within 5 sec
   select e1.v as v1, e2.v as v2
   insert into MatchStream;
+end;
+"""
+
+PARTITIONED = """
+define stream S (k string, v long);
+partition with (k of S)
+begin
+  @info(name='pq')
+  from S#window.length(4) select k, sum(v) as total insert into O;
 end;
 """
 
@@ -190,6 +203,8 @@ def test_an_nfa_app_leaves_journeys_with_every_ring_key():
     for rec in ring:
         assert set(rec) == RING_KEYS
         assert rec["queries"] == ["nfa"] and rec["pack_ms"] > 0
+        # the counters of a device-routed query: None for every other
+        assert [rec[k] for k in ROUTE_KEYS] == [None] * 4
         assert rec["dispatch_ms"] > 0 and rec["meta_pull_ms"] > 0
     assert len({rec["batch"] for rec in ring}) == 4
     heads, tails = ring[0::2], ring[1::2]
@@ -265,6 +280,81 @@ def test_off_the_columns_pull_without_a_span_or_a_journey(tmp_path):
     _send(h, 0)
     assert not spans_on()
     ring_before = journey.ring()     # what an earlier enable left
+    jax.profiler.start_trace(str(tmp_path))
+    _send(h, 1)
+    jax.profiler.stop_trace()
+    m.shutdown()
+    assert len(out.pulled) == 2 and _engine_spans(tmp_path) == {}
+    assert journey.ring() == ring_before
+
+
+def _routed(depth=2, shards=4, rows_per_shard=16):
+    """``PARTITIONED`` with its query routed over ``shards`` of the
+    virtual devices; one batch sent, so the routed step is compiled."""
+    from siddhi_tpu.parallel.mesh import device_route_query_step, make_mesh
+
+    m = _manager(pipeline_depth=depth)
+    rt = m.create_siddhi_app_runtime(PARTITIONED)
+    out = Columns()
+    rt.add_callback("O", out)
+    rt.start()
+    device_route_query_step(rt.query_runtimes["pq"], make_mesh(shards),
+                            rows_per_shard=rows_per_shard,
+                            exchange="all_to_all")
+    h = rt.get_input_handler("S")
+    _send(h, 0)
+    return m, rt, h, out
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_routed_query_prepares_inside_its_step(tmp_path, depth):
+    """``siddhi.route.prepare`` (the host side of the device-routed
+    dispatch) nests in ``siddhi.query.step`` under the batch's id, and
+    the batch's journey keeps its duration, the pieces, and at the drain
+    the fullest shard's rows beside a shard's capacity."""
+    m, rt, h, _out = _routed(depth)
+    rt.start_trace(str(tmp_path))
+    for i in range(1, 4):
+        _send(h, i)
+    ring = journey.ring()
+    rt.stop_trace()
+    m.shutdown()
+    (spans,) = _engine_spans(tmp_path).values()
+    prepares = [sp for sp in spans if sp[2] == "siddhi.route.prepare"]
+    steps = [sp for sp in spans if sp[2] == "siddhi.query.step"]
+    packs = [sp for sp in spans if sp[2] == "siddhi.pack"]
+    assert len(prepares) == len(steps) == len(packs) == 3
+    for pack, prep, step in zip(packs, prepares, steps):
+        assert _inside(prep, step)
+        assert prep[3]["batch"] == step[3]["batch"] == pack[3]["batch"]
+        assert prep[3]["query"] == "pq"
+    assert len(ring) == 3
+    for rec, prep in zip(ring, prepares):
+        assert set(rec) == RING_KEYS and rec["queries"] == ["pq"]
+        assert rec["batch"] == prep[3]["batch"]
+        assert 0 < rec["route_prep_ms"] <= rec["dispatch_ms"]
+        assert rec["route_pieces"] == 1
+        # three keys, three rows: a, b, c go to shards 0, 1, 2
+        assert rec["shard_rows_max"] == 1
+        assert rec["shard_capacity"] == 16      # 4 shards x quota 4
+
+
+def test_a_split_batch_says_so_in_its_journey():
+    m, _rt, h, out = _routed(rows_per_shard=8)     # quota 2 a pair
+    journey.enable()
+    h.send_columns({"k": np.array(["a"] * 16, object), "v": np.arange(16)})
+    (rec,) = journey.ring()
+    journey.disable()
+    m.shutdown()
+    # 16 rows of one key: every source's four rows go to shard 0
+    assert rec["route_pieces"] == 2 and rec["route_prep_ms"] > 0
+    assert sum(int(p["__valid__"].sum()) for p in out.pulled[1:]) == 16
+
+
+def test_off_a_routed_query_leaves_no_span_and_no_journey(tmp_path):
+    m, _rt, h, out = _routed()
+    assert not spans_on()
+    ring_before = journey.ring()
     jax.profiler.start_trace(str(tmp_path))
     _send(h, 1)
     jax.profiler.stop_trace()

@@ -256,11 +256,36 @@ def test_pallas_ring_exchange_compiles(topo, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("exchange,marker", [
-    ("all_to_all", "all-to-all"),
-    pytest.param("pallas_ring", "tpu_custom_call", marks=pytest.mark.slow),
+_COLLECTIVE = re.compile(
+    r" (all-to-all|all-gather|all-reduce|collective-permute)(?:-start)?\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _collectives(hlo_text):
+    """``(opcode, op_name)`` of every collective of a compiled program
+    (the ``-start`` half of an asynchronous pair stands for the pair)."""
+    found = []
+    for line in hlo_text.splitlines():
+        op = _COLLECTIVE.search(line)
+        if op:
+            name = _OP_NAME.search(line)
+            found.append((op.group(1), name.group(1) if name else ""))
+    return found
+
+
+# the small shape is tier-1; the slow all_to_all case is the cell
+# ``partition_len1k_40k.hot20_bulk_x4`` of BENCHMARK.json as the chip
+# compiles it: 40,000 keys (16,384 a chip), 262,144-row batches, 81,920
+# rows a shard, per-pair quota 20,480
+@pytest.mark.parametrize("exchange,marker,window,keys,batch,rows_per_shard", [
+    ("all_to_all", "all-to-all", 100, 1_000, 4_096, 4_096),
+    pytest.param("pallas_ring", "tpu_custom_call", 100, 1_000, 4_096, 4_096,
+                 marks=pytest.mark.slow),
+    pytest.param("all_to_all", "all-to-all", 1_000, 40_000, 262_144, 81_920,
+                 marks=pytest.mark.slow),
 ])
-def test_device_routed_step_compiles(topo, exchange, marker):
+def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
+                                     batch, rows_per_shard):
     """The whole ``device_route_query_step`` body on the described mesh:
     install routing on a CPU mesh of the same width to size the layout,
     then lower the routed program for the four described chips."""
@@ -268,12 +293,12 @@ def test_device_routed_step_compiles(topo, exchange, marker):
 
     manager = SiddhiManager()
     rt = manager.create_siddhi_app_runtime(_STOCK.format(
-        precision="fast", W=100, group="", tail="end;",
+        precision="fast", W=window, group="", tail="end;",
         head="partition with (symbol of StockStream)\nbegin"))
     rt.start()
     q = rt.query_runtimes["bench"]
-    batch, keys = 4_096, 1_000
-    M.device_route_query_step(q, M.make_mesh(4), rows_per_shard=4_096,
+    M.device_route_query_step(q, M.make_mesh(4),
+                              rows_per_shard=rows_per_shard,
                               exchange="all_to_all")
     seen = _spy_steps(q)
     symbols = np.array([f"S{i}" for i in range(keys)], dtype=object)
@@ -284,6 +309,8 @@ def test_device_routed_step_compiles(topo, exchange, marker):
         timestamps=np.zeros(batch, np.int64))
     _step, (state, cols, now) = seen[-1]
     luts = _avals(q._route_layout.device_luts())
+    assert q._route_layout.quota == rows_per_shard // 4
+    assert q._route_layout.localK >= keys // 4
     # the same layout and body over the described chips: shard_map takes
     # its devices from the mesh, so plain avals are enough
     q._route_layout.mesh = _mesh4(topo)
@@ -292,6 +319,20 @@ def test_device_routed_step_compiles(topo, exchange, marker):
     manager.shutdown()
     t0 = time.perf_counter()
     compiled = routed.lower(state, cols, luts, now).compile()
-    _report(f"device-routed step n=4 {exchange}", compiled,
+    _report(f"device-routed step n=4 {exchange} B={batch} keys={keys} "
+            f"rows_per_shard={rows_per_shard}", compiled,
             time.perf_counter() - t0)
-    assert marker in compiled.as_text()
+    text = compiled.as_text()
+    assert marker in text
+    # ingress and egress are traced in their own scopes, and nothing
+    # crosses chips outside them: the benchmark's ``step_route_ms`` and
+    # ``step_merge_ms`` read the scopes off the device trace
+    names = _OP_NAME.findall(text)
+    for scope in ("siddhi.route", "siddhi.merge", "siddhi.state",
+                  "siddhi.select"):
+        assert any(f"/{scope}/" in name for name in names), scope
+    collectives = _collectives(text)
+    assert {"all-gather", "all-reduce"} <= {op for op, _ in collectives}
+    for op, name in collectives:
+        assert "/siddhi.route/" in name or "/siddhi.merge/" in name, (
+            f"{op} outside the routed step's scopes: {name!r}")
