@@ -896,6 +896,92 @@ def _canonical_to_routed(runtime, layout: RouteLayout, canonical):
 
 # ----------------------------------------------------------- routed step
 
+def _int32_words(v):
+    """Column ``v`` (``[rows, ...]``, any dtype but float64) as ``(words,
+    back)``: ``words`` is int32 ``[rows, p]`` and holds the column's bits
+    (a 64-bit integer as two words, a float as its bits, anything
+    narrower than a word widened to one); ``back`` turns such words into
+    the column again."""
+    dt = v.dtype
+    if dt.itemsize == 8:
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32)       # [.., 2]
+        unbits = lambda b: jax.lax.bitcast_convert_type(b, dt)  # noqa: E731
+    elif jnp.issubdtype(dt, jnp.floating):
+        as_int = jnp.dtype(f"int{8 * dt.itemsize}")
+        bits = jax.lax.bitcast_convert_type(v, as_int).astype(jnp.int32)
+        unbits = lambda b: jax.lax.bitcast_convert_type(  # noqa: E731
+            b.astype(as_int), dt)
+    else:
+        bits = v.astype(jnp.int32)
+        unbits = lambda b: b.astype(dt)  # noqa: E731
+    inner = bits.shape[1:]
+
+    def back(words):
+        return unbits(words.reshape((words.shape[0],) + inner))
+
+    return bits.reshape(v.shape[0], -1), back
+
+
+def _ordered_merge(okey, out, n: int):
+    """The routed egress's ordered re-merge (call under ``shard_map`` over
+    ``KEY_AXIS``): ``okey`` holds THIS shard's ``L`` order keys and ``out``
+    its ``L`` emitted rows per column. Returns every column over all
+    ``n * L`` rows in the stable order of the gathered keys, replicated:
+    what ``all_gather(v)[argsort(all_gather(okey), stable=True)]`` gives,
+    bit for bit and in every slot, invalid rows included. Nothing here
+    computes on a value: columns are only moved.
+
+    The order keys are gathered and sorted ONCE. Every column but the
+    doubles is then placed by the shard that owns the rows: its bits go,
+    as 1-D int32 words, into zeroed ``[n * L]`` buffers at this shard's
+    rows' positions in the merged order (``rank``, the inverse of the
+    sort's permutation); the shards' buffers hold disjoint slots, so one
+    integer ``psum`` (exact) combines them. A one-operand 32-bit scatter
+    gets the TPU compiler's sorted path; a 64-bit value scattered as one
+    two-plane operand does not (PERF.md sections 5, 6), so an int64 goes
+    as two words, and floats ride as their bits (``-0.0 + 0.0`` and NaN
+    payloads would not survive a float sum). A float64 has no bits to
+    take on the TPU (it is a pair of float32 there and the compiler
+    refuses to bitcast it; splitting it arithmetically flushes a
+    subnormal low half), so the doubles are gathered and ride the sort
+    as its payload instead."""
+    def gathered(v):
+        return jax.lax.all_gather(v, KEY_AXIS, axis=0, tiled=True)
+
+    L = okey.shape[0]
+    nL = n * L
+    slots = jnp.arange(nL, dtype=jnp.int32)
+    doubles = {k: v.reshape(L, -1) for k, v in out.items()
+               if v.dtype == jnp.float64}
+    keys = gathered(okey)
+    payload = [gathered(d[:, j]) for d in doubles.values()
+               for j in range(d.shape[1])]
+    _, order, *riders = jax.lax.sort(
+        [keys, slots] + payload, num_keys=1, is_stable=True)
+    rank = jnp.zeros(nL, jnp.int32).at[order].set(
+        slots, unique_indices=True)
+    my_rank = jax.lax.dynamic_slice_in_dim(
+        rank, jax.lax.axis_index(KEY_AXIS) * L, L)
+    columns = {k: _int32_words(v) for k, v in out.items()
+               if k not in doubles}
+    words = jax.lax.psum([
+        jnp.zeros(nL, jnp.int32).at[my_rank].set(
+            w[:, j], unique_indices=True)
+        for w, _back in columns.values() for j in range(w.shape[1])],
+        KEY_AXIS)
+    merged, riders, words = {}, iter(riders), iter(words)
+    for k, v in out.items():
+        if k in doubles:
+            merged[k] = jnp.stack(
+                [next(riders) for _ in range(doubles[k].shape[1])],
+                axis=1).reshape((nL,) + v.shape[1:])
+        else:
+            w, back = columns[k]
+            merged[k] = back(jnp.stack(
+                [next(words) for _ in range(w.shape[1])], axis=1))
+    return merged
+
+
 def routed_step_for(runtime, side_key: Optional[str] = None):
     """Build (and return) the device-routed ``step3(state, cols, now)``
     for a runtime whose ``_route_layout`` is installed. ``side_key``
@@ -916,10 +1002,18 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
               spaces, so GK != PK is fine) and the shard steps its local
               ``[.., K/n]`` state.
     egress    the window/selector's emission-order key (``__okey__``,
-              derived from the pre-exchange global row index) rides out;
-              shards ``all_gather`` their emitted rows and sort once by
-              okey — the ordered re-merge that makes sharded output
-              bit-identical to the unsharded run. The packed meta becomes
+              derived from the pre-exchange global row index) rides out.
+              Shards ``all_gather`` the order keys and sort them once
+              (stable; invalid rows last); each shard then places its
+              OWN emitted rows at their positions in that order (one
+              32-bit scatter a word) and the disjoint partial columns
+              are summed across shards as integers; float64 columns,
+              which the TPU cannot hand over as words, are gathered and
+              ride that one sort as payload (``_ordered_merge``: columns
+              are moved, never computed on). This ordered re-merge makes
+              sharded output bit-identical to the unsharded run and
+              leaves it replicated on every shard, with ``1/n`` of the
+              rows moved a shard. The packed meta becomes
               ``[overflow, notify, count, route_overflow, rows_0..n-1]``
               (prefix-compatible with the unsharded ``[3]`` contract)."""
 
@@ -1062,16 +1156,10 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
                 else:
                     gg = gko.astype(jnp.int64) * n + me.astype(jnp.int64)
                 out[GK_KEY] = gg.astype(gko.dtype)
-            # ordered re-merge: gather every shard's emitted rows and
-            # sort once by the global emission-order key (invalid rows
-            # sort last, exactly like _order_emit does within one step)
-            okg = jax.lax.all_gather(okey, KEY_AXIS, axis=0, tiled=True)
-            order = jnp.argsort(okg, stable=True)
-            merged = {
-                k: jax.lax.all_gather(
-                    v, KEY_AXIS, axis=0, tiled=True)[order]
-                for k, v in out.items()
-            }
+            # ordered re-merge by the global emission-order key (invalid
+            # rows sort last, exactly like _order_emit does within one
+            # step)
+            merged = _ordered_merge(okey, out, n)
             ov = jax.lax.psum(meta[0], KEY_AXIS)
             ntb = jnp.where(meta[1] < 0, _ROUTE_BIG, meta[1])
             # 64-bit min/max across shards go through all_gather: the

@@ -9,8 +9,13 @@ against an unsharded run of the same feed, through the full engine path
 (junction -> process_batch -> CompletionPump -> callbacks).
 """
 
+import re
+import struct
+
+import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from siddhi_tpu import SiddhiManager, StreamCallback
 from siddhi_tpu.core.stream.junction import FatalQueryError
@@ -112,6 +117,204 @@ def test_out_of_order_emission_remerges():
     m2.shutdown()
     assert len(c1.rows) > 0
     assert c2.rows == c1.rows
+
+
+def _bits(row):
+    """A delivered row with its doubles as bit patterns: ``-0.0`` is not
+    ``0.0`` here, and a NaN equals itself."""
+    return tuple(struct.pack("<d", x) if isinstance(x, float) else x
+                 for x in row)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_remerge_with_holes_keeps_every_bit(n_dev):
+    """``test_out_of_order_emission_remerges`` with what a sum across
+    shards could lose: the ``having`` leaves holes among each shard's
+    emitted rows, and the payload carries ``-0.0``, a NaN and integers
+    above 2^53 (no double holds them) through the merge."""
+    app = """
+        define stream S (k string, d double, big long, v long);
+        partition with (k of S)
+        begin
+          @info(name = 'q')
+          from S#window.length(2)
+          select k, d, big, sum(v) as s
+          having s > 40
+          insert into Out;
+        end;
+    """
+    odd = [-0.0, float("nan"), 0.0, 1.0 / 3.0, -1e300, 5e-324]
+
+    def feed(rt):
+        h = rt.get_input_handler("S")
+        for i in range(240):
+            h.send([f"P{i % 16}", odd[i % len(odd)],
+                    2 ** 53 + 1 + i * (2 ** 40 + 1), i % 37])
+
+    m1, rt1, c1 = _build(app)
+    feed(rt1)
+    m1.shutdown()
+    m2, rt2, c2 = _build(app)
+    device_route_query_step(rt2.query_runtimes["q"], make_mesh(n_dev),
+                            rows_per_shard=256)
+    feed(rt2)
+    m2.shutdown()
+    assert 0 < len(c1.rows) < 240          # the having dropped some
+    assert {struct.pack("<d", r[1]) for r in c1.rows} >= {
+        struct.pack("<d", -0.0), struct.pack("<d", 0.0)}
+    assert any(r[1] != r[1] for r in c1.rows)
+    assert [_bits(r) for r in c2.rows] == [_bits(r) for r in c1.rows]
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_merge_places_every_row_padded_tail_included(n_dev):
+    """The egress permutation itself, over ALL ``n * L`` rows: what each
+    shard scatters of its own rows and the shards sum (the doubles: what
+    rides the sort) equals ``all_gather(column)[order]`` bit for bit,
+    invalid rows and the padded tail included, with holes between a
+    shard's valid rows."""
+    from siddhi_tpu.parallel.mesh import KEY_AXIS, _ordered_merge
+
+    L, big = 96, np.int64(2 ** 62)
+    rng = np.random.default_rng(n_dev)
+    okey = rng.permutation(n_dev * L).astype(np.int64)
+    valid = rng.random(n_dev * L) < 0.6      # holes anywhere, not a tail
+    okey = np.where(valid, okey, big)
+    f64 = rng.standard_normal(n_dev * L)
+    f64[:6] = [-0.0, np.nan, np.inf, 5e-324, 1e-30, -np.inf]
+    f64[~valid] = -0.0                       # the tail's bits count too
+    cols = {
+        "__valid__": valid,
+        "__type__": rng.integers(-128, 127, n_dev * L).astype(np.int8),
+        "sym": rng.integers(-2 ** 31, 2 ** 31 - 1, n_dev * L,
+                            dtype=np.int32),
+        "big": rng.integers(2 ** 53 + 1, 2 ** 63 - 1, n_dev * L,
+                            dtype=np.int64),
+        "neg": -rng.integers(2 ** 53 + 1, 2 ** 63 - 1, n_dev * L,
+                             dtype=np.int64),
+        "f64": f64,
+        "f32": rng.standard_normal(n_dev * L).astype(np.float32) * -0.0,
+        "wide": rng.integers(0, 2 ** 62, (n_dev * L, 3), dtype=np.int64),
+        "f64x2": np.stack([f64[::-1], -f64], axis=1),
+    }
+
+    got = jax.jit(jax.shard_map(
+        lambda okey, cols: _ordered_merge(okey, cols, n_dev),
+        mesh=make_mesh(n_dev), in_specs=(P(KEY_AXIS), P(KEY_AXIS)),
+        out_specs=P(), check_vma=False))(okey, cols)
+    order = np.argsort(okey, kind="stable")
+    assert set(got) == set(cols)
+    for name, col in cols.items():
+        want, have = col[order], np.asarray(got[name])
+        assert have.dtype == want.dtype and have.shape == want.shape, name
+        assert have.tobytes() == want.tobytes(), name
+
+
+_KEYED_APP = """
+    define stream S (k string, v double, n long);
+    partition with (k of S)
+    begin
+      @info(name = 'q')
+      from S#window.length(4)
+      select k, avg(v) as a, sum(n) as t insert into Out;
+    end;
+"""
+_JOIN_APP = """
+    define stream L (sym string, lv long);
+    define stream R (sym string, rv long);
+    partition with (sym of L, sym of R)
+    begin
+      @info(name = 'q') from L#window.length(8) join R#window.length(8)
+        on L.lv > R.rv
+        select L.sym as sym, L.lv as lv, R.rv as rv insert into Out;
+    end;
+"""
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"')
+_HLO_OP = re.compile(
+    r'"?stablehlo\.(all_gather|gather|scatter|sort)"?\(([^)]*)\)')
+_HLO_SIG = re.compile(r': \(([^()]*)\) -> (.*?) loc\((#loc\d+)\)\s*$')
+
+
+def _merge_scope_ops(text):
+    """``(op, n_operands, operand types, result types)`` of every
+    all_gather, gather, scatter and sort of a lowered program (StableHLO
+    with debug info) that was traced under ``siddhi.merge``. An operation
+    with a region (a scatter, a sort) carries its types on the line that
+    closes it."""
+    names = {m[1]: m[2] for m in map(_LOC_DEF.match, text.splitlines()) if m}
+    found, open_ops = [], []
+    for line in text.splitlines():
+        stripped = line.strip()
+        op = _HLO_OP.search(line)
+        head = (op[1], op[2].count("%")) if op else None
+        if stripped.startswith("})"):
+            head = open_ops.pop()
+        if stripped.endswith("({"):
+            open_ops.append(head)
+            continue
+        sig = _HLO_SIG.search(line)
+        if head and sig and "siddhi.merge/" in names.get(sig[3], ""):
+            found.append((head[0], head[1], sig[1], sig[2]))
+    assert not open_ops
+    return found
+
+
+@pytest.mark.parametrize("app,stream,row", [
+    (_KEYED_APP, "S", lambda i: [f"P{i % 16}", float(i), i]),
+    (_JOIN_APP, "L", lambda i: [f"P{i % 16}", i]),
+], ids=["keyed_window", "join_side"])
+def test_egress_moves_own_rows_only(app, stream, row):
+    """The routed program as lowered: under ``siddhi.merge`` the only
+    ``all_gather``s a row wide are the order keys' and the float64
+    columns' (which ride the ONE sort), no ``gather`` reads an ``n *
+    L``-row operand (no column is permuted by ``[order]``), and every
+    scatter is one 32-bit operand with one update: a 64-bit value
+    scattered as one operand becomes a two-plane scatter on the chip,
+    which gets none of the compiler's sorted path (PERF.md section 5)."""
+    m, rt, _c = _build(app)
+    q = rt.query_runtimes["q"]
+    device_route_query_step(q, make_mesh(4), rows_per_shard=64)
+    seen, finish = [], q._finish_device_batch
+
+    def spying_finish(step, cols, overflow_msg):
+        def spy(*args):
+            seen.append((step, args))
+            return step(*args)
+
+        return finish(spy, cols, overflow_msg)
+
+    q._finish_device_batch = spying_finish
+    h = rt.get_input_handler(stream)
+    for i in range(32):
+        h.send(row(i))
+    step, (state, cols, now) = seen[-1]
+    lowered = step._routed_raw.lower(
+        state, cols, q._route_layout.device_luts(), now)
+    m.shutdown()
+    emitted = [a for k, a in lowered.out_info[1].items() if k != "__meta__"]
+    rows = emitted[0].shape[0]                            # n * L
+    doubles = sum(a.dtype == np.float64 for a in emitted)
+    text = lowered.as_text(debug_info=True)
+    ops = _merge_scope_ops(text)
+    wide = f"tensor<{rows}x"
+    gathered = sorted(o[3] for o in ops
+                      if o[0] == "all_gather" and wide in o[3])
+    assert gathered == ([f"tensor<{rows}xf64>"] * doubles
+                        + [f"tensor<{rows}xi64>"]), gathered
+    assert not [o for o in ops if o[0] == "gather"
+                and o[2].startswith(wide)], ops
+    # the rank's scatter, and one for every 32-bit word of a column that
+    # does not ride the sort
+    scatters = [o for o in ops if o[0] == "scatter"]
+    words = sum(max(a.dtype.itemsize // 4, 1) for a in emitted
+                if a.dtype != np.float64)
+    assert len(scatters) == words + 1, scatters
+    for _op, n_operands, operands, result in scatters:
+        assert n_operands == 3, (operands, result)
+        assert result == f"tensor<{rows}xi32>", (operands, result)
+    # ONE sort: the order keys, the slots' numbers, the doubles
+    sorts = [o for o in ops if o[0] == "sort"]
+    assert [o[1] for o in sorts] == [2 + doubles], sorts
 
 
 def test_oversized_batches_split_not_die():

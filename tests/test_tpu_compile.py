@@ -318,7 +318,8 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     routed = M.routed_step_for(q)._routed_raw
     manager.shutdown()
     t0 = time.perf_counter()
-    compiled = routed.lower(state, cols, luts, now).compile()
+    lowered = routed.lower(state, cols, luts, now)
+    compiled = lowered.compile()
     _report(f"device-routed step n=4 {exchange} B={batch} keys={keys} "
             f"rows_per_shard={rows_per_shard}", compiled,
             time.perf_counter() - t0)
@@ -336,3 +337,21 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     for op, name in collectives:
         assert "/siddhi.route/" in name or "/siddhi.merge/" in name, (
             f"{op} outside the routed step's scopes: {name!r}")
+    # the egress moves each shard's OWN rows: of the row-wide columns only
+    # the order keys (two u32 planes here) and the double that rides their
+    # sort (``avgPrice``: a pair of f32 here) are all-gathered, nothing is
+    # permuted by a gather, and every scatter writes ONE 32-bit plane
+    # (``tests/test_mesh_routing.py::test_egress_moves_own_rows_only``
+    # says the same of the lowered program, on the CPU)
+    wide = re.compile(
+        r"= (\(.*?\)|\S+) all-gather(?:-start)?\(.*/siddhi\.merge/all_gather")
+    gathered = [m[1] for m in map(wide.search, text.splitlines()) if m]
+    n_l = lowered.out_info[1]["__valid__"].shape[0]       # n * L
+    planes = re.findall(rf"(\w+)\[{n_l}\]", " ".join(gathered))
+    assert sorted(planes) == ["f32", "f32", "u32", "u32"], gathered
+    assert not [name for name in names
+                if name.endswith("/siddhi.merge/gather")]
+    merge_scatters = [s for s in _scatters(text) if s[0] == "siddhi.merge"]
+    assert len(merge_scatters) >= 8
+    assert {result for _s, _p, result in merge_scatters} == {
+        f"s32[{n_l}]"}, merge_scatters
