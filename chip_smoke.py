@@ -18,7 +18,7 @@ query written here with ``collections``/``numpy`` and nothing from
 
 ``python chip_smoke.py`` needs one TPU chip and refuses to run without.
 ``--chips 4`` runs ONLY phase B's query routed over a mesh of four chips
-(once per ``shard_exchange`` value) against the same query unsharded.
+against the same query unsharded.
 ``--cpu-rehearsal`` runs the same code at a tiny size on the CPU backend
 (a control-flow rehearsal; its last line says ``"platform": "cpu"``).
 
@@ -35,9 +35,7 @@ import collections
 import dataclasses
 import json
 import logging
-import os
 import sys
-import threading
 import time
 
 import numpy as np
@@ -76,7 +74,6 @@ end;
 """
 
 WITHIN_S = 5
-_HANG_S = 900      # --chips 4: one exchange's compile + run, generously
 # float32 arithmetic: unit roundoff of the device dtype under "fast"
 _EPS32 = float(np.finfo(np.float32).eps)
 
@@ -390,8 +387,8 @@ def _stock_feed(rng, sizes, n_batches, skewed):
 
 def _drive_stock(app, sizes, feed, symbols, meter, route=None):
     """Run one StockStream app over ``feed`` through the normal entry
-    points. ``route`` (mesh, exchange) installs device routing on the
-    query first. Returns (collector, line, marks)."""
+    points. ``route`` (a mesh) installs device routing on the query
+    first. Returns (collector, line, marks)."""
     import jax
 
     from siddhi_tpu import SiddhiManager
@@ -404,13 +401,11 @@ def _drive_stock(app, sizes, feed, symbols, meter, route=None):
     if route is not None:
         from siddhi_tpu.parallel.mesh import device_route_query_step
 
-        mesh, exchange = route
-        n = int(mesh.devices.size)
+        n = int(route.devices.size)
         rt.start()
         device_route_query_step(
-            rt.query_runtimes["bench"], mesh,
-            rows_per_shard=int(sizes.batch / n * sizes.route_slack),
-            exchange=exchange)
+            rt.query_runtimes["bench"], route,
+            rows_per_shard=int(sizes.batch / n * sizes.route_slack))
     h = rt.get_input_handler("StockStream")
     B = sizes.batch
 
@@ -435,7 +430,7 @@ def _drive_stock(app, sizes, feed, symbols, meter, route=None):
         "measured_events": B * (len(feed) - 1),
         "measured_wall_seconds": round(wall, 3),
         "memory": [_memory(d) for d in jax.devices()[
-            :1 if route is None else int(route[0].devices.size)]],
+            :1 if route is None else int(route.devices.size)]],
     }
     # ids -> strings through the app's dictionary, as decode_events does
     out_ids = out.column("symbol").astype(np.int64)
@@ -492,7 +487,7 @@ def _pattern_feed(rng, sizes):
     only ``WITHIN_S + 1`` rounds later — outside the bound."""
     K, B = sizes.keys, sizes.batch_c
     names = np.array([f"K{i}" for i in range(K)], dtype=object)
-    # one timestamp per batch (as bench.py's feed): a head batch whose
+    # one timestamp per batch: a head batch whose
     # same-key rows span several timestamps is dispatched to the serial
     # engine instead of the two-step kernel (nfa_runtime._host_hard_batch)
     stamp = np.zeros(B, np.int64)
@@ -600,8 +595,8 @@ def phase_c(sizes, seed, meter, errors):
 
 def phase_mesh(sizes, seed, meter, errors, n_chips, device):
     """Phase B's partitioned query routed over ``n_chips`` devices
-    (``device_route_query_step``), once per ``shard_exchange`` value,
-    against the same query unsharded in this process."""
+    (``device_route_query_step``) against the same query unsharded in
+    this process."""
     import jax
 
     from siddhi_tpu.parallel.mesh import make_mesh
@@ -617,60 +612,26 @@ def phase_mesh(sizes, seed, meter, errors, n_chips, device):
     line = {"phase": "mesh/unsharded", "devices": 1, "keys": sizes.keys,
             "batch": sizes.batch, **line}
     _finish("mesh/unsharded", line, meter, warm_mark, run_mark, errors)
-    outcomes = {}
-
-    def hung(exchange, device):
-        # a remote-DMA kernel that never completes blocks this process
-        # inside the runtime for good: state the outcome and leave, with
-        # the result line only if the other exchange already agreed
-        outcomes[exchange] = f"hung: no result after {_HANG_S} s"
-        print(json.dumps({"phase": "mesh", "shard_exchange": outcomes}),
-              flush=True)
-        agreed = any(o.startswith("equal") for o in outcomes.values())
-        if agreed:
-            print(json.dumps({"ok": True, "device": device}), flush=True)
-        os._exit(0 if agreed else 3)
-
-    for exchange in ("all_to_all", "pallas_ring"):
-        name = f"mesh/{exchange}"
-        watchdog = threading.Timer(_HANG_S, hung, (exchange, device))
-        watchdog.daemon = True
-        watchdog.start()
-        try:
-            out, out_sym, line, warm_mark, run_mark = _drive_stock(
-                _APP_B, sizes, feed, symbols, meter, route=(mesh, exchange))
-        except Exception as e:  # noqa: BLE001 — the outcome IS the report
-            # an exchange the compiler or runtime refuses is stated, not
-            # hidden; at least one exchange must still agree (below)
-            outcomes[exchange] = f"failed: {type(e).__name__}: {e}"[:1500]
-            print(json.dumps({"phase": name, "outcome": outcomes[exchange]}),
-                  flush=True)
-            errors.records.clear()
-            continue
-        finally:
-            watchdog.cancel()
-        _compare(name, "symbol", out_sym, base_sym)
-        _compare(name, "totalVolume", out.column("totalVolume"),
-                 base.column("totalVolume"))
-        # same arithmetic in another program: bit-equal on the CPU; on the
-        # TPU float64 is emulated and the two programs round differently
-        # in the last bits (first four-chip run, PR 21: 95 of 3.2 M rows)
-        tol = _double_tolerance(device["platform"])
-        not_bit_equal = int((out.column("avgPrice")
-                             != base.column("avgPrice")).sum())
-        err = _compare(name, "avgPrice", out.column("avgPrice"),
-                       base.column("avgPrice"), tol)
-        outcomes[exchange] = "equal to unsharded, row for row"
-        line = {"phase": name, "devices": n_chips,
-                "mesh_device_ids": [d.id for d in devs],
-                "outcome": outcomes[exchange], "double_tolerance": tol,
-                "rows_not_bit_equal": not_bit_equal,
-                "float_max_abs_error": err, **line}
-        _finish(name, line, meter, warm_mark, run_mark, errors)
-    if not any(o.startswith("equal") for o in outcomes.values()):
-        raise SmokeFailure(f"no shard_exchange agreed: {outcomes}")
-    print(json.dumps({"phase": "mesh", "shard_exchange": outcomes}),
-          flush=True)
+    name = "mesh/routed"
+    out, out_sym, line, warm_mark, run_mark = _drive_stock(
+        _APP_B, sizes, feed, symbols, meter, route=mesh)
+    _compare(name, "symbol", out_sym, base_sym)
+    _compare(name, "totalVolume", out.column("totalVolume"),
+             base.column("totalVolume"))
+    # same arithmetic in another program: bit-equal on the CPU; on the
+    # TPU float64 is emulated and the two programs round differently
+    # in the last bits (first four-chip run, PR 21: 95 of 3.2 M rows)
+    tol = _double_tolerance(device["platform"])
+    not_bit_equal = int((out.column("avgPrice")
+                         != base.column("avgPrice")).sum())
+    err = _compare(name, "avgPrice", out.column("avgPrice"),
+                   base.column("avgPrice"), tol)
+    line = {"phase": name, "devices": n_chips,
+            "mesh_device_ids": [d.id for d in devs],
+            "outcome": "equal to unsharded, row for row",
+            "double_tolerance": tol, "rows_not_bit_equal": not_bit_equal,
+            "float_max_abs_error": err, **line}
+    _finish(name, line, meter, warm_mark, run_mark, errors)
 
 
 def main(argv=None) -> int:

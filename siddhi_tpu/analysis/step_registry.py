@@ -24,12 +24,10 @@ JIT_STEP_BUILDERS: Dict[str, Tuple[str, str]] = {
     # fused sibling queries: one jitted step per junction group
     "fused_fanout": ("siddhi_tpu.core.query.fused_fanout",
                      "FusedFanoutRuntime"),
-    # GSPMD keyed sharding (round-4) + host-routed shard_map (round-5)
+    # GSPMD keyed sharding: what the device router cannot take
     "gspmd_replicated_batch": ("siddhi_tpu.parallel.mesh",
                                "shard_query_step"),
-    "shard_map_routed": ("siddhi_tpu.parallel.mesh",
-                         "shard_keyed_query_step"),
-    # device-side repartitioning (round-6): routing inside the step
+    # device-side repartitioning: routing inside the step
     "device_routed": ("siddhi_tpu.parallel.mesh",
                       "device_route_query_step"),
     # device join engine: fused insert+probe side step
@@ -69,7 +67,6 @@ PROGRAM_CACHE_FAMILIES: Dict[str, Tuple[str, ...]] = {
     "query_step": ("query_step", "selector"),
     "fused_fanout": ("fused_fanout",),
     "gspmd_replicated_batch": ("gspmd_replicated_batch",),
-    "shard_map_routed": ("shard_map_routed",),
     "device_routed": ("device_routed",),
     # NFA steps ride QueryRuntime's module (pattern/sequence queries)
     "nfa_step": ("nfa_step", "nfa_timer"),
@@ -89,7 +86,6 @@ PROGRAM_CACHE_FAMILY_SITES: Dict[str, str] = {
     "selector": "siddhi_tpu.core.query.runtime",
     "fused_fanout": "siddhi_tpu.core.query.fused_fanout",
     "gspmd_replicated_batch": "siddhi_tpu.parallel.mesh",
-    "shard_map_routed": "siddhi_tpu.parallel.mesh",
     "device_routed": "siddhi_tpu.parallel.mesh",
     "nfa_step": "siddhi_tpu.core.query.nfa_runtime",
     "nfa_timer": "siddhi_tpu.core.query.nfa_runtime",

@@ -137,8 +137,7 @@ def _apply_route_shards(rt, direction) -> Optional[Tuple[int, int]]:
             # snapshot captures a settled state (owner -> pump order)
             rt.app_context.completion_pump.flush_owner(qr)
             device_route_query_step(
-                qr, make_mesh(new), rows_per_shard=layout.rows_per_shard,
-                exchange=layout.exchange)
+                qr, make_mesh(new), rows_per_shard=layout.rows_per_shard)
         changed = (old, new)
     return changed
 

@@ -264,9 +264,10 @@ class SiddhiAppContext:
         # partition-local probes cut the [N, W] probe surface ~P-fold.
         # 0 = auto: 8 on accelerator backends, 1 on the CPU fallback —
         # the directory's gathers + emission-order sort lose to the
-        # vectorized broadcast compare on a scalar core (bench.py
-        # --section join, PERF.md), while P = 1 keeps the fused in-state
-        # step (pipeline/fusion/mesh eligibility) at legacy speed. An
+        # vectorized broadcast compare on a scalar core (a CPU timing;
+        # AUTO is not measured on the chip: PERF.md), while P = 1 keeps
+        # the fused in-state step (pipeline/fusion/mesh eligibility) at
+        # legacy speed. An
         # explicit value is always honored. Key siddhi_tpu.join_partitions.
         self.join_partitions = 0
         # per-partition sub-window slack factor: each [P, W*slack/P]

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that compiles (``chip_smoke.py``,
-``bench.py``, ``cluster/worker.py``): where ``JAX_COMPILATION_CACHE_DIR``
+``benchmarks/run.py``, ``cluster/worker.py``): where ``JAX_COMPILATION_CACHE_DIR``
 is set in the environment, JAX reads it itself and the program sets
 nothing; where it is not, the cache goes to ``<checkout>/.jax_cache``
 (listed in ``.gitignore``). The path is computed from this file's own

@@ -154,8 +154,6 @@ KNOBS: Dict[str, Knob] = _declare(
     # floats
     Knob("cluster_step_timeout", "float", attr="cluster_step_timeout"),
     # enums
-    Knob("shard_exchange", "enum", choices=("all_to_all", "pallas_ring"),
-         attr="shard_exchange"),
     Knob("join_engine", "enum", choices=("device", "legacy"),
          attr="join_engine"),
     # overload armor (resilience/overload.py) — applied by

@@ -194,158 +194,6 @@ def _out_shardings(mesh: Mesh, st_sh):
     return (st_sh, NamedSharding(mesh, P()))
 
 
-def route_batch_to_shards(cols, n_shards: int, rows_per_shard: int):
-    """Host-side all-to-all: scatter batch rows to their owning key shard.
-
-    DEPRECATED — a compatibility shim kept for the legacy
-    ``shard_keyed_query_step`` callers. The host router costs ~75% of
-    single-shard throughput (a CPU timing) and requires GK == PK; new code
-    should use :func:`device_route_query_step`, which routes rows INSIDE
-    the jitted step (dense ``all_to_all`` under ``shard_map``), supports a
-    group-by key distinct from the partition key, and re-merges emitted
-    rows into the exact unsharded order.
-
-    The owner of dense key ``k`` is ``k % n_shards`` and its local id is
-    ``k // n_shards`` — round-robin keeps the keyer's dense ids
-    load-balanced across shards. Returns a routed column dict of shape
-    ``[n_shards * rows_per_shard]`` where segment ``d`` holds shard ``d``'s
-    rows (original order preserved within the shard) padded with invalid
-    rows, and the PK/GK columns rewritten to LOCAL ids."""
-    import time
-    import warnings
-
-    from siddhi_tpu.core.plan.selector_plan import GK_KEY
-    from siddhi_tpu.core.stream.junction import FatalQueryError
-    from siddhi_tpu.ops.expressions import PK_KEY, VALID_KEY
-
-    warnings.warn(
-        "route_batch_to_shards is deprecated: use device_route_query_step "
-        "(on-device repartitioning; lifts the GK == PK restriction)",
-        DeprecationWarning, stacklevel=2)
-    t0 = time.perf_counter()
-    key_col = PK_KEY if PK_KEY in cols else GK_KEY
-    if GK_KEY in cols and PK_KEY in cols and not np.array_equal(
-            np.asarray(cols[GK_KEY]), np.asarray(cols[PK_KEY])):
-        # a group-by key distinct from the partition key lives in its own
-        # dense-id space; rewriting it to partition-local ids would corrupt
-        # the selector's group state. The DEVICE router carries the two id
-        # spaces separately — use device_route_query_step for distinct GKs.
-        raise FatalQueryError(
-            "route_batch_to_shards requires GK == PK (partitioned query "
-            "without a distinct group-by key) — device_route_query_step "
-            "lifts this restriction")
-    valid = np.asarray(cols[VALID_KEY])
-    keep = np.nonzero(valid)[0]  # capacity padding never competes for rows
-    pk = np.asarray(cols[key_col]).astype(np.int64)[keep]
-    owner = pk % n_shards
-    local = pk // n_shards
-    order = np.argsort(owner, kind="stable")
-    counts = np.bincount(owner, minlength=n_shards)
-    if int(counts.max(initial=0)) > rows_per_shard:
-        raise FatalQueryError(
-            f"shard overflow: {int(counts.max())} rows for one shard > "
-            f"rows_per_shard={rows_per_shard} — raise rows_per_shard or "
-            f"split the batch")
-    starts = np.zeros(n_shards, np.int64)
-    np.cumsum(counts[:-1], out=starts[1:])
-    owner_sorted = owner[order]
-    pos = np.arange(keep.shape[0], dtype=np.int64) - starts[owner_sorted]
-    dest = owner_sorted * rows_per_shard + pos
-    src = keep[order]
-
-    N = n_shards * rows_per_shard
-    routed = {}
-    for k, v in cols.items():
-        v = np.asarray(v)
-        if k in (PK_KEY, GK_KEY):
-            buf = np.zeros(N, v.dtype)
-            buf[dest] = local[order].astype(v.dtype)
-        else:
-            buf = np.zeros((N,) + v.shape[1:], v.dtype)
-            buf[dest] = v[src]
-        routed[k] = buf
-    _record_route_telemetry(None, "host", counts,
-                            (time.perf_counter() - t0) * 1000.0)
-    return routed  # padding rows keep VALID=False (zero-fill)
-
-
-def shard_keyed_query_step(runtime, mesh: Mesh, rows_per_shard: int):
-    """Jit a keyed (partitioned) query step as a ``shard_map`` over ``mesh``
-    — zero-collective data parallelism over the key space.
-
-    Contract with ``route_batch_to_shards``: the runtime is sized to its
-    PER-SHARD key capacity (``selector_plan.num_keys`` / ``_win_keys`` are
-    local values), and batches arrive routed (``[n * rows_per_shard]`` rows
-    carrying local key ids). Each device then steps its own
-    ``[slots, K_local]`` / ``[K_local * W]`` state over only its own rows;
-    the compiled HLO contains NO collective ops (verified by
-    ``tools/hlo_audit.py``) — the host router IS the all-to-all, and the
-    ICI carries nothing per step. Global-window queries cannot take this
-    path (their ring semantics need every row in order); use
-    ``shard_query_step`` for those.
-
-    Returns ``(jitted_step, global_state)``. Out rows come back
-    shard-segmented (leaf axis 0 = ``n * R_local``); ``"__meta__"`` is
-    ``[n, 3]`` — one (overflow, notify, count) row per shard."""
-
-    _release_from_fanout(runtime)
-    n = mesh.devices.size
-    localK = runtime.selector_plan.num_keys
-    local_win = getattr(runtime, "_win_keys", 1)
-    if runtime._state is None:
-        runtime._state = runtime._init_state()
-    local_state = runtime._state
-    step = runtime.build_step_fn()
-
-    axes = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: _key_axis_of(path, leaf, localK, local_win),
-        local_state)
-
-    def stack_global(leaf, ax):
-        arr = np.asarray(leaf)
-        if ax < 0:
-            # unkeyed leaf: leading device axis — every shard keeps its own
-            # independently-evolving copy (squeezed back inside the map)
-            return np.stack([arr] * n, axis=0)
-        return np.concatenate([arr] * n, axis=ax)
-
-    global_state = jax.tree_util.tree_map(stack_global, local_state, axes)
-    st_specs = jax.tree_util.tree_map(
-        lambda ax: P(KEY_AXIS) if ax <= 0 else P(*([None] * ax), KEY_AXIS),
-        axes)
-
-    def wrapped(state, cols, now):
-        state = jax.tree_util.tree_map(
-            lambda leaf, ax: leaf[0] if ax < 0 else leaf, state, axes)
-        st, out = step(state, cols, now)
-        st = jax.tree_util.tree_map(
-            lambda leaf, ax: jnp.asarray(leaf)[None] if ax < 0 else leaf,
-            st, axes)
-        out = {
-            k: jnp.asarray(v)[None] if (k == "__meta__" or jnp.ndim(v) == 0)
-            else v
-            for k, v in out.items()
-        }
-        return st, out
-
-    sharded = jax.shard_map(
-        wrapped, mesh=mesh,
-        in_specs=(st_specs, P(KEY_AXIS), P()),
-        out_specs=(st_specs, P(KEY_AXIS)),
-        check_vma=False,
-    )
-    jitted = jax.jit(named_step(sharded, "shard_map_routed"),
-                     donate_argnums=(0,))
-    tel = getattr(runtime.app_context, "telemetry", None)
-    if tel is not None:
-        jitted = tel.instrument_jit(
-            jitted, f"query.{runtime.name}.shard_map_step",
-            family="shard_map_routed", cache_extra=str(mesh))
-    state = jax.device_put(global_state, jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), st_specs))
-    return jitted, state
-
-
 def sharded_jit_for(runtime, fn, n_state_args: int = 1, n_plain_args: int = 2):
     """Jit ``fn(state, *plain)`` with the runtime's recorded mesh shardings
     (used by NFAQueryRuntime for per-stream and timer steps)."""
@@ -361,18 +209,19 @@ def sharded_jit_for(runtime, fn, n_state_args: int = 1, n_plain_args: int = 2):
 
 
 # ---------------------------------------------------------------------------
-# Device-side repartitioning (round 6): the host router above moved every
-# batch row through numpy before dispatch and hard-required GK == PK. The
-# device router below does the same scatter INSIDE the jitted step — the
-# unrouted batch enters B-sharded, each shard computes owners on device,
-# rows exchange shard-to-shard with one dense all_to_all (or a Pallas TPU
-# ring kernel, config-selected), and emitted rows re-merge into the exact
-# unsharded emission order on the way out ("Scaling Ordered Stream
-# Processing on Shared-Memory Multicores": ordered re-merge over
+# Two sharded paths, each the only one for its inputs. Above: GSPMD
+# (``shard_query_step``), for whatever ``route_ineligibility`` refuses
+# (patterns, time-driven and unkeyed windows), for a multi-process mesh and
+# for the survivor-mesh recovery. Below: the device router for keyed
+# queries (``device_route_query_step``). The unrouted batch enters
+# B-sharded, each shard computes owners on device, rows exchange
+# shard-to-shard with one dense all_to_all, and emitted rows re-merge into
+# the exact unsharded emission order on the way out ("Scaling Ordered
+# Stream Processing on Shared-Memory Multicores": ordered re-merge over
 # out-of-order parallel execution). Two dense id spaces ride each row —
 # the partition key (owner = pk % n, local = pk // n) and the group-by key
 # (owned by its pk's shard, local ids assigned per shard in allocation
-# order via a host-maintained LUT) — which is what lifts GK == PK.
+# order via a host-maintained LUT) — so GK need not equal PK.
 # ---------------------------------------------------------------------------
 
 # plain numpy scalar: a module-level jnp constant would initialize the
@@ -389,15 +238,13 @@ _ROUTE_ROWS: "_weakref.WeakKeyDictionary" = _weakref.WeakKeyDictionary()
 
 def _record_route_telemetry(telemetry, scope: str, rows, exchange_ms):
     """siddhi_shard_rows{shard} gauges + siddhi_shard_exchange_ms histogram
-    — registered on BOTH the legacy host-routed path (process-global
-    registry, scope "host") and the device-routed path (app registry,
-    scope = query name) so key skew is visible either way."""
+    of the device-routed path, so key skew is visible (``scope`` = query
+    name; the process-global registry where the app has no telemetry)."""
     if telemetry is None:
         from siddhi_tpu.observability.telemetry import global_registry
 
         telemetry = global_registry()
-    if exchange_ms is not None:
-        telemetry.histogram(f"shard.exchange_ms.{scope}").record(exchange_ms)
+    telemetry.histogram(f"shard.exchange_ms.{scope}").record(exchange_ms)
     store = _ROUTE_ROWS.setdefault(telemetry, {})
     prev = store.get(scope)
     known = 0 if prev is None else prev.shape[0]
@@ -419,13 +266,12 @@ class RouteLayout:
     runtime's (now per-shard) capacity fields; ``n * localK`` is the
     global dense-id capacity the keyer allocates into."""
 
-    def __init__(self, mesh: Mesh, rows_per_shard: int, exchange: str,
+    def __init__(self, mesh: Mesh, rows_per_shard: int,
                  partitioned: bool, use_lut: bool):
         self.mesh = mesh
         self.n = int(mesh.devices.size)
         self.rows_per_shard = int(rows_per_shard)
         self.quota = max(1, self.rows_per_shard // self.n)
-        self.exchange = exchange
         self.partitioned = partitioned
         self.use_lut = use_lut
         self.localK = 1
@@ -625,13 +471,17 @@ def device_route_query_step(runtime, mesh: Mesh, rows_per_shard: int = 4096,
     """Install on-device repartitioning for a keyed query: the runtime's
     step becomes a ``shard_map`` whose body (1) computes each row's owner
     shard from its key on device, (2) exchanges rows shard-to-shard with a
-    dense ``jax.lax.all_to_all`` (``exchange="pallas_ring"`` selects the
-    TPU ring kernel and raises ``CompileError`` on any other backend),
-    (3) rewrites the partition-
-    and group-key columns into their per-shard local id spaces (distinct
-    spaces — GK == PK is no longer required), (4) steps the shard's local
+    dense ``jax.lax.all_to_all``, (3) rewrites the partition- and
+    group-key columns into their per-shard local id spaces (distinct
+    spaces — GK == PK is not required), (4) steps the shard's local
     state, and (5) re-merges emitted rows across shards by their global
     emission-order keys, so sharded output is bit-identical to unsharded.
+
+    Raises ``CompileError`` for a query ``route_ineligibility`` refuses
+    (``shard_query_step`` is the path for those). ``exchange`` is kept for
+    the configuration files that still pass it: ``None`` and
+    ``"all_to_all"`` mean the one exchange there is, any other value
+    raises ``CompileError``.
 
     ``rows_per_shard`` bounds each shard's per-batch receive capacity;
     the host pre-checks per-pair quotas and SPLITS oversized batches
@@ -650,17 +500,11 @@ def device_route_query_step(runtime, mesh: Mesh, rows_per_shard: int = 4096,
         raise CompileError(
             f"query '{runtime.name}': device routing does not support "
             f"{why} — use shard_query_step for those")
-    _release_from_fanout(runtime)
-    n = int(mesh.devices.size)
-    if exchange is None:
-        exchange = getattr(runtime.app_context, "shard_exchange",
-                           "all_to_all")
-    platform = mesh.devices.flat[0].platform
-    if exchange == "pallas_ring" and platform != "tpu":
+    if exchange not in (None, "all_to_all"):
         raise CompileError(
-            f"query '{runtime.name}': shard_exchange = 'pallas_ring' is a "
-            f"TPU remote-DMA kernel and cannot run on the '{platform}' "
-            f"backend — set shard_exchange to 'all_to_all'")
+            f"query '{runtime.name}': exchange = {exchange!r} is not "
+            f"supported — rows are exchanged by 'all_to_all' only")
+    _release_from_fanout(runtime)
     partitioned = runtime.partition_ctx is not None
     use_lut = partitioned and runtime.keyer is not None
 
@@ -678,7 +522,7 @@ def device_route_query_step(runtime, mesh: Mesh, rows_per_shard: int = 4096,
             canonical = jax.tree_util.tree_map(
                 np.asarray, jax.device_get(runtime._state))
 
-    layout = RouteLayout(mesh, rows_per_shard, exchange, partitioned, use_lut)
+    layout = RouteLayout(mesh, rows_per_shard, partitioned, use_lut)
     _install_routed(runtime, layout, canonical, Kg, Wg)
     return runtime._step, runtime._state
 
@@ -1079,12 +923,6 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
     st_specs = jax.tree_util.tree_map(
         lambda ax: P(KEY_AXIS) if ax <= 0 else P(*([None] * ax), KEY_AXIS),
         axes)
-    if layout.exchange == "pallas_ring":
-        exchange = lambda buf: _pallas_ring_exchange(buf, n)  # noqa: E731
-    else:
-        exchange = lambda buf: jax.lax.all_to_all(  # noqa: E731
-            buf, KEY_AXIS, split_axis=0, concat_axis=0, tiled=True)
-
     def wrapped(state, cols, luts, now):
         state = jax.tree_util.tree_map(
             lambda leaf, ax: leaf[0] if ax < 0 else leaf, state, axes)
@@ -1115,7 +953,8 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
             def exch(col):
                 buf = jnp.zeros((n * Q,) + col.shape[1:], col.dtype)
                 buf = buf.at[slot_row].set(col, mode="drop")
-                return exchange(buf)
+                return jax.lax.all_to_all(
+                    buf, KEY_AXIS, split_axis=0, concat_axis=0, tiled=True)
 
             rcols = {k: exch(v) for k, v in cols.items()}
             rcols[RIDX_KEY] = exch(ridx)
@@ -1341,87 +1180,3 @@ def adopt_canonical(runtime, sel_keys_g: int, win_keys_g: int) -> None:
         canonical = jax.tree_util.tree_map(
             np.asarray, jax.device_get(runtime._state))
     _install_routed(runtime, layout, canonical, sel_keys_g, win_keys_g)
-
-
-# ------------------------------------------------- Pallas TPU ring kernel
-
-def _pallas_ring_exchange(buf, n: int):
-    """All-to-all of ``buf`` ([n * Q, ...]: segment d goes to shard d) via
-    direct async remote copies (SNIPPETS.md [2] pattern:
-    ``pltpu.make_async_remote_copy`` under ``shard_map``). TPU-only —
-    selected by ``shard_exchange = "pallas_ring"``; any other backend is
-    refused by name in ``device_route_query_step``.
-    Each shard pushes segment d straight to shard d's receive buffer at
-    segment ``me`` (received rows stay source-major, matching the dense
-    all_to_all layout); ``wait()`` on every descriptor covers both the
-    local sends and the n-1 expected arrivals, whose semaphore slots line
-    up because transfer sizes are uniform."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    orig_dtype, orig_shape = buf.dtype, buf.shape
-    if orig_dtype == jnp.bool_:
-        buf = buf.astype(jnp.int8)   # DMA-friendly lane type
-    elif orig_dtype.itemsize == 8:
-        # Mosaic has no 64-bit types: move i64/f64 columns as i32 pairs
-        buf = jax.lax.bitcast_convert_type(buf, jnp.int32)
-    # axis 0 is major, so segment d stays contiguous in the flat view; a
-    # trailing dim of 2 would be padded to a 128-lane tile
-    buf = buf.reshape(-1)
-
-    # scalar semaphores and int32 indices only: under jax_enable_x64 a
-    # Python-int index into a semaphore array lowers as i64, which
-    # tpu.memref_slice refuses
-    def kernel(x_ref, out_ref, own_sem, *sems):
-        send_sems, recv_sems = sems[:n - 1], sems[n - 1:]
-        me = jax.lax.axis_index(KEY_AXIS)
-        Q = x_ref.shape[0] // n
-        peers = [jax.lax.rem(me + hop, jnp.int32(n)) for hop in range(1, n)]
-        # no peer may write into this shard's out buffer before the shard
-        # has entered the kernel: meet at the barrier first
-        barrier = pltpu.get_barrier_semaphore()
-        for dst in peers:
-            pltpu.semaphore_signal(
-                barrier, inc=1, device_id=(dst,),
-                device_id_type=pltpu.DeviceIdType.MESH)
-        pltpu.semaphore_wait(barrier, n - 1)
-        # refs live in HBM (memory_space=ANY): the own segment moves by a
-        # local DMA, not a vector load/store
-        own = pltpu.make_async_copy(
-            x_ref.at[pl.ds(me * Q, Q)], out_ref.at[pl.ds(me * Q, Q)],
-            own_sem)
-        own.start()
-        descs = [own]
-        for dst, send_sem, recv_sem in zip(peers, send_sems, recv_sems):
-            d = pltpu.make_async_remote_copy(
-                src_ref=x_ref.at[pl.ds(dst * Q, Q)],
-                dst_ref=out_ref.at[pl.ds(me * Q, Q)],
-                send_sem=send_sem,
-                recv_sem=recv_sem,
-                device_id=(dst,),
-                device_id_type=pltpu.DeviceIdType.MESH,
-            )
-            d.start()
-            descs.append(d)
-        for d in descs:
-            d.wait()
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())] * (2 * n - 1),
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            collective_id=0, has_side_effects=True),
-    )(buf)
-    if orig_dtype == jnp.bool_:
-        out = out.astype(jnp.bool_)
-    elif orig_dtype.itemsize == 8:
-        out = jax.lax.bitcast_convert_type(
-            out.reshape(orig_shape + (2,)), orig_dtype)
-    return out.reshape(orig_shape)

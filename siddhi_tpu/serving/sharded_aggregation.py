@@ -14,8 +14,7 @@ This module replaces that DB round trip with in-process mesh sharding:
   Stream Processing Engines" — share the program, split the data).
 - **Routing.** A group tuple's owner is ``crc32(key) % n_shards`` — the
   same owner-by-modulus convention as the keyed-query sharding
-  (``parallel/mesh.device_route_query_step``; the old host-side
-  ``route_batch_to_shards`` is a deprecated shim). Ingest prepares a batch once
+  (``parallel/mesh.device_route_query_step``). Ingest prepares a batch once
   (``_prepare_batch``) and folds each shard's row subset under that
   shard's own lock, so two shards never contend.
 - **Snapshot reads, no stop-the-world.** Queries read per-shard

@@ -89,7 +89,7 @@ def test_fixture_findings_are_single_rule():
 def test_clean_tree_zero_findings():
     """The acceptance bar: the repaired production tree lints clean."""
     modules = load_modules(
-        ("siddhi_tpu", "tools", "bench.py", "chip_smoke.py",
+        ("siddhi_tpu", "tools", "chip_smoke.py",
          "__graft_entry__.py"), REPO)
     findings = run_lint(modules)
     assert not findings, "\n".join(f.format() for f in findings)
@@ -217,9 +217,27 @@ def test_step_registry_resolves():
     (hlo_audit trusts this registry for its coverage assertion)."""
     from siddhi_tpu.analysis.step_registry import JIT_STEP_BUILDERS, resolve
 
-    assert len(JIT_STEP_BUILDERS) >= 7
+    # the exact set: a builder that disappears, or appears, is seen
+    assert sorted(JIT_STEP_BUILDERS) == [
+        "device_join", "device_routed", "fused_fanout",
+        "gspmd_replicated_batch", "query_step", "sharded_agg"]
     for name in JIT_STEP_BUILDERS:
         assert resolve(name) is not None
+
+
+def test_hlo_audit_covers_the_registry():
+    """``tools/hlo_audit.py`` has one ``@audit`` for every registered
+    builder and none for a builder that is gone (its ``main`` asserts the
+    same before it lowers anything)."""
+    import importlib.util
+
+    from siddhi_tpu.analysis.step_registry import JIT_STEP_BUILDERS
+
+    spec = importlib.util.spec_from_file_location(
+        "hlo_audit", os.path.join(REPO, "tools", "hlo_audit.py"))
+    hlo_audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(hlo_audit)
+    assert sorted(hlo_audit.AUDITS) == sorted(JIT_STEP_BUILDERS)
 
 
 def test_graftlint_driver_exits_zero():

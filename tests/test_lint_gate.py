@@ -21,7 +21,7 @@ from siddhi_tpu.analysis import default_rules, load_modules, run_lint
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
-ROOTS = ("siddhi_tpu", "tools", "bench.py", "chip_smoke.py",
+ROOTS = ("siddhi_tpu", "tools", "chip_smoke.py",
          "__graft_entry__.py")
 
 

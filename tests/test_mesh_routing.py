@@ -63,6 +63,22 @@ def _feed(rt, lo, hi, n_sym=13, n_side=5):
                 float(i % 17) + 0.25, int(i)])
 
 
+def _spy_step_calls(q):
+    """Record every ``(step, (state, cols, now))`` the runtime dispatches
+    from here on."""
+    seen, finish = [], q._finish_device_batch
+
+    def spying_finish(step, cols, overflow_msg):
+        def spy(*args):
+            seen.append((step, args))
+            return step(*args)
+
+        return finish(spy, cols, overflow_msg)
+
+    q._finish_device_batch = spying_finish
+    return seen
+
+
 def _run_unsharded(app, lo=0, hi=400):
     m, rt, c = _build(app)
     _feed(rt, lo, hi)
@@ -274,16 +290,7 @@ def test_egress_moves_own_rows_only(app, stream, row):
     m, rt, _c = _build(app)
     q = rt.query_runtimes["q"]
     device_route_query_step(q, make_mesh(4), rows_per_shard=64)
-    seen, finish = [], q._finish_device_batch
-
-    def spying_finish(step, cols, overflow_msg):
-        def spy(*args):
-            seen.append((step, args))
-            return step(*args)
-
-        return finish(spy, cols, overflow_msg)
-
-    q._finish_device_batch = spying_finish
+    seen = _spy_step_calls(q)
     h = rt.get_input_handler(stream)
     for i in range(32):
         h.send(row(i))
@@ -457,6 +464,38 @@ def test_ineligible_runtimes_raise_cleanly():
         device_route_query_step(rt.query_runtimes["q"], make_mesh(2),
                                 rows_per_shard=64)
     m.shutdown()
+
+
+def test_one_exchange_and_any_other_value_is_refused():
+    """``exchange`` is what the configuration files still pass: ``None``
+    and ``"all_to_all"`` install the same program, and any other value is
+    refused by name, on every platform, before anything is installed."""
+    from siddhi_tpu.ops.expressions import CompileError
+
+    m, rt, _c = _build(DISTINCT_GK_APP)
+    q = rt.query_runtimes["q"]
+    with pytest.raises(CompileError, match="exchange = 'pallas_ring'"):
+        device_route_query_step(q, make_mesh(2), rows_per_shard=64,
+                                exchange="pallas_ring")
+    assert q._route_layout is None
+    m.shutdown()
+
+    def routed_program(exchange):
+        m, rt, _c = _build(DISTINCT_GK_APP)
+        q = rt.query_runtimes["q"]
+        device_route_query_step(q, make_mesh(2), rows_per_shard=64,
+                                exchange=exchange)
+        seen = _spy_step_calls(q)
+        _feed(rt, 0, 8)
+        step, (state, cols, now) = seen[-1]
+        text = step._routed_raw.lower(
+            state, cols, q._route_layout.device_luts(), now).as_text()
+        m.shutdown()
+        return text
+
+    named = routed_program("all_to_all")
+    assert "all_to_all" in named
+    assert routed_program(None) == named
 
 
 def test_purged_groups_do_not_leak_into_new_ones():

@@ -131,7 +131,7 @@ def test_routed_answers_equal_the_plain_reference(case, shards):
         <= CONFIG["limits"]["avg_max_abs_err"]
 
 
-@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_routed_rows_equal_the_unsharded_run_in_order(case, shards):
     routed, plain = _deployed(case, shards), _deployed(case, 0)

@@ -222,115 +222,3 @@ def test_sharded_partitioned_absent_pattern():
     m2.shutdown()
     assert len(c1.events) > 0
     assert [e.data for e in c1.events] == [e.data for e in c2.events]
-
-
-def test_shard_map_routed_keyed_window_matches_unsharded():
-    """Round-5 zero-collective path: host router + shard_map over local
-    [K/n] keyed state must reproduce the unsharded per-key output
-    sequences exactly (tools/hlo_audit.py separately asserts the compiled
-    HLO carries no collectives)."""
-    import jax
-
-    from siddhi_tpu.core.plan.selector_plan import GK_KEY
-    from siddhi_tpu.ops.expressions import PK_KEY, TS_KEY, TYPE_KEY, VALID_KEY
-    from siddhi_tpu.parallel.mesh import (
-        route_batch_to_shards, shard_keyed_query_step)
-
-    APP = """
-        define stream S (symbol string, price float, volume long);
-        partition with (symbol of S)
-        begin
-          @info(name = 'q')
-          from S#window.length(8)
-          select symbol, avg(price) as ap, sum(volume) as tv
-          insert into Out;
-        end;
-    """
-    NUM_KEYS, B, N = 40, 64, 8
-    rng = np.random.default_rng(0)
-
-    def make_batch(i):
-        sym = rng.integers(0, NUM_KEYS, B, dtype=np.int64)
-        return {
-            TS_KEY: np.arange(i * B, (i + 1) * B, dtype=np.int64),
-            TYPE_KEY: np.zeros(B, np.int8),
-            VALID_KEY: np.ones(B, bool),
-            "symbol": sym, "symbol?": np.zeros(B, bool),
-            "price": (rng.random(B) * 100).astype(np.float32),
-            "price?": np.zeros(B, bool),
-            "volume": rng.integers(1, 1000, B, np.int64),
-            "volume?": np.zeros(B, bool),
-            GK_KEY: sym.astype(np.int32), PK_KEY: sym.astype(np.int32),
-        }
-
-    batches = [make_batch(i) for i in range(3)]
-
-    def collect(outs, n_shards=None):
-        rows = {}
-        for out in outs:
-            v = np.asarray(out[VALID_KEY])
-            pk = np.asarray(out[PK_KEY])
-            r_local = len(v) // (n_shards or 1)
-            for j in np.nonzero(v)[0]:
-                k = int(pk[j])
-                if n_shards is not None:
-                    k = k * n_shards + j // r_local  # local id -> global
-                rows.setdefault(k, []).append((
-                    int(out[TS_KEY][j]), int(out[TYPE_KEY][j]),
-                    round(float(out["ap"][j]), 3), int(out["tv"][j])))
-        return rows
-
-    m1 = SiddhiManager()
-    rt1 = m1.create_siddhi_app_runtime(APP)
-    rt1.start()
-    q1 = rt1.query_runtimes["q"]
-    q1.selector_plan.num_keys = 64
-    q1._win_keys = 64
-    state = q1._init_state()
-    step = jax.jit(q1.build_step_fn())
-    uns = []
-    for i, b in enumerate(batches):
-        state, out = step(state, b, np.int64(10_000 + i))
-        uns.append(jax.device_get(out))
-    m1.shutdown()
-
-    m2 = SiddhiManager()
-    rt2 = m2.create_siddhi_app_runtime(APP)
-    rt2.start()
-    q2 = rt2.query_runtimes["q"]
-    q2.selector_plan.num_keys = 16   # local capacity: ceil(40/8) -> 16
-    q2._win_keys = 16
-    sstep, sstate = shard_keyed_query_step(q2, make_mesh(8), rows_per_shard=B)
-    sh = []
-    for i, b in enumerate(batches):
-        rb = route_batch_to_shards(b, 8, B)
-        sstate, out = sstep(sstate, rb, np.int64(10_000 + i))
-        sh.append(jax.device_get(out))
-    m2.shutdown()
-
-    u, s = collect(uns), collect(sh, n_shards=8)
-    assert set(u) == set(s)
-    assert all(u[k] == s[k] for k in u)
-
-
-def test_route_batch_overflow_raises():
-    """Round-6: the legacy host router's overflow follows the
-    FatalQueryError + knob-naming convention (it used to die with a bare
-    ValueError), and the router itself is a deprecated shim."""
-    import warnings
-
-    import pytest
-
-    from siddhi_tpu.core.plan.selector_plan import GK_KEY
-    from siddhi_tpu.core.stream.junction import FatalQueryError
-    from siddhi_tpu.ops.expressions import PK_KEY, VALID_KEY
-    from siddhi_tpu.parallel.mesh import route_batch_to_shards
-
-    cols = {PK_KEY: np.zeros(16, np.int32), GK_KEY: np.zeros(16, np.int32),
-            VALID_KEY: np.ones(16, bool)}
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        with pytest.raises(DeprecationWarning):
-            route_batch_to_shards(cols, 4, 16)   # shim warns
-    with pytest.raises(FatalQueryError, match="rows_per_shard"):
-        route_batch_to_shards(cols, 4, 2)  # 16 rows all on shard 0 > 2

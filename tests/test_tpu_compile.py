@@ -18,7 +18,7 @@ import time
 import jax
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
 
 from siddhi_tpu import SiddhiManager
@@ -239,23 +239,6 @@ def _mesh4(topo):
     return Mesh(np.asarray(topo.devices[:4]), (KEY_AXIS,))
 
 
-@pytest.mark.parametrize("dtype", [np.int32, np.int64, np.bool_])
-def test_pallas_ring_exchange_compiles(topo, dtype):
-    from siddhi_tpu.parallel.mesh import KEY_AXIS, _pallas_ring_exchange
-
-    mesh = _mesh4(topo)
-    fn = jax.jit(jax.shard_map(
-        lambda buf: _pallas_ring_exchange(buf, 4), mesh=mesh,
-        in_specs=P(KEY_AXIS), out_specs=P(KEY_AXIS), check_vma=False))
-    x = jax.ShapeDtypeStruct((4 * 4 * 1_024,), dtype,
-                             sharding=NamedSharding(mesh, P(KEY_AXIS)))
-    t0 = time.perf_counter()
-    compiled = fn.lower(x).compile()
-    _report(f"pallas_ring exchange n=4 Q=1024 {np.dtype(dtype)}", compiled,
-            time.perf_counter() - t0)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 _COLLECTIVE = re.compile(
     r" (all-to-all|all-gather|all-reduce|collective-permute)(?:-start)?\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -279,8 +262,6 @@ def _collectives(hlo_text):
 # rows a shard, per-pair quota 20,480
 @pytest.mark.parametrize("exchange,marker,window,keys,batch,rows_per_shard", [
     ("all_to_all", "all-to-all", 100, 1_000, 4_096, 4_096),
-    pytest.param("pallas_ring", "tpu_custom_call", 100, 1_000, 4_096, 4_096,
-                 marks=pytest.mark.slow),
     pytest.param("all_to_all", "all-to-all", 1_000, 40_000, 262_144, 81_920,
                  marks=pytest.mark.slow),
 ])
@@ -314,7 +295,6 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     # the same layout and body over the described chips: shard_map takes
     # its devices from the mesh, so plain avals are enough
     q._route_layout.mesh = _mesh4(topo)
-    q._route_layout.exchange = exchange
     routed = M.routed_step_for(q)._routed_raw
     manager.shutdown()
     t0 = time.perf_counter()

@@ -44,7 +44,7 @@ if "siddhi_tpu" not in sys.modules:
     _pkg.__path__ = [os.path.join(REPO, "siddhi_tpu")]
     sys.modules["siddhi_tpu"] = _pkg
 
-DEFAULT_ROOTS = ("siddhi_tpu", "tools", "bench.py", "chip_smoke.py",
+DEFAULT_ROOTS = ("siddhi_tpu", "tools", "chip_smoke.py",
                  "__graft_entry__.py")
 
 

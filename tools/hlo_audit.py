@@ -6,8 +6,8 @@ CPU tool: forces the CPU backend and is never on the chip path
 Round 4 shipped this as a hand-kept pair of lowerings; it is now a
 REGISTRY-driven audit: every entry in
 ``siddhi_tpu/analysis/step_registry.py`` (the declarative list of all
-jitted step builders — query, fused fan-out, GSPMD + host-routed +
-device-routed sharding, device join, sharded-agg serving) must have a
+jitted step builders — query, fused fan-out, GSPMD and device-routed
+sharding, device join, sharded-agg serving) must have a
 matching ``@audit`` function here, so a new step builder fails the
 quick tier until it is audited — coverage by construction, not memory.
 
@@ -295,40 +295,6 @@ define stream R (sym string, rv long);
     }
     m.shutdown()
     return report
-
-
-@audit("shard_map_routed")
-def _audit_shard_map_routed(ctx):
-    """Round-5 strategy: host-routed batch, shard_map local state."""
-    from siddhi_tpu import SiddhiManager
-    from siddhi_tpu.parallel.mesh import (route_batch_to_shards,
-                                          shard_keyed_query_step)
-
-    m = SiddhiManager()
-    rt = m.create_siddhi_app_runtime(_APP)
-    rt.start()
-    q = rt.query_runtimes["bench"]
-    local_k = 2_048  # pow2(ceil(10k / 8))
-    q.selector_plan.num_keys = local_k
-    q._win_keys = local_k
-    rows = B // N_DEV * 2
-    jitted, state = shard_keyed_query_step(q, ctx.mesh, rows_per_shard=rows)
-    import warnings
-
-    with warnings.catch_warnings():
-        # route_batch_to_shards is a deprecated shim kept as the audit's
-        # reference router
-        warnings.simplefilter("ignore", DeprecationWarning)
-        routed = route_batch_to_shards(ctx.batch, N_DEV, rows)
-    hlo = jitted.lower(state, routed, np.int64(0)).compile().as_text()
-    _assert_no_host_transfers(hlo, "host-routed shard_map step")
-    counts = _count_collectives(hlo)
-    # host-routed rows + local state: the whole point is ZERO
-    # collectives per step (the round-5 mesh-curve fix)
-    assert not counts, (
-        f"host-routed shard_map step grew collectives: {counts}")
-    m.shutdown()
-    return counts
 
 
 @audit("device_routed")

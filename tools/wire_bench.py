@@ -16,8 +16,7 @@ zero-copy wire path (client ``WireEncoder.encode`` -> ``decode_frame``
 alone. ``rest`` additionally drives frames through a live
 ``POST /ingest/{stream}`` endpoint from concurrent client threads.
 
-Prints ONE JSON line; ``bench.py --section ingest`` embeds the same
-numbers in the BENCH artifact with the ``host_cores`` caveat field.
+Prints ONE JSON line.
 """
 
 import json
