@@ -21,7 +21,8 @@ import numpy as np
 
 from siddhi_tpu.observability import journey
 from siddhi_tpu.observability.tracing import span, spans_on
-from siddhi_tpu.ops.expressions import TS_KEY, TYPE_KEY, VALID_KEY
+from siddhi_tpu.ops.expressions import (PADDED_KEY, TS_KEY, TYPE_KEY,
+                                        VALID_KEY)
 from siddhi_tpu.ops.types import dtype_of
 from siddhi_tpu.query_api.definitions import AbstractDefinition, AttrType
 
@@ -296,25 +297,58 @@ def _pull(refs: list, rows: bool) -> list:
         return jax.device_get(refs)
     nbytes = sum(int(getattr(r, "nbytes", 0)) for r in refs)
     jr = journey.emitting_journey()
-    with span("pull", bytes=nbytes, arrays=len(refs),
+    length = max((r.shape[0] for r in refs if getattr(r, "ndim", 0)),
+                 default=0) if rows else 0
+    with span("pull", bytes=nbytes, arrays=len(refs), rows=length,
               batch=jr.batch if jr is not None else None) as sp:
         out = jax.device_get(refs)
     if jr is not None:
-        jr.pulled(sp.ms or 0.0, max(
-            (r.shape[0] for r in refs if getattr(r, "ndim", 0)),
-            default=0) if rows else 0)
+        jr.pulled(sp.ms or 0.0, length)
     return out
 
 
 class LazyColumns(dict):
     """Column dict whose device-array values materialize to numpy on first
-    access. Every device->host pull is a synchronization with a fixed
-    cost regardless of size (on a co-located chip: not measured), but
+    access. A device->host pull is a synchronization that costs a fixed
+    part and the bytes it moves (on the v5e 1.3 ms a pull plus 1.9 ms per
+    65,536 rows of a cell's columns: PERF_LEDGER.jsonl, PR 29), and
     ``jax.device_get`` batches arbitrarily many arrays into one round
     trip — so the first touched device column pulls every remaining
     device column in one transfer, and
     consumers that never read data columns (output counters served by the
-    ``__meta__`` size hint) pull nothing."""
+    ``__meta__`` size hint) pull nothing.
+
+    An NFA step's columns are its valid rows compacted to a narrower
+    static width (``ops/compact.py``), and the same columns at their padded
+    width ride beside them under ``PADDED_KEY``. Those are held aside, out
+    of reach of a pull, until ``choose`` is told the meta's count: it
+    throws them away where the count fits the compacted columns and swaps
+    them in where it does not. Whoever builds a ``HostBatch`` from a
+    step's output calls it first (``QueryRuntime._host_batch``); a pull of
+    an output nobody chose for moves the padded columns, which hold every
+    row whatever the count."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self._padded = dict.pop(self, PADDED_KEY, None)
+        # after a fall-back, the compacted width it fell back from
+        self.fell_back_from = None
+
+    def choose(self, count: Optional[int]):
+        """Settle what a pull will move, before anything touches a column:
+        the compacted columns if the output's ``count`` valid rows fit them
+        (True), else the padded ones (False; ``count`` None: not known, so
+        they do not). None, and nothing done, for an output that carries
+        one set of columns only."""
+        padded, self._padded = self._padded, None
+        if padded is None:
+            return None
+        width = dict.__getitem__(self, VALID_KEY).shape[0]
+        if count is not None and count <= width:
+            return True
+        dict.update(self, padded)
+        self.fell_back_from = width
+        return False
 
     def __getitem__(self, k):
         v = super().__getitem__(k)
@@ -324,6 +358,8 @@ class LazyColumns(dict):
         return v
 
     def _materialize_all(self):
+        if self._padded is not None:
+            self.choose(None)
         pending = [(key, val) for key, val in super().items()
                    if not isinstance(val, np.ndarray)]
         if not pending:

@@ -114,8 +114,6 @@ class QueryCompletion:
         return _is_ready(self.meta_refs()[0])
 
     def complete(self, metas: list) -> Optional[Exception]:
-        from siddhi_tpu.core.event import HostBatch
-
         q = self.owner
         meta = np.asarray(metas[0])
         dict.pop(self.out, "__meta__")
@@ -146,7 +144,7 @@ class QueryCompletion:
                     f"creating the runtime")
             # the owner's emit stage (siddhi.emit span + journey), as in
             # the synchronous tail
-            q._timed_emit(HostBatch(self.out, size=size), self.journey,
+            q._timed_emit(q._host_batch(self.out, size), self.journey,
                           rows_out=size)
             if notify >= 0 and q.scheduler is not None:
                 q.scheduler.notify_at(

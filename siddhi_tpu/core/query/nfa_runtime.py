@@ -26,10 +26,13 @@ from siddhi_tpu.core.plan.selector_plan import GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
 from siddhi_tpu.observability import journey
-from siddhi_tpu.observability.instruments import (META_SCOPE, SELECT_SCOPE,
-                                                  STATE_SCOPE, named_step)
+from siddhi_tpu.observability.instruments import (COMPACT_SCOPE, META_SCOPE,
+                                                  SELECT_SCOPE, STATE_SCOPE,
+                                                  named_step)
 from siddhi_tpu.observability.tracing import span
-from siddhi_tpu.ops.expressions import PK_KEY, TS_KEY, TYPE_KEY, VALID_KEY
+from siddhi_tpu.ops.compact import compact_columns, compact_width
+from siddhi_tpu.ops.expressions import (PADDED_KEY, PK_KEY, TS_KEY, TYPE_KEY,
+                                        VALID_KEY)
 from siddhi_tpu.ops.nfa import NFAStage
 from siddhi_tpu.query_api.definitions import StreamDefinition
 
@@ -216,7 +219,8 @@ class NFAQueryRuntime(QueryRuntime):
 
     # ---------------------------------------------------------- step builds
 
-    def build_stream_step_fn(self, stream_id: str, force_generic: bool = False):
+    def build_stream_step_fn(self, stream_id: str, force_generic: bool = False,
+                             compact: bool = True):
         """Pure (state, cols, now) -> (state', out) for one input stream —
         the NFA transition fused with the selector stage (unless a host
         group-by keyer has to run between them). ``force_generic`` builds
@@ -224,9 +228,14 @@ class NFAQueryRuntime(QueryRuntime):
         timestamps are hostile to the fast kernel (see
         ``process_stream_batch``); an in-graph ``lax.cond`` would instead
         break buffer donation (XLA copies the whole [K, S] state through
-        conditionals — measured 11 big copies/step)."""
+        conditionals — measured 11 big copies/step). ``compact``: the
+        output's columns are its valid rows compacted (see
+        ``_select_and_meta_fn``)."""
         stage = self.stage
         _select_and_meta = self._select_and_meta_fn()
+        # a GSPMD-sharded step keeps the padded pull: a compaction across a
+        # sharded axis would bring collectives nobody has measured
+        compact = compact and self._shard_mesh is None
 
         def step(state, cols, current_time):
             from siddhi_tpu.core.plan.selector_plan import STR_RANK
@@ -246,8 +255,9 @@ class NFAQueryRuntime(QueryRuntime):
             notify = out_cols.pop("__notify__", None)
             if strrank is not None:
                 out_cols[STR_RANK] = strrank
-            return _select_and_meta(state, new_nfa, out_cols, overflow,
-                                    notify, ctx)
+            return _select_and_meta(
+                state, new_nfa, out_cols, overflow, notify, ctx,
+                batch_rows=cols[VALID_KEY].shape[0] if compact else None)
 
         return step
 
@@ -255,12 +265,27 @@ class NFAQueryRuntime(QueryRuntime):
         """The tail every NFA step shares (stream and timer): the
         selector over the stage's emissions, unless a host group-by keyer
         has to run between them, then the packed meta with the
-        ``nfa_runs`` lane."""
+        ``nfa_runs`` lane.
+
+        Given the input batch's ``batch_rows``, the output's columns are
+        its valid rows compacted to a width that follows from the batch's
+        (``ops/compact.py``): the same rows in the same order (event order,
+        then slot order), ``__valid__`` the first ``count`` positions. The
+        columns at their padded width ride beside them under
+        ``PADDED_KEY``, and the host swaps those in whenever the meta's
+        count does not fit (``LazyColumns.choose``). Not engaged, on
+        purpose: the timer step (no batch), the split-keyer path (the host
+        keyer reads the NFA's output at once), a GSPMD-sharded step
+        (``build_stream_step_fn``), and wherever the width would not be
+        well under the padded one (small ``nfa_slots``). Their output is
+        the padded columns alone."""
         sel = self.selector_plan
         split = self.keyer is not None
         ins_on = self._instruments_on()
 
-        def tail(state, new_nfa, out_cols, overflow, notify, ctx):
+        def tail(state, new_nfa, out_cols, overflow, notify, ctx,
+                 batch_rows=None):
+            padded = None
             if split:
                 out, new_sel = out_cols, state["sel"]
                 out["__overflow__"] = overflow
@@ -268,13 +293,28 @@ class NFAQueryRuntime(QueryRuntime):
             else:
                 with jax.named_scope(SELECT_SCOPE):
                     new_sel, out = sel.apply(state["sel"], out_cols, ctx)
+                rows = out[VALID_KEY].shape[0]
+                width = (compact_width(batch_rows, rows)
+                         if batch_rows is not None else None)
+                if width is not None:
+                    # the columns: what is as long as the valid mask; the
+                    # rest (the selector's 0-d overflow flag) is the meta's
+                    padded = {k: v for k, v in out.items()
+                              if jnp.ndim(v) >= 1 and v.shape[0] == rows}
+                    with jax.named_scope(COMPACT_SCOPE):
+                        twins = compact_columns(padded, width)
                 if overflow is not None:
                     out["__overflow__"] = overflow
                 if notify is not None:
                     out["__notify__"] = notify
             with jax.named_scope(META_SCOPE):
-                return ({"nfa": new_nfa, "sel": new_sel},
-                        _nfa_meta(pack_meta(out), new_nfa, ins_on))
+                out = _nfa_meta(pack_meta(out), new_nfa, ins_on)
+            if padded is not None:
+                # the meta counted the padded mask: it says more than the
+                # width where the rows do not fit
+                out = {**{k: v for k, v in out.items() if k not in padded},
+                       **twins, PADDED_KEY: padded}
+            return {"nfa": new_nfa, "sel": new_sel}, out
 
         return tail
 
@@ -295,8 +335,11 @@ class NFAQueryRuntime(QueryRuntime):
         return step
 
     def build_step_fn(self):
-        # single-step export (driver compile checks): first stream's step
-        return self.build_stream_step_fn(self.stage.plan.stream_ids[0])
+        # single-step export (driver compile checks, and what
+        # shard_query_step jits with the mesh's shardings): first stream's
+        # step, its output padded only
+        return self.build_stream_step_fn(self.stage.plan.stream_ids[0],
+                                         compact=False)
 
     # ----------------------------------------------------------- processing
 
@@ -548,7 +591,7 @@ class NFAQueryRuntime(QueryRuntime):
             out_host.pop("__notify__", None)
             out_host = self._host_keyed_select(out_host)
             size_hint = None
-        self._timed_emit(HostBatch(out_host, size=size_hint), jr,
+        self._timed_emit(self._host_batch(out_host, size_hint), jr,
                          rows_out=size_hint)
         if notify >= 0:
             return notify

@@ -1014,6 +1014,23 @@ class QueryRuntime(Receiver):
             return int(notify)
         return None
 
+    def _host_batch(self, out_host: LazyColumns,
+                    size: Optional[int]) -> HostBatch:
+        """The batch to emit from a step's output, once its meta has said
+        how many rows are valid: the one place that settles, before
+        anything touches a column, whether a pull moves the compacted
+        columns or the padded ones (``LazyColumns.choose``: an NFA stream
+        step's output; a no-op for every other step's), and counts the
+        choice (``pull.<query>.compacted`` / ``.padded``; an output with
+        no valid row is never pulled and counts as neither). ``size``
+        None (no meta said): the padded columns."""
+        compacted = out_host.choose(size)
+        if compacted is not None and size:
+            self.app_context.telemetry.count(
+                f"pull.{self.name}."
+                + ("compacted" if compacted else "padded"))
+        return HostBatch(out_host, size=size)
+
     def _timed_emit(self, out: HostBatch, jr, rows_out=None) -> None:
         """``_emit`` inside the journey's emit stage (``siddhi.emit``
         span; at its close the journey is finished: histograms + ring) —
@@ -1103,7 +1120,7 @@ class QueryRuntime(Receiver):
                     # reported (first-error-wins dropped the later
                     # members' knobs); still drain-then-raise
                     overflow_errs.append(overflow_msg)
-                self._emit(HostBatch(out_host, size=size))
+                self._emit(self._host_batch(out_host, size))
                 if notify >= 0:
                     notify_min = notify if notify_min is None else min(notify_min, notify)
             if overflow_errs:
@@ -1153,7 +1170,14 @@ class QueryRuntime(Receiver):
                 # device column to the host
                 t = cols[TYPE_KEY]
                 cols[TYPE_KEY] = np.where(t == EXPIRED, CURRENT, t).astype(np.int8)
-            self.output_junction.send_batch(HostBatch(cols, size=out._size))
+            # more matches than an NFA step's compacted width holds (a
+            # fall-back to the padded columns): downstream has compiled for
+            # that width, so the rows go on in pieces of it and it never
+            # sees the padded one
+            width = getattr(out.cols, "fell_back_from", None)
+            for piece in ([HostBatch(cols, size=out._size)] if width is None
+                          else _valid_rows_in_pieces(cols, width)):
+                self.output_junction.send_batch(piece)
             return
         want_pk = self.attach_pk or self.limiter_needs_pk
         events = out.to_events(
@@ -1192,6 +1216,20 @@ class QueryRuntime(Receiver):
             in_events = [e for e in events if not e.is_expired] or None
             remove_events = [e for e in events if e.is_expired] or None
             cb.receive(events[0].timestamp, in_events, remove_events)
+
+
+def _valid_rows_in_pieces(cols: LazyColumns, width: int):
+    """The valid rows of ``cols``, in order, as batches ``width`` wide
+    (host side: this pulls the columns)."""
+    rows = np.nonzero(cols[VALID_KEY])[0]
+    for at in range(0, rows.size, width):
+        take = rows[at:at + width]
+        piece = {}
+        for k in cols:
+            col = cols[k]
+            piece[k] = np.zeros((width,) + col.shape[1:], col.dtype)
+            piece[k][:take.size] = col[take]
+        yield HostBatch(piece, size=int(take.size))
 
 
 def backfill_null_masks(batch: HostBatch, definition) -> None:

@@ -63,6 +63,10 @@ import numpy as np
 STATE_SCOPE = "siddhi.state"
 SELECT_SCOPE = "siddhi.select"
 META_SCOPE = "siddhi.meta"
+# An NFA stream step's tail also compacts its emitted rows to a static
+# width (``ops/compact.py``), between the selector and the meta, in a scope
+# of its own: no metric of the benchmark reads it, a trace shows its cost.
+COMPACT_SCOPE = "siddhi.compact"
 # The device-routed step (``parallel/mesh.py`` ``routed_step_for``) wraps
 # that body in two more, beside the three and never around them: ingress
 # (owner, bucketing, the exchange, the id rewrite) and egress (the order

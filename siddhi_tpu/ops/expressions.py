@@ -51,6 +51,10 @@ TS_KEY = "__ts__"
 TYPE_KEY = "__type__"
 VALID_KEY = "__valid__"
 PK_KEY = "__pk__"  # partition-key id column (dense, host-computed)
+# An NFA step's OUTPUT whose columns hold the valid rows compacted to a
+# narrower static width (ops/compact.py) carries, under this key, a nested
+# dict: the same columns at their padded width, for when the rows do not fit
+PADDED_KEY = "__padded__"
 # Device-routed sharding (parallel/mesh.device_route_query_step) carries
 # TWO dense id spaces per row: the partition key (PK_KEY, owner = pk % n,
 # local id = pk // n) and the group-by key (GK_KEY, owned by its pk's

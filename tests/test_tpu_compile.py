@@ -22,6 +22,7 @@ from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
 
 from siddhi_tpu import SiddhiManager
+from siddhi_tpu.ops.expressions import PADDED_KEY
 
 _STOCK = """
 @app:precision('{precision}')
@@ -228,6 +229,13 @@ def test_nfa_steps_compile(one_chip, keys, batch):
     assert sorted(steps.seen) == [("AStream", False), ("BStream", False)]
     for (stream, _generic), (step, avals) in sorted(steps.seen.items()):
         assert avals[0]["nfa"]["consumed"].shape[0] == 16_384
+        # both steps carry, in the ONE program, their emitted rows
+        # compacted to twice the batch (ops/compact.py) and, beside them,
+        # padded ([batch x 33])
+        out = step.lower(*avals).out_info[1]
+        assert out["v1"].shape == (2 * batch,)
+        assert {k: v.shape for k, v in out[PADDED_KEY].items()} == {
+            k: (batch * 33,) for k in out if k not in (PADDED_KEY, "__meta__")}
         compiled, seconds = _compile_for(one_chip, step, avals)
         _report(f"C nfa {stream} step B={batch} K=16384", compiled, seconds)
 
