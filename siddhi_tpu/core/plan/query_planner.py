@@ -1043,6 +1043,16 @@ def plan_query(
                 "length", [window_stage.length], selector_plan, app_context)
             if fused is not None:
                 window_stage = fused
+    if window_stage is not None and not post_pipeline and partition_ctx is None:
+        # a tumbling window whose selector reads only group keys and
+        # aggregates that reset at each flush keeps accumulators as wide
+        # as its keys, not the window's events (ops/tumbling_agg.py)
+        from siddhi_tpu.ops.tumbling_agg import plan_tumbling_fold
+
+        folded = plan_tumbling_fold(
+            window_stage, query.selector, selector_plan, resolver)
+        if folded is not None:
+            window_stage = folded
 
     runtime = QueryRuntime(
         name=query_name,

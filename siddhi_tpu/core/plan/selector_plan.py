@@ -168,8 +168,9 @@ class SelectorPlan:
     limit: Optional[int]
     offset: Optional[int]
     num_keys: int = 16
-    # a fused upstream stage (ops/fused_agg.py) already computed the
-    # aggregate columns; skip the scans and just project/filter
+    # a fused upstream stage (ops/fused_agg.py, ops/tumbling_agg.py)
+    # already computed the aggregate columns (and, of a batch chunk, left
+    # one row a group); skip the scans and the collapse, project/filter
     precomputed: bool = False
     # output columns whose value is a host-generated UUID per row (the
     # device step emits placeholders; QueryRuntime._emit fills them)
@@ -249,7 +250,8 @@ class SelectorPlan:
         if self.having_fn is not None:
             valid = valid & self.having_fn(out, ctx)
 
-        if self.batch_mode and (self.contains_aggregator or self.group_by):
+        if (self.batch_mode and not self.precomputed
+                and (self.contains_aggregator or self.group_by)):
             # keep only the last valid row per (flush epoch, group) — GK is
             # the partition id for keyless partitioned queries, so per-key
             # flushes in one multi-key chunk stay distinct

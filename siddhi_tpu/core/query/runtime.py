@@ -632,29 +632,39 @@ class QueryRuntime(Receiver):
 
     def process_timer(self, ts: int):
         """Inject a TIMER chunk (the role of Scheduler.sendTimerEvents +
-        EntryValveProcessor in the reference)."""
-        batch = HostBatch.from_events(
-            [Event(timestamp=int(ts), data=[_zero_value(a.type) for a in self.input_definition.attributes])],
-            self.input_definition,
-            self.dictionary,
-        )
-        batch.cols[TYPE_KEY][...] = TIMER_TYPE
-        # take the per-query lock BEFORE setting the override: a live-mode
-        # event batch on another thread must never observe the timer's ts
-        # as its clock (the RLock nests with process_batch's own acquire)
-        with self._lock:
-            # in-flight pipelined batches were dispatched BEFORE this
-            # timer fired: drain them first so the timer sweep observes a
-            # fully-emitted timeline (and the timer batch itself runs
-            # synchronously — _now_override gates the pipeline branch)
-            pump = getattr(self.app_context, "completion_pump", None)
-            if pump is not None and pump.has_pending:
-                pump.flush_owner(self)
-            self._now_override = int(ts)
-            try:
-                self.process_batch(batch)
-            finally:
-                self._now_override = None
+        EntryValveProcessor in the reference), under the ``siddhi.timer``
+        span. Fired by a send's clock advance (playback), the chunk is
+        that send's work: span and journey take its batch id."""
+        self.app_context.telemetry.count(f"window.{self.name}.timer_steps")
+        sender = journey.sending_batch()
+        with span("timer", query=self.name, batch=sender, ts=int(ts)):
+            batch = HostBatch.from_events(
+                [Event(timestamp=int(ts), data=[_zero_value(a.type) for a in self.input_definition.attributes])],
+                self.input_definition,
+                self.dictionary,
+            )
+            batch.cols[TYPE_KEY][...] = TIMER_TYPE
+            if sender is not None \
+                    and getattr(batch, "journey", None) is not None:
+                batch.journey.batch = sender
+            # take the per-query lock BEFORE setting the override: a
+            # live-mode event batch on another thread must never observe
+            # the timer's ts as its clock (the RLock nests with
+            # process_batch's own acquire)
+            with self._lock:
+                # in-flight pipelined batches were dispatched BEFORE this
+                # timer fired: drain them first so the timer sweep
+                # observes a fully-emitted timeline (and the timer batch
+                # itself runs synchronously — _now_override gates the
+                # pipeline branch)
+                pump = getattr(self.app_context, "completion_pump", None)
+                if pump is not None and pump.has_pending:
+                    pump.flush_owner(self)
+                self._now_override = int(ts)
+                try:
+                    self.process_batch(batch)
+                finally:
+                    self._now_override = None
 
     def _apply_host_transforms(self, cols, ctx):
         for t in self.transforms:
@@ -729,6 +739,9 @@ class QueryRuntime(Receiver):
             # splits ride the first piece)
             self._cur_journey = journey.begin(batch) \
                 if journey.enabled() else None
+            if self._cur_journey is not None \
+                    and self._now_override is not None:
+                self._cur_journey.timer_steps = 1
             notify_host = None
             if self.log_stages:
                 self._run_log_taps(batch)
@@ -836,6 +849,12 @@ class QueryRuntime(Receiver):
                     self._step, cols, self.overflow_knob_msg())
         if notify_host is not None:
             notify = notify_host if notify is None else min(notify, notify_host)
+        if (notify is not None and self._now_override is not None
+                and notify <= self._now_override):
+            # a TIMER step that asks to be woken at or before its own
+            # time has not moved its boundary: waking it again would spin
+            # the playback sweep at one instant forever
+            notify = None
         if notify is not None and self.scheduler is not None:
             self.scheduler.notify_at(notify, self.process_timer)
 
@@ -1036,6 +1055,11 @@ class QueryRuntime(Receiver):
         span; at its close the journey is finished: histograms + ring) —
         the synchronous tail; pipelined batches run the same accounting
         at drain (completion.py)."""
+        if rows_out and getattr(self.window_stage, "counts_flushes", False):
+            # a folded tumbling window answers only when it closes
+            self.app_context.telemetry.count(f"window.{self.name}.flushes")
+            if jr is not None:
+                jr.flushed(rows_out)
         if jr is None:
             self._emit(out)
             return

@@ -101,6 +101,7 @@ class InputHandler:
         import numpy as np
 
         from siddhi_tpu.core.event import HostBatch, pack_pool_of
+        from siddhi_tpu.observability import journey
 
         if self._ensure_started is not None:
             self._ensure_started()
@@ -130,9 +131,11 @@ class InputHandler:
                     # must anchor at the first event, not the batch max)
                     lo = int(ts_arr.min())
                     hi = int(ts_arr.max())
-                    if lo != hi:
-                        tsg.set_current_timestamp(lo)
-                    tsg.set_current_timestamp(hi)
+                    # the timers this advance fires are this batch's work
+                    with journey.sending(batch):
+                        if lo != hi:
+                            tsg.set_current_timestamp(lo)
+                        tsg.set_current_timestamp(hi)
             wal_seq = None
             if wal is not None:
                 # raw columns, not the encoded HostBatch: replay re-encodes

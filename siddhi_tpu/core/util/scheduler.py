@@ -23,13 +23,25 @@ import time
 from typing import Callable, Dict, List, Tuple
 
 
+def _target_key(target):
+    """What makes two wake requests the same: a bound method is a new
+    object at every ``obj.method`` (its ``id`` says nothing), so it is
+    known by its owner and function; every batch of a window re-requests
+    the one boundary, and each request that got past this cost a TIMER
+    step of its own."""
+    owner = getattr(target, "__self__", None)
+    if owner is None:
+        return id(target)
+    return (id(owner), getattr(target, "__func__", None))
+
+
 class Scheduler:
     def __init__(self, app_context):
         self.app_context = app_context
         self._lock = threading.RLock()
         self._heap: List[Tuple[int, int, Callable]] = []
         self._counter = itertools.count()
-        self._scheduled: Dict[Tuple[int, int], bool] = {}
+        self._scheduled: Dict[tuple, bool] = {}
         self._live_timers: List[threading.Timer] = []
         self._periodic: List["_PeriodicJob"] = []
         self._stopped = False
@@ -40,7 +52,7 @@ class Scheduler:
 
     def notify_at(self, ts: int, target: Callable[[int], None]):
         """Request `target(ts)` to run at event/wall time `ts` (deduped)."""
-        key = (id(target), int(ts))
+        key = (_target_key(target), int(ts))
         with self._lock:
             if self._stopped or key in self._scheduled:
                 return
@@ -69,7 +81,7 @@ class Scheduler:
                 if not self._heap or self._heap[0][0] > new_ts:
                     return
                 ts, _seq, target = heapq.heappop(self._heap)
-                self._scheduled.pop((id(target), ts), None)
+                self._scheduled.pop((_target_key(target), ts), None)
             target(ts)
 
     # ----------------------------------------------------------- periodic

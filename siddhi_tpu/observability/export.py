@@ -73,6 +73,9 @@ TELEMETRY_PREFIXES = (
     "eligibility",   # build-time strategy-eligibility census counters
                      # (core/eligibility.py register_census ->
                      # siddhi_eligibility_total{surface,code,query})
+    "window",        # a scheduler-driven window's TIMER steps and a
+                     # folded tumbling window's flushes, per query
+                     # (core/query/runtime.py; generic counter family)
     "autopilot",     # closed-loop controller: mode gauge, tick/freeze
                      # counters, per-(knob,direction,reason) decision
                      # counters (siddhi_tpu/autopilot/ ->

@@ -67,6 +67,16 @@ at the drain ``shard_rows_max`` (the fullest shard's received rows, from
 the meta's ``rows_0..n-1`` lanes) beside ``shard_capacity`` (``n x Q``).
 A split batch's journey rides its first piece.
 
+A tumbling window folded into per-group accumulators
+(``ops/tumbling_agg.py``) stamps two more, ``None`` for every other
+query: ``flush_rows`` on the journey of a step that closed a window (the
+groups it delivered), and ``timer_steps`` = 1 on the journey of a TIMER
+step (``QueryRuntime.process_timer``, under the ``siddhi.timer`` span).
+Under ``@app:playback`` the scheduler fires a window's timer while the
+send that crosses the boundary advances the clock, before that send's own
+step: the TIMER step's journey and span carry that send's ``batch`` id,
+so the fields add up per send.
+
 Cost model: near-zero when off — every instrumented site checks one
 module flag and does nothing else. When on, a batch carries one small
 ``Journey`` object (a handful of floats); finished journeys land in
@@ -241,7 +251,7 @@ class Journey:
                  "_t_disp1", "_t_drain0", "ready", "meta_pull_ms",
                  "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded",
                  "route_prep_ms", "route_pieces", "shard_rows_max",
-                 "shard_capacity")
+                 "shard_capacity", "flush_rows", "timer_steps")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -263,6 +273,8 @@ class Journey:
         self.route_pieces: Optional[int] = None
         self.shard_rows_max: Optional[int] = None
         self.shard_capacity: Optional[float] = None
+        self.flush_rows: Optional[int] = None
+        self.timer_steps: Optional[int] = None
 
     # one journey object is stamped on the batch at pack time; each
     # receiving query forks its own (stage times are per query)
@@ -307,6 +319,11 @@ class Journey:
         received rows, and what a shard can receive."""
         self.shard_rows_max = rows_max
         self.shard_capacity = capacity
+
+    def flushed(self, rows: int) -> None:
+        """This step closed a tumbling window (``ops/tumbling_agg.py``)
+        and delivers ``rows`` groups."""
+        self.flush_rows = rows
 
     def emitting(self, app_context, names, rows_out: Optional[int] = None):
         """The emit stage as a context manager: ``siddhi.emit`` span,
@@ -383,6 +400,12 @@ class Journey:
                 "route_pieces": self.route_pieces,
                 "shard_rows_max": self.shard_rows_max,
                 "shard_capacity": self.shard_capacity,
+                # a tumbling window's: the groups this step's flush
+                # delivered; 1 where the step was a scheduler's TIMER
+                # step (its batch id is that of the send whose clock
+                # advance fired it); None for every other
+                "flush_rows": self.flush_rows,
+                "timer_steps": self.timer_steps,
             })
 
 
@@ -413,6 +436,41 @@ class _EmitStage:
 def emitting_journey() -> Optional[Journey]:
     """The journey whose emit stage is open on this thread, if any."""
     return getattr(_TLS, "emitting", None)
+
+
+class _Sending:
+    """``sending``: the batch whose send is advancing the clock."""
+
+    __slots__ = ("jr", "_prev")
+
+    def __init__(self, jr):
+        self.jr = jr
+
+    def __enter__(self):
+        self._prev = getattr(_TLS, "sending", None)
+        _TLS.sending = self.jr
+        return self
+
+    def __exit__(self, *exc):
+        _TLS.sending = self._prev
+        return False
+
+
+def sending(batch):
+    """Held by ``InputHandler.send_columns`` while it advances the playback
+    clock for ``batch``: the TIMER steps the scheduler fires meanwhile (a
+    tumbling window's flush) are that send's work, and
+    ``QueryRuntime.process_timer`` gives their chunk's stamp and their
+    ``siddhi.timer`` span its batch id. The shared no-op when journeys are
+    off."""
+    jr = getattr(batch, "journey", None) if _ENABLED else None
+    return tracing.NOOP if jr is None else _Sending(jr)
+
+
+def sending_batch() -> Optional[int]:
+    """The batch id of the send advancing the clock on this thread."""
+    jr = getattr(_TLS, "sending", None)
+    return jr.batch if jr is not None else None
 
 
 def pack_span():

@@ -712,6 +712,23 @@ class LengthBatchWindowStage(WindowStage):
 
 # --------------------------------------------------------------- timeBatch
 
+def time_batch_boundary(time_ms: int, start_time: int, next_emit0, now):
+    """(the next boundary, whether this step flushes) of a ``timeBatch``
+    window: the boundary is set on the first chunk
+    (TimeBatchWindowProcessor:266-276) and moves one window on when a
+    step's clock has reached it. Shared by the buffered stage below and
+    the folded one (``ops/tumbling_agg.py``)."""
+    t = jnp.int64(time_ms)
+    if start_time >= 0:
+        st = jnp.int64(start_time)
+        init_emit = now + (t - ((now - st) % t))
+    else:
+        init_emit = now + t
+    next_emit = jnp.where(next_emit0 < 0, init_emit, next_emit0)
+    send = now >= next_emit
+    return jnp.where(send, next_emit + t, next_emit), send
+
+
 class TimeBatchWindowStage(WindowStage):
     """Tumbling time window; flush check once per chunk (arriving rows join
     the flushing batch), exactly as the reference processes chunks.
@@ -743,21 +760,12 @@ class TimeBatchWindowStage(WindowStage):
 
     def apply(self, state, cols, ctx):
         Wc = self.capacity
-        t = jnp.int64(self.time_ms)
         keys = _data_keys(cols)
         now = jnp.int64(ctx["current_time"])
         valid_cur = cols[VALID_KEY] & (cols[TYPE_KEY] == CURRENT)
 
-        # boundary init on first chunk (TimeBatchWindowProcessor:266-276)
-        next_emit0 = state["next_emit"]
-        if self.start_time >= 0:
-            st = jnp.int64(self.start_time)
-            init_emit = now + (t - ((now - st) % t))
-        else:
-            init_emit = now + t
-        next_emit = jnp.where(next_emit0 < 0, init_emit, next_emit0)
-        send = now >= next_emit
-        next_emit = jnp.where(send, next_emit + t, next_emit)
+        next_emit, send = time_batch_boundary(
+            self.time_ms, self.start_time, state["next_emit"], now)
 
         count0 = state["count"]
         rank, n_ins = _insert_ranks(valid_cur)

@@ -24,7 +24,8 @@ RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "device_service_ms", "device_queue_ms", "emit_ms", "t",
              "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded",
              "route_prep_ms", "route_pieces", "shard_rows_max",
-             "shard_capacity"}
+             "shard_capacity", "flush_rows", "timer_steps"}
+FLUSH_KEYS = ("flush_rows", "timer_steps")
 ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
               "shard_capacity")
 
@@ -205,6 +206,8 @@ def test_an_nfa_app_leaves_journeys_with_every_ring_key():
         assert rec["queries"] == ["nfa"] and rec["pack_ms"] > 0
         # the counters of a device-routed query: None for every other
         assert [rec[k] for k in ROUTE_KEYS] == [None] * 4
+        # and those of a folded tumbling window
+        assert [rec[k] for k in FLUSH_KEYS] == [None] * 2
         assert rec["dispatch_ms"] > 0 and rec["meta_pull_ms"] > 0
     assert len({rec["batch"] for rec in ring}) == 4
     heads, tails = ring[0::2], ring[1::2]
@@ -212,6 +215,80 @@ def test_an_nfa_app_leaves_journeys_with_every_ring_key():
     assert all(r["rows_out"] == 0 and r["pull_ms"] is None for r in heads)
     assert all(r["rows_out"] == 6 and r["pull_ms"] > 0 for r in tails)
     assert sum(len(p["v2"]) > 0 for p in out.pulled) >= 2
+
+
+TUMBLING = """
+@app:playback
+define stream S (k string, v float);
+@info(name='bars')
+from S#window.timeBatch(1 sec)
+select k, count() as n, min(v) as lo, max(v) as hi group by k
+insert into O;
+"""
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_flush_is_a_timer_step_of_the_send_that_crossed(tmp_path, depth):
+    """A folded tumbling window under playback: the send whose timestamp
+    crosses the boundary fires the scheduler's timer while it advances the
+    clock, so ``siddhi.timer`` (with the flush's step, meta pull, emit and
+    pull inside it) carries THAT send's batch id, and so do the journey's
+    ``timer_steps`` and ``flush_rows``."""
+    m = _manager(pipeline_depth=depth)
+    rt = m.create_siddhi_app_runtime(TUMBLING)
+    out = Columns()
+    rt.add_callback("O", out)
+    h = rt.get_input_handler("S")
+
+    def send(t):
+        h.send_columns({"k": np.array(["a", "b", "a"], object),
+                        "v": np.array([1.0, 2.0, 3.0], np.float32)},
+                       timestamps=np.full(3, t, np.int64))
+
+    send(1_000)
+    send(2_000)                     # compiles the TIMER step too
+    rt.start_trace(str(tmp_path))
+    for t in (2_400, 2_800, 3_000, 3_500):
+        send(t)
+    ring = journey.ring()
+    rt.stop_trace()
+    counters = rt.app_context.telemetry.snapshot()["counters"]
+    m.shutdown()
+
+    (spans,) = _engine_spans(tmp_path).values()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp[2], []).append(sp)
+    (timer,) = by_name["siddhi.timer"]        # one window closed: at 3,000
+    # the sends' packs (the TIMER chunk is packed too, inside its span)
+    ids = [sp[3]["batch"] for sp in by_name["siddhi.pack"]
+           if not _inside(sp, timer)]
+    assert len(ids) == 4
+    assert timer[3]["batch"] == ids[2] and timer[3]["query"] == "bars"
+    assert timer[3]["ts"] == 3_000
+    stages = {"siddhi.query.step", "siddhi.meta_pull", "siddhi.emit",
+              "siddhi.pull"}
+    inside = [sp for sp in spans if sp[2] in stages and _inside(sp, timer)]
+    assert {sp[2] for sp in inside} == stages
+    assert {sp[3]["batch"] for sp in inside} == {ids[2]}
+    # the timer fired before that send's own step
+    own = [sp for sp in by_name["siddhi.query.step"]
+           if sp[3]["batch"] == ids[2] and not _inside(sp, timer)]
+    assert len(own) == 1 and timer[1] <= own[0][0]
+
+    assert all(set(rec) == RING_KEYS for rec in ring)
+    assert len(ring) == 5           # four data steps and the TIMER step
+    (flush,) = [rec for rec in ring if rec["flush_rows"]]
+    assert flush["timer_steps"] == 1 and flush["batch"] == ids[2]
+    assert flush["flush_rows"] == flush["rows_out"] == 2      # a and b
+    assert [rec["timer_steps"] for rec in ring if rec is not flush] \
+        == [None] * 4
+    assert sorted(rec["batch"] for rec in ring if rec is not flush) \
+        == sorted(ids)
+    assert counters["window.bars.flushes"] == 2     # at 2,000 and 3,000
+    assert counters["window.bars.timer_steps"] == 2
+    (answer,) = [p for p in out.pulled[1:] if p["__valid__"].any()]
+    assert answer["n"][answer["__valid__"]].tolist() == [3, 6]   # b, a
 
 
 def test_pull_counters_equal_what_the_arrays_say():

@@ -205,6 +205,63 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch):
                 f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
 
 
+_TUMBLING = """
+@app:playback
+define stream StockStream (symbol string, price float, volume long);
+@info(name = 'bench')
+from StockStream#window.timeBatch(1 sec)
+select symbol, count() as n, min(price) as lo, max(price) as hi
+group by symbol
+insert into OutStream;
+"""
+
+
+def _tumbling_steps(keys, batch):
+    """(data step, TIMER step) of ``BASELINE.json`` configs[2] under the
+    engine's defaults: one warm batch that holds every key, one more a
+    window later, whose clock advance fires the flush's TIMER chunk."""
+    manager = SiddhiManager()
+    rt = manager.create_siddhi_app_runtime(_TUMBLING)
+    seen = _spy_steps(rt.query_runtimes["bench"])
+    symbols = np.array([f"S{i}" for i in range(keys)], dtype=object)
+    for ts in (10_000, 11_000):
+        rt.get_input_handler("StockStream").send_columns(
+            {"symbol": symbols[np.arange(batch) % keys],
+             "price": np.ones(batch, np.float32),
+             "volume": np.ones(batch, np.int64)},
+            timestamps=np.full(batch, ts, np.int64))
+    manager.shutdown()
+    data, timer, _data = seen
+    return data, timer
+
+
+# (e) BASELINE.json configs[2], the tumbling window folded into keys-wide
+# accumulators (ops/tumbling_agg.py): the data step and the one-row TIMER
+# step that flushes. State and output are [K] whatever the batch; every
+# batch-wide scatter is ONE 32-bit operand (the chip's sorted path).
+@pytest.mark.parametrize("keys,batch", [
+    (1_000, 2_048),
+    pytest.param(10_000, 65_536, marks=pytest.mark.slow),
+])
+def test_tumbling_steps_compile(one_chip, keys, batch):
+    for name, (step, avals) in zip(("data", "timer"),
+                                   _tumbling_steps(keys, batch)):
+        compiled, seconds = _compile_for(one_chip, step, avals)
+        m = _report(f"E timeBatch(1 sec) count/min/max {name} step "
+                    f"B={batch} keys={keys}", compiled, seconds)
+        widest = max(a.shape[0] for a in
+                     jax.tree_util.tree_leaves(avals[0]["win"]) if a.shape)
+        assert keys <= widest < 2 * keys + 16     # as wide as the keys
+        assert m.output_size_in_bytes < 64 * widest * 8
+        scatters = _scatters(compiled.as_text())
+        if name == "data":
+            assert scatters
+        for scope, primitive, result in scatters:
+            assert not result.startswith("("), (
+                f"a 64-bit scatter in the tumbling step: "
+                f"{scope}/{primitive} -> {result}")
+
+
 # (b) phase C: the two-step NFA at K = 16,384 partition-key slots.
 @pytest.mark.parametrize("keys,batch", [
     (10_000, 1_024),
