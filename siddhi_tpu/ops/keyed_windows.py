@@ -15,6 +15,18 @@ Semantics match the unkeyed stages in ``ops/windows.py`` applied per key:
   the batch; TIMER chunks drain all keys (``TimeWindowProcessor``).
 
 The partition key id column is ``PK_KEY`` (host-computed, dense ids).
+
+A 64-bit integer is two 32-bit planes on the TPU, and a scatter of both
+planes at once (one two-operand scatter) misses the compiler's sorted
+path: 146 ns an update into a ``[16,384,000]`` ring against 5-8 for a
+32-bit column (PERF.md section 5). So the keyed length window's ring
+write, and the routed exchange's buckets in ``parallel/mesh.py``, scatter
+an int64 column as its two words (``int64_words`` / ``int64_from_words``).
+The ring keeps its int64 layout: taking its high plane and re-forming the
+int64 are two elementwise passes over the ring, 0.45 ms a column there
+against the 9.6 ms the two-plane scatter took. A ``double`` has no bits
+to take on that chip (a pair of float32 there, and the compiler refuses
+to bitcast it), so a ``double`` column stays ONE two-plane write.
 """
 
 from __future__ import annotations
@@ -41,6 +53,29 @@ from siddhi_tpu.ops.windows import (
     _row_order_base,
 )
 
+
+
+def int64_words(v):
+    """An int64 array as its ``(low, high)`` uint32 words, bit for bit.
+    Unsigned, so that the TPU compiler takes the low plane as it is (a
+    convert to int32 is one more pass over a ring)."""
+    return v.astype(jnp.uint32), (v >> 32).astype(jnp.uint32)
+
+
+def int64_from_words(low, high):
+    """``int64_words``' inverse."""
+    return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
+
+
+def _ring_write(ring, slot, col):
+    """``ring.at[slot].set(col, mode="drop")``; an int64 ring is written
+    word by word, by two one-operand 32-bit scatters at the same slots."""
+    if ring.dtype != jnp.int64:
+        return ring.at[slot].set(col, mode="drop")
+    low, high = int64_words(ring)
+    col_low, col_high = int64_words(col.astype(jnp.int64))
+    return int64_from_words(low.at[slot].set(col_low, mode="drop"),
+                            high.at[slot].set(col_high, mode="drop"))
 
 
 def _per_key_layout(pk, valid_cur, num_keys: int):
@@ -129,7 +164,7 @@ class KeyedLengthWindowStage(WindowStage):
         # write the last min(W, n_key) arrivals of each key (unique slots)
         write = valid_cur & (occ >= counts[pk] - W)
         slot = jnp.where(write, pk * W + seq % W, jnp.int64(K * W)).astype(jnp.int64)
-        new_buf = {k: state["buf"][k].at[slot].set(cols[k], mode="drop") for k in state["buf"]}
+        new_buf = {k: _ring_write(state["buf"][k], slot, cols[k]) for k in state["buf"]}
 
         # order base: original batch position (global under device routing,
         # so a shard's 2*i/2*i+1 keys interleave correctly with its peers')

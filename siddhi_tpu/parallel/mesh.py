@@ -864,6 +864,7 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
     from siddhi_tpu.core.plan.selector_plan import GK_KEY
     from siddhi_tpu.ops.expressions import (
         OKEY_KEY, PK_KEY, RIDX_KEY, VALID_KEY)
+    from siddhi_tpu.ops.keyed_windows import int64_from_words, int64_words
 
     layout = runtime._route_layout
     n, Q = layout.n, layout.quota
@@ -951,6 +952,12 @@ def routed_step_for(runtime, side_key: Optional[str] = None):
                                  jnp.int64(n * Q))
 
             def exch(col):
+                # an int64 goes as its two 32-bit words, each through its
+                # own bucket scatter and all_to_all: one two-plane scatter
+                # gets no sorted path on the TPU (ops/keyed_windows.py).
+                # A double cannot be split there and goes as it is
+                if col.dtype == jnp.int64:
+                    return int64_from_words(*map(exch, int64_words(col)))
                 buf = jnp.zeros((n * Q,) + col.shape[1:], col.dtype)
                 buf = buf.at[slot_row].set(col, mode="drop")
                 return jax.lax.all_to_all(

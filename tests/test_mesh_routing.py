@@ -251,12 +251,12 @@ _HLO_OP = re.compile(
 _HLO_SIG = re.compile(r': \(([^()]*)\) -> (.*?) loc\((#loc\d+)\)\s*$')
 
 
-def _merge_scope_ops(text):
+def _scope_ops(text, scope):
     """``(op, n_operands, operand types, result types)`` of every
     all_gather, gather, scatter and sort of a lowered program (StableHLO
-    with debug info) that was traced under ``siddhi.merge``. An operation
-    with a region (a scatter, a sort) carries its types on the line that
-    closes it."""
+    with debug info) that was traced under ``scope``. An operation with a
+    region (a scatter, a sort) carries its types on the line that closes
+    it."""
     names = {m[1]: m[2] for m in map(_LOC_DEF.match, text.splitlines()) if m}
     found, open_ops = [], []
     for line in text.splitlines():
@@ -269,7 +269,7 @@ def _merge_scope_ops(text):
             open_ops.append(head)
             continue
         sig = _HLO_SIG.search(line)
-        if head and sig and "siddhi.merge/" in names.get(sig[3], ""):
+        if head and sig and f"{scope}/" in names.get(sig[3], ""):
             found.append((head[0], head[1], sig[1], sig[2]))
     assert not open_ops
     return found
@@ -302,7 +302,7 @@ def test_egress_moves_own_rows_only(app, stream, row):
     rows = emitted[0].shape[0]                            # n * L
     doubles = sum(a.dtype == np.float64 for a in emitted)
     text = lowered.as_text(debug_info=True)
-    ops = _merge_scope_ops(text)
+    ops = _scope_ops(text, "siddhi.merge")
     wide = f"tensor<{rows}x"
     gathered = sorted(o[3] for o in ops
                       if o[0] == "all_gather" and wide in o[3])
@@ -322,6 +322,91 @@ def test_egress_moves_own_rows_only(app, stream, row):
     # ONE sort: the order keys, the slots' numbers, the doubles
     sorts = [o for o in ops if o[0] == "sort"]
     assert [o[1] for o in sorts] == [2 + doubles], sorts
+
+
+_WORDS_APP = """
+    @app:playback
+    define stream S (k string, d double, big long, v long);
+    partition with (k of S)
+    begin
+      @info(name = 'q')
+      from S#window.length(3)
+      select k, d, big, v, sum(v) as s
+      insert all events into Out;
+    end;
+"""
+_I64 = np.iinfo(np.int64)
+# the sign, a low word with its top bit set, both words all ones
+_NASTY = np.array([
+    0, -1, _I64.min, _I64.max, 0x80000000, 0xFFFFFFFF, -0x80000000,
+    0x100000000, 0x7FFFFFFF80000000, -0x00000001FFFFFFFF, 2 ** 53 + 1,
+], dtype=np.int64)
+_ODD = np.array([-0.0, np.nan, 0.0, 1.0 / 3.0, -1e300, 5e-324])
+
+
+def _feed_words(rt, hot):
+    """Fifteen 16-row batches whose ``long`` columns and timestamps
+    (epoch milliseconds, above 2^40) need both of their words; ``hot``
+    of every five rows land on ONE key."""
+    h = rt.get_input_handler("S")
+    for lo in range(0, 240, 16):
+        i = np.arange(lo, lo + 16)
+        h.send_columns(
+            {"k": np.array([f"P{0 if j % 5 < hot else j % 16}" for j in i],
+                           dtype=object),
+             "d": _ODD[i % len(_ODD)], "big": _NASTY[i % len(_NASTY)],
+             "v": _NASTY[(3 * i + 1) % len(_NASTY)] >> 8},
+            timestamps=1_791_000_000_000 + i)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+@pytest.mark.parametrize("rows_per_shard,hot", [(256, 0), (8, 4)],
+                         ids=["within_quota", "over_quota_splits"])
+def test_int64_columns_cross_the_exchange_as_words(n_dev, rows_per_shard, hot):
+    """The exchange buckets a 64-bit integer column as its two 32-bit
+    words (``mesh.py`` ``exch``): every ``long``, the timestamps and the
+    row index arrive bit for bit, the ``double`` beside them (which is
+    not split) too, also when a shard's quota forces the batch apart."""
+    m1, rt1, c1 = _build(_WORDS_APP)
+    _feed_words(rt1, hot)
+    m1.shutdown()
+    m2, rt2, c2 = _build(_WORDS_APP)
+    q = rt2.query_runtimes["q"]
+    device_route_query_step(q, make_mesh(n_dev), rows_per_shard=rows_per_shard)
+    seen = _spy_step_calls(q)
+    _feed_words(rt2, hot)
+    m2.shutdown()
+    assert (len(seen) > 15) == bool(hot)       # split, or one step a batch
+    assert len(c1.rows) > 240                  # current and expired rows
+    assert {r[2] for r in c1.rows} == set(_NASTY.tolist())
+    assert [_bits(r) for r in c2.rows] == [_bits(r) for r in c1.rows]
+
+
+def test_ingress_buckets_are_32_bit_scatters():
+    """The routed program as lowered: under ``siddhi.route`` no bucket
+    scatter has a 64-bit integer operand (two planes on the chip, no
+    sorted path: PERF.md section 5); a ``long`` column, the timestamps
+    and the row index go as two ``ui32`` words each. The exception,
+    written down: a ``double`` cannot be split there and is ONE
+    ``f64`` scatter."""
+    m, rt, _c = _build(_WORDS_APP)
+    q = rt.query_runtimes["q"]
+    device_route_query_step(q, make_mesh(4), rows_per_shard=64)
+    seen = _spy_step_calls(q)
+    _feed_words(rt, 0)
+    step, (state, cols, now) = seen[-1]
+    text = step._routed_raw.lower(
+        state, cols, q._route_layout.device_luts(), now).as_text(
+            debug_info=True)
+    m.shutdown()
+    buckets = [o[3] for o in _scope_ops(text, "siddhi.route")
+               if o[0] == "scatter"]
+    int64s = [k for k, v in cols.items() if v.dtype == np.int64]
+    assert sorted(int64s) == ["__ts__", "big", "v"]
+    assert buckets.count("tensor<64xui32>") == 2 * (len(int64s) + 1)
+    assert buckets.count("tensor<64xf64>") == 1
+    assert not [b for b in buckets if "i64" in b], buckets
+    assert len(buckets) == len(cols) + 1 + len(int64s) + 1, buckets
 
 
 def test_oversized_batches_split_not_die():

@@ -26,7 +26,7 @@ from siddhi_tpu.ops.expressions import PADDED_KEY
 
 _STOCK = """
 @app:precision('{precision}')
-define stream StockStream (symbol string, price float, volume long);
+define stream StockStream (symbol string, price {price}, volume long);
 {head}
   @info(name = 'bench')
   from StockStream#window.length({W})
@@ -137,12 +137,21 @@ def _scatters(hlo_text):
             for m in map(_SCATTER.search, hlo_text.splitlines()) if m]
 
 
-def _stock_step(precision, partitioned, window, keys, batch):
+def _scatter_ops(hlo_text):
+    """``_scatters`` of the scatter instructions alone: one entry for
+    every scatter the chip runs (``_scatters`` also sees the fusion that
+    holds it, and fusions that only prepare its indices, under the same
+    ``op_name``)."""
+    return _scatters("\n".join(
+        line for line in hlo_text.splitlines() if " scatter(" in line))
+
+
+def _stock_step(precision, partitioned, window, keys, batch, price="float"):
     """The last step dispatch of one warm batch that covers every key at
     the measured batch shape — what chip_smoke.py compiles in warm-up."""
     manager = SiddhiManager()
     rt = manager.create_siddhi_app_runtime(_STOCK.format(
-        precision=precision, W=window,
+        precision=precision, W=window, price=price,
         head="partition with (symbol of StockStream)\nbegin"
         if partitioned else "",
         group="" if partitioned else "group by symbol",
@@ -175,15 +184,16 @@ def test_global_window_step_compiles(one_chip, precision, keys, batch):
 
 # (c) phase B: per-key rings [K * 1000]; the tier-1 case is the size that
 # fits a test's time, the deployment size is `slow`.
-@pytest.mark.parametrize("keys,batch", [
-    (100, 1_024),
-    pytest.param(10_000, 65_536, marks=pytest.mark.slow),
+@pytest.mark.parametrize("keys,batch,price", [
+    (100, 1_024, "float"),
+    (100, 1_024, "double"),
+    pytest.param(10_000, 65_536, "float", marks=pytest.mark.slow),
 ])
-def test_keyed_ring_step_compiles(one_chip, keys, batch):
-    step, avals = _stock_step("fast", True, 1_000, keys, batch)
+def test_keyed_ring_step_compiles(one_chip, keys, batch, price):
+    step, avals = _stock_step("fast", True, 1_000, keys, batch, price)
     compiled, seconds = _compile_for(one_chip, step, avals)
-    m = _report(f"B partitioned length(1000) B={batch} keys={keys}",
-                compiled, seconds)
+    m = _report(f"B partitioned length(1000) B={batch} keys={keys} "
+                f"price {price}", compiled, seconds)
     rows = max(a.shape[0] for a in jax.tree_util.tree_leaves(avals[0]["win"]))
     assert rows >= keys * 1_000          # the rings really are [K * W]
     assert m.argument_size_in_bytes > rows
@@ -203,6 +213,24 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch):
         elif primitive == "scatter-add":
             assert not result.startswith("("), (
                 f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
+    _assert_int64_rings_written_as_words(
+        compiled.as_text(), rows, doubles=int(price == "double"))
+
+
+def _assert_int64_rings_written_as_words(hlo_text, rows, doubles=0):
+    """The ring write of ``ops/keyed_windows.py``: each of the two int64
+    ring columns (``__ts__``, ``volume``) is two one-operand u32 scatters
+    into ``[rows]`` (146 ns an update as one two-plane scatter against
+    5-8: PERF.md section 5, PR 32). The exception, written down: a
+    ``double`` is a pair of float32 on the chip with no bits to take (no
+    bitcast-convert from f64 there), so each of the ``doubles`` ring
+    columns stays ONE two-plane write; nothing else under
+    ``siddhi.state`` writes two planes."""
+    ring = [result for scope, primitive, result in _scatter_ops(hlo_text)
+            if scope == "siddhi.state" and primitive == "scatter"]
+    assert [r for r in ring if r.startswith("(")] == [
+        f"(f32[{rows}], f32[{rows}])"] * doubles, ring
+    assert ring.count(f"u32[{rows}]") == 4, ring
 
 
 _TUMBLING = """
@@ -339,7 +367,7 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
 
     manager = SiddhiManager()
     rt = manager.create_siddhi_app_runtime(_STOCK.format(
-        precision="fast", W=window, group="", tail="end;",
+        precision="fast", W=window, price="float", group="", tail="end;",
         head="partition with (symbol of StockStream)\nbegin"))
     rt.start()
     q = rt.query_runtimes["bench"]
@@ -400,3 +428,14 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     assert len(merge_scatters) >= 8
     assert {result for _s, _p, result in merge_scatters} == {
         f"s32[{n_l}]"}, merge_scatters
+    # ingress: the exchange buckets the three int64 columns (``__ts__``,
+    # ``volume``, the row index) as two u32 words each, 66 ns an update
+    # as one two-plane scatter (PERF.md section 5); the shard's ring step
+    # writes its int64 columns the same way
+    buckets = [result for scope, _p, result in _scatter_ops(text)
+               if scope == "siddhi.route"]
+    assert not [r for r in buckets if r.startswith("(")], buckets
+    assert buckets.count(f"u32[{rows_per_shard}]") == 6, buckets
+    ring_rows = max(a.shape[0] for a in
+                    jax.tree_util.tree_leaves(state["win"])) // 4
+    _assert_int64_rings_written_as_words(text, ring_rows)
