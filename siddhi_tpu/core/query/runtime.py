@@ -193,6 +193,12 @@ class QueryRuntime(Receiver):
         self._instr_gauged: set = set()
         self._instr_spec = None     # cached instrument_slots() result
         self.on_error: Optional[Callable] = None
+        # what the dense state holds, on /metrics beside
+        # state.<query>.grows (note_growth); sampled from shapes, no pull
+        tel = getattr(app_context, "telemetry", None)
+        if tel is not None:
+            tel.gauge(f"state.{name}.key_capacity", self.key_capacity)
+            tel.gauge(f"state.{name}.bytes", self.state_bytes)
 
     # ---------------------------------------------------------------- state
 
@@ -220,6 +226,39 @@ class QueryRuntime(Receiver):
         if self.window_stage is not None:
             state["win"] = self.window_stage.init_state(self._win_keys)
         return state
+
+    def key_capacity(self) -> int:
+        """Keys the dense state has rows for (``state.<query>.key_capacity``):
+        the larger of the selector's and the window's key axes, over
+        every shard of a device-routed query."""
+        rl = self._route_layout
+        if rl is not None:
+            return rl.n * max(rl.localK, rl.local_win)
+        return max(self.selector_plan.num_keys, self._win_keys)
+
+    def state_bytes(self) -> int:
+        """Bytes of the query's state on the device
+        (``state.<query>.bytes``), from the leaves' shapes: no pull."""
+        from siddhi_tpu.core.util.statistics import pytree_nbytes
+
+        return pytree_nbytes(self._state)
+
+    def state_slots(self) -> Optional[int]:
+        """Ring slots of a keyed window's state, key capacity x the ring
+        each key owns; None where the query keeps no keyed ring."""
+        ring = getattr(self.window_stage, "ring_capacity", None)
+        if ring is None or not getattr(self.window_stage, "keyed", False):
+            return None
+        rl = self._route_layout
+        keys = rl.n * rl.local_win if rl is not None else self._win_keys
+        return keys * ring
+
+    def note_growth(self, ms: Optional[float]) -> None:
+        """One key-capacity growth happened, under a ``siddhi.grow`` span
+        of ``ms``: counted, and charged to the batch that forced it."""
+        self.app_context.telemetry.count(f"state.{self.name}.grows")
+        if self._cur_journey is not None:
+            self._cur_journey.grown(ms)
 
     def _needed_sel_keys(self) -> int:
         if self.keyer is not None:
@@ -255,24 +294,44 @@ class QueryRuntime(Receiver):
             # device-memory budget gate (resilience/overload.py): deny
             # the growth BEFORE allocating — dense state scales with the
             # grown key capacity, so project from the current footprint
-            from siddhi_tpu.core.util.statistics import pytree_nbytes
             from siddhi_tpu.resilience.overload import ensure_memory_budget
 
             ratio = max(new_k / max(k, 1), new_w / max(self._win_keys, 1))
             ensure_memory_budget(
                 self.app_context, f"query.{self.name}",
-                int(pytree_nbytes(self._state) * ratio),
+                int(self.state_bytes() * ratio),
                 what=f"query '{self.name}' key-capacity growth "
                      f"({k}->{new_k} keys)")
+        from_keys = self.key_capacity()
         self.selector_plan.num_keys = new_k
         self._win_keys = new_w
         self._sel_step = None
-        old_state = self._state
-        new_state = self._init_state()
-        if old_state is not None:
-            self._state = jax.tree_util.tree_map(_copy_prefix, new_state, old_state)
+        if self._state is None:
+            self._state = self._init_state()
         else:
-            self._state = new_state
+            grown = jax.tree_util.tree_leaves(
+                jax.eval_shape(self._init_state))
+            with span("grow", query=self.name, from_keys=from_keys,
+                      to_keys=self.key_capacity(),
+                      bytes_before=self.state_bytes(),
+                      bytes_after=sum(g.size * g.dtype.itemsize
+                                      for g in grown)) as sp:
+                # the runtime lets go of the old state before the first
+                # leaf moves: grow_state owns its only reference
+                old_leaves, treedef = jax.tree_util.tree_flatten(self._state)
+                self._state = None
+                try:
+                    self._state = jax.tree_util.tree_unflatten(
+                        treedef,
+                        grow_state(self._init_state, grown, old_leaves))
+                except Exception as e:
+                    # leaves that had moved are gone with their old
+                    # copies: nothing to fall back to
+                    raise FatalQueryError(
+                        f"query '{self.name}': key-capacity growth "
+                        f"({from_keys}->{self.key_capacity()} keys) failed "
+                        f"with its state half moved: {e}") from e
+            self.note_growth(sp.ms)
         self._step = None  # re-jit
         if self._shard_mesh is not None:
             # re-establish key-axis sharding on the grown state
@@ -280,11 +339,10 @@ class QueryRuntime(Receiver):
 
             shard_query_step(self, self._shard_mesh)
         if getattr(self.app_context, "overload", None) is not None:
-            from siddhi_tpu.core.util.statistics import pytree_nbytes
             from siddhi_tpu.resilience.overload import charge_memory
 
             charge_memory(self.app_context, f"query.{self.name}",
-                          pytree_nbytes(self._state))
+                          self.state_bytes())
 
     def reset_partition_keys(self, ids):
         """Zero the dense state rows of purged partition keys so their ids
@@ -951,6 +1009,8 @@ class QueryRuntime(Receiver):
 
             cols[STR_RANK] = self.dictionary.rank_table()
         self._state, out = step(self._state, cols, now)
+        if jr is not None:
+            jr.state_sized(self.state_bytes(), self.state_slots())
         # lazy pull: only columns a consumer actually reads cross the
         # device->host link; overflow/notify/size travel as ONE packed
         # array — a single device->host round trip per batch
@@ -1295,9 +1355,32 @@ def _pow2(needed: int, start: int = 16) -> int:
     return k
 
 
-def _copy_prefix(new, old):
-    """Copy old state into the (larger) new buffer along the key axis."""
-    if new.shape == old.shape:
-        return old
-    sl = tuple(slice(0, s) for s in old.shape)
-    return new.at[sl].set(old)
+def grow_leaf(init_state, index: int, old):
+    """Leaf ``index`` of ``init_state()`` with ``old`` laid over its
+    prefix along every axis (keyed buffers are laid out so that a prefix
+    copy keeps per-key alignment). One small program: the other leaves
+    of ``init_state()`` are dead code in it, and the rows beyond ``old``
+    are written straight into the result, so the device holds ``old``
+    and the grown leaf and no third buffer. Waits for the result: the
+    caller lets ``old`` go only when its successor exists."""
+    def build(old):
+        fresh = jax.tree_util.tree_leaves(init_state())[index]
+        return fresh.at[tuple(slice(0, s) for s in old.shape)].set(old)
+
+    return jax.block_until_ready(jax.jit(build)(old))
+
+
+def grow_state(init_state, grown: list, old_leaves: list) -> list:
+    """The leaves of the state ``init_state()`` describes (``grown``:
+    their shapes, at the grown capacity) with the old state's rows in
+    place, built LEAF BY LEAF so that the grown state is never held
+    twice: ``old_leaves`` (the caller's ONLY reference to the old state,
+    flattened) gives each leaf up as its successor is made. The peak is
+    the old state plus the new one less what has already moved. A leaf
+    whose shape did not change is passed through, the same buffer."""
+    new_leaves = []
+    for i, shape in enumerate(grown):
+        old, old_leaves[i] = old_leaves[i], None
+        new_leaves.append(old if shape.shape == old.shape
+                          else grow_leaf(init_state, i, old))
+    return new_leaves

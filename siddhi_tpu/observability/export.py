@@ -76,6 +76,10 @@ TELEMETRY_PREFIXES = (
     "window",        # a scheduler-driven window's TIMER steps and a
                      # folded tumbling window's flushes, per query
                      # (core/query/runtime.py; generic counter family)
+    "state",         # a query's dense state: key capacity and bytes
+                     # gauges, key-capacity growths counter
+                     # (core/query/runtime.py, parallel/mesh.py; generic
+                     # gauge and counter families)
     "autopilot",     # closed-loop controller: mode gauge, tick/freeze
                      # counters, per-(knob,direction,reason) decision
                      # counters (siddhi_tpu/autopilot/ ->
@@ -124,6 +128,8 @@ PROCESS_LIFETIME_GAUGES = (
                             # cost record outlives any single app
     "device.*",             # app registry — device-instrument last-value
                             # and capacity gauges die with the app
+    "state.*",              # app registry — a query's state gauges
+                            # die with the app
 )
 # ---------------------------------------------------------------------
 

@@ -77,6 +77,12 @@ send that crosses the boundary advances the clock, before that send's own
 step: the TIMER step's journey and span carry that send's ``batch`` id,
 so the fields add up per send.
 
+Every query stamps three more: ``grow_ms``, the ``siddhi.grow`` spans
+of the key-capacity growths this batch forced (``None``: none), and
+``state_bytes`` / ``state_slots``, the state its step left on the device
+(bytes from the leaves' shapes; the ring slots of a keyed window, key
+capacity x ring, ``None`` where there is no keyed ring).
+
 Cost model: near-zero when off — every instrumented site checks one
 module flag and does nothing else. When on, a batch carries one small
 ``Journey`` object (a handful of floats); finished journeys land in
@@ -251,7 +257,8 @@ class Journey:
                  "_t_disp1", "_t_drain0", "ready", "meta_pull_ms",
                  "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded",
                  "route_prep_ms", "route_pieces", "shard_rows_max",
-                 "shard_capacity", "flush_rows", "timer_steps")
+                 "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
+                 "state_bytes", "state_slots")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -275,6 +282,9 @@ class Journey:
         self.shard_capacity: Optional[float] = None
         self.flush_rows: Optional[int] = None
         self.timer_steps: Optional[int] = None
+        self.grow_ms: Optional[float] = None
+        self.state_bytes: Optional[int] = None
+        self.state_slots: Optional[int] = None
 
     # one journey object is stamped on the batch at pack time; each
     # receiving query forks its own (stage times are per query)
@@ -324,6 +334,17 @@ class Journey:
         """This step closed a tumbling window (``ops/tumbling_agg.py``)
         and delivers ``rows`` groups."""
         self.flush_rows = rows
+
+    def grown(self, ms: Optional[float]) -> None:
+        """This batch forced a key-capacity growth: the ``siddhi.grow``
+        span's duration (a batch can force several: summed)."""
+        self.grow_ms = (self.grow_ms or 0.0) + float(ms or 0.0)
+
+    def state_sized(self, nbytes: int, slots: Optional[int]) -> None:
+        """The query's state on the device as this batch's step left it:
+        its bytes, and the ring slots of a keyed window's."""
+        self.state_bytes = nbytes
+        self.state_slots = slots
 
     def emitting(self, app_context, names, rows_out: Optional[int] = None):
         """The emit stage as a context manager: ``siddhi.emit`` span,
@@ -406,6 +427,13 @@ class Journey:
                 # advance fired it); None for every other
                 "flush_rows": self.flush_rows,
                 "timer_steps": self.timer_steps,
+                # the ms this batch spent growing key capacity (the
+                # ``siddhi.grow`` spans; None: it forced no growth), and
+                # the state its step left on the device: bytes, and the
+                # ring slots of a keyed window (None: no keyed ring)
+                "grow_ms": self.grow_ms,
+                "state_bytes": self.state_bytes,
+                "state_slots": self.state_slots,
             })
 
 

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -67,15 +68,26 @@ def int64_from_words(low, high):
     return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
 
 
+# the operations of a ring write that read or write a WHOLE ring column
+# without being the scatter (an int64 ring's split into words and its
+# re-join): their time grows with key capacity x window, not with the
+# batch. It nests in ``siddhi.state``; the benchmark's
+# ``step_ring_pass_ms`` reads it (``benchmarks/metrics/_ring_pass.py``).
+RING_PASS_SCOPE = "siddhi.ring_pass"
+
+
 def _ring_write(ring, slot, col):
     """``ring.at[slot].set(col, mode="drop")``; an int64 ring is written
     word by word, by two one-operand 32-bit scatters at the same slots."""
     if ring.dtype != jnp.int64:
         return ring.at[slot].set(col, mode="drop")
-    low, high = int64_words(ring)
+    with jax.named_scope(RING_PASS_SCOPE):
+        low, high = int64_words(ring)
     col_low, col_high = int64_words(col.astype(jnp.int64))
-    return int64_from_words(low.at[slot].set(col_low, mode="drop"),
-                            high.at[slot].set(col_high, mode="drop"))
+    low = low.at[slot].set(col_low, mode="drop")
+    high = high.at[slot].set(col_high, mode="drop")
+    with jax.named_scope(RING_PASS_SCOPE):
+        return int64_from_words(low, high)
 
 
 def _per_key_layout(pk, valid_cur, num_keys: int):

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from siddhi_tpu.observability.instruments import (
     MERGE_SCOPE, ROUTE_SCOPE, named_step)
+from siddhi_tpu.observability.tracing import span
 
 KEY_AXIS = "keys"
 
@@ -1166,7 +1167,17 @@ def ensure_routed_capacity(runtime) -> None:
             int(pytree_nbytes(canonical) * ratio),
             what=f"query '{runtime.name}' routed key-capacity growth "
                  f"({n * layout.localK}->{Kg} global keys)")
-    _install_routed(runtime, layout, canonical, Kg, Wg)
+    if canonical is None:
+        _install_routed(runtime, layout, canonical, Kg, Wg)
+    else:
+        # the plain path's span and counter (_ensure_capacity); the
+        # re-layout goes through the host, so the device holds the old
+        # state and the new one, and ``bytes_after`` is not known before
+        with span("grow", query=runtime.name,
+                  from_keys=runtime.key_capacity(), to_keys=max(Kg, Wg),
+                  bytes_before=runtime.state_bytes()) as sp:
+            _install_routed(runtime, layout, canonical, Kg, Wg)
+        runtime.note_growth(sp.ms)
     if overloaded:
         from siddhi_tpu.core.util.statistics import pytree_nbytes
         from siddhi_tpu.resilience.overload import charge_memory

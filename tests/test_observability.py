@@ -122,6 +122,7 @@ def test_span_ring_buffer_bound_and_disabled_noop():
     assert sorted(kept) == list(range(84, 100))
     # disabled: the global helper returns the shared no-op
     assert not TRACER.enabled
+    TRACER.clear()      # whatever an earlier file of this worker left
     cm = span("ignored", x=1)
     with cm:
         pass

@@ -24,7 +24,8 @@ RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "device_service_ms", "device_queue_ms", "emit_ms", "t",
              "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded",
              "route_prep_ms", "route_pieces", "shard_rows_max",
-             "shard_capacity", "flush_rows", "timer_steps"}
+             "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
+             "state_bytes", "state_slots"}
 FLUSH_KEYS = ("flush_rows", "timer_steps")
 ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
               "shard_capacity")
@@ -289,6 +290,65 @@ def test_a_flush_is_a_timer_step_of_the_send_that_crossed(tmp_path, depth):
     assert counters["window.bars.timer_steps"] == 2
     (answer,) = [p for p in out.pulled[1:] if p["__valid__"].any()]
     assert answer["n"][answer["__valid__"]].tolist() == [3, 6]   # b, a
+
+
+def test_a_growth_is_one_grow_span_with_what_it_moved(tmp_path):
+    """Key capacity outgrown mid-stream: ``siddhi.grow`` once a growth,
+    inside the step of the batch that forced it, with the capacities and
+    the state's bytes on both sides; the same on the journey
+    (``grow_ms``, ``state_bytes``, ``state_slots``) and on /metrics
+    (``state.<query>.grows`` / ``.key_capacity`` / ``.bytes``)."""
+    from siddhi_tpu.observability.export import prometheus_text
+
+    m = _manager(pipeline_depth=2)
+    rt = m.create_siddhi_app_runtime(PARTITIONED)
+    rt.add_callback("O", Columns())
+    h = rt.get_input_handler("S")
+
+    def send(keys):
+        h.send_columns({"k": np.array([f"k{i}" for i in range(keys)], object),
+                        "v": np.arange(keys)})
+
+    send(10)                        # the least capacity, 16, holds these
+    q = rt.query_runtimes["pq"]
+    bytes_16 = q.state_bytes()
+    rt.start_trace(str(tmp_path))
+    send(10)
+    send(40)                        # 16 -> 64
+    send(40)
+    send(200)                       # 64 -> 256
+    ring = journey.ring()
+    rt.stop_trace()
+    snap = rt.app_context.telemetry.snapshot()
+    text = prometheus_text(m)
+    bytes_256 = q.state_bytes()
+    m.shutdown()
+
+    (spans,) = _engine_spans(tmp_path).values()
+    grows = [sp for sp in spans if sp[2] == "siddhi.grow"]
+    assert [(g[3]["from_keys"], g[3]["to_keys"]) for g in grows] \
+        == [(16, 64), (64, 256)]
+    assert {g[3]["query"] for g in grows} == {"pq"}
+    assert grows[0][3]["bytes_before"] == bytes_16
+    assert grows[0][3]["bytes_after"] == grows[1][3]["bytes_before"]
+    assert grows[1][3]["bytes_after"] == bytes_256 == 16 * bytes_16
+    steps = [sp for sp in spans if sp[2] == "siddhi.query.step"]
+    for g in grows:
+        assert sum(_inside(g, st) for st in steps) == 1
+    assert all(set(rec) == RING_KEYS for rec in ring)
+    assert [rec["grow_ms"] is not None for rec in ring] \
+        == [False, True, False, True]
+    for rec, g in zip([r for r in ring if r["grow_ms"]], grows):
+        assert rec["grow_ms"] == pytest.approx((g[1] - g[0]) / 1e6, rel=0.2)
+    # the state as each batch's step left it: 4 ring slots a key
+    assert [rec["state_slots"] for rec in ring] == [64, 256, 256, 1024]
+    assert [rec["state_bytes"] for rec in ring] \
+        == [bytes_16, 4 * bytes_16, 4 * bytes_16, bytes_256]
+    assert snap["counters"]["state.pq.grows"] == 2
+    assert snap["gauges"]["state.pq.key_capacity"] == 256
+    assert snap["gauges"]["state.pq.bytes"] == bytes_256
+    for name in ("grows", "key_capacity", "bytes"):
+        assert f'name="state.pq.{name}"' in text
 
 
 def test_pull_counters_equal_what_the_arrays_say():

@@ -22,7 +22,9 @@ from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
 
 from siddhi_tpu import SiddhiManager
+from siddhi_tpu.observability.instruments import STATE_SCOPE
 from siddhi_tpu.ops.expressions import PADDED_KEY
+from siddhi_tpu.ops.keyed_windows import RING_PASS_SCOPE
 
 _STOCK = """
 @app:precision('{precision}')
@@ -215,6 +217,7 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch, price):
                 f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
     _assert_int64_rings_written_as_words(
         compiled.as_text(), rows, doubles=int(price == "double"))
+    _assert_ring_passes_are_scoped(compiled.as_text(), rows)
 
 
 def _assert_int64_rings_written_as_words(hlo_text, rows, doubles=0):
@@ -231,6 +234,26 @@ def _assert_int64_rings_written_as_words(hlo_text, rows, doubles=0):
     assert [r for r in ring if r.startswith("(")] == [
         f"(f32[{rows}], f32[{rows}])"] * doubles, ring
     assert ring.count(f"u32[{rows}]") == 4, ring
+
+
+def _assert_ring_passes_are_scoped(hlo_text, rows):
+    """``siddhi.ring_pass`` (inside ``siddhi.state``) is on what takes an
+    int64 ring apart and puts it together again, whole-ring elementwise
+    passes (the benchmark's ``step_ring_pass_ms`` reads it), and on no
+    scatter: the two int64 rings' high-plane passes and re-joins are
+    there, over ``[rows]``, and every scatter keeps the plain
+    ``siddhi.state/scatter`` name that ``_scatters`` reads."""
+    scoped = [line for line in hlo_text.splitlines()
+              if RING_PASS_SCOPE in line]
+    assert all(f"{STATE_SCOPE}/{RING_PASS_SCOPE}/" in line for line in scoped)
+    primitives = {_OP_NAME.search(line)[1].rsplit("/", 1)[1]
+                  for line in scoped}
+    assert primitives == {"shift_right_arithmetic", "shift_left", "or"}
+    assert not [line for line in scoped if "scatter" in line]
+    passes = [line for line in scoped if " fusion(" in line]
+    assert len(passes) == 4, passes       # two columns: a pass, a re-join
+    assert all(f"u32[{rows}]" in line for line in passes)
+    assert len(_scatter_ops(hlo_text)) > 0
 
 
 _TUMBLING = """
@@ -439,3 +462,63 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     ring_rows = max(a.shape[0] for a in
                     jax.tree_util.tree_leaves(state["win"])) // 4
     _assert_int64_rings_written_as_words(text, ring_rows)
+
+
+# ------------------------------------------------------------------------
+# The step programs of the benchmark's cells, by what JAX's compile cache
+# keys them with: the StableHLO without debug info (scopes and locations
+# are metadata and not in it). A PR that means to leave a cell's program
+# alone (PR 33: a new scope, a new growth path) proves it here; a PR that
+# means to change one replaces that cell's digests with what this test
+# prints (PR 32 printed the first five: PERF.md section 6).
+_STEP_SHA256 = {
+    "partition_len1k_10k.hot20_bulk": [
+        "f3e0facb264ce3676ba4d52488614e9c4867162a4cbab20a36b71e5cbd901842"],
+    "groupby_len1k_10k.uniform_bulk": [
+        "7a62b53a00ec4f8fb6467bcbe998ae7b46666ea6f20ddf5ad627fcdc40ccafe5"],
+    "pattern_ab_10k.rounds_bulk": [
+        "58f6255a51ee3dbd38c347f8e70ad0e764a036bc46c0b5c2c85f840f77780b56",
+        "dd60d4459a94eed6d147e4df268c4c98930b9226b1567827d7921905062b4166"],
+    "partition_len1k_40k.hot20_bulk_x4": [
+        "8849587ba314b80ef8173ea4cbe34b26820ce801186f6a86189d01ca0613a102"],
+    "timebatch_1s_10k.hot20_tick250": [
+        "a055e7ea31b399f49b871ee11dec9001f9402c7dfb5695fb840daf5a3282ce43",
+        "a46ee91e0411de179849c770668c3f573c42a301e26b436dd40114fe56361f27"],
+}
+
+
+@pytest.mark.parametrize("cell", list(_STEP_SHA256))
+def test_a_cells_step_programs_are_the_ones_written_down(cell, monkeypatch):
+    """The cell's rehearsal (its configuration's and traffic's
+    ``rehearsal`` sizes, seed 11, the warm batch, the fill and four
+    batches more, as ``benchmarks/run.py`` drives them): every distinct
+    program its steps were called with, lowered again from the jitted
+    callable and the arguments' shapes."""
+    import hashlib
+
+    from benchmarks import drive, generator, manifest
+    from siddhi_tpu.observability.telemetry import InstrumentedJit
+
+    seen = {}
+    call = InstrumentedJit.__call__
+
+    def spy(self, *args):
+        avals = _avals(args)
+        seen[(self._key, str(avals))] = (self._fn, avals)
+        return call(self, *args)
+
+    monkeypatch.setattr(InstrumentedJit, "__call__", spy)
+    found = manifest.Cell(cell)
+    sizes, traffic = found.sized(True)
+    feed = generator.make_feed(found.config, sizes, traffic, 11)
+    manager, rt, _collector = drive.build_app(found.config, sizes,
+                                              found.chips)
+    sender = drive.Sender(rt, feed)
+    for i in range(len(feed.warm) + feed.fill_batches + 4):
+        sender.send(i)
+    manager.shutdown()
+    digests = sorted(
+        hashlib.sha256(fn.lower(*avals).as_text().encode()).hexdigest()
+        for fn, avals in seen.values())
+    print(f"\n[step-sha256] {cell}: {digests}")
+    assert digests == _STEP_SHA256[cell]
