@@ -33,7 +33,10 @@ log = logging.getLogger(__name__)
 # v4: GroupKeyer key tuples gained null-mask elements (general path) and
 # the single-string LUT moved to shifted dict ids — older keyer_map
 # snapshots would silently orphan their aggregate rows
-FORMAT_VERSION = 4
+# v5: the keyed length window holds an int64 ring column as two uint32
+# word leaves (low, high) under buf[name]; a v4 state has one int64 leaf
+# there and would not fit the step
+FORMAT_VERSION = 5
 
 
 # one jitted identity per replicated sharding: jax.jit caches by wrapped

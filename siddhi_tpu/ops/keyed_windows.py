@@ -19,21 +19,27 @@ The partition key id column is ``PK_KEY`` (host-computed, dense ids).
 A 64-bit integer is two 32-bit planes on the TPU, and a scatter of both
 planes at once (one two-operand scatter) misses the compiler's sorted
 path: 146 ns an update into a ``[16,384,000]`` ring against 5-8 for a
-32-bit column (PERF.md section 5). So the keyed length window's ring
-write, and the routed exchange's buckets in ``parallel/mesh.py``, scatter
-an int64 column as its two words (``int64_words`` / ``int64_from_words``).
-The ring keeps its int64 layout: taking its high plane and re-forming the
-int64 are two elementwise passes over the ring, 0.45 ms a column there
-against the 9.6 ms the two-plane scatter took. A ``double`` has no bits
-to take on that chip (a pair of float32 there, and the compiler refuses
-to bitcast it), so a ``double`` column stays ONE two-plane write.
+32-bit column (PERF.md section 5). So the keyed length window HOLDS an
+int64 ring column as its two words: two ``uint32[K*W]`` leaves ``(low,
+high)`` under ``buf[name]``, key-major like every ring leaf, each written
+by a one-operand 32-bit scatter in place. Nothing in a step reads or
+writes such a ring whole: the expired lane re-joins the 64 bits of the
+rows it gathers (``int64_from_words`` on a batch's worth of elements),
+and only ``contents()``, the partitioned join's probe surface, re-joins a
+ring. (Held as ``int64[K*W]`` and split / re-joined around the write, the
+layout cost 33 of a 152 ms step at 131,072,000 slots: the compiler's
+``X64Split*`` / ``X64Combine`` are timed copies on the chip, PR 33.) The
+routed exchange's buckets in ``parallel/mesh.py`` scatter an int64 column
+the same way (``int64_words`` / ``int64_from_words``). A ``double`` has no
+bits to take on that chip (a pair of float32 there, and the compiler
+refuses to bitcast it), so a ``double`` column stays ONE float64 leaf and
+ONE two-plane write. The other keyed stages keep their int64 rings whole.
 """
 
 from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -68,26 +74,31 @@ def int64_from_words(low, high):
     return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
 
 
-# the operations of a ring write that read or write a WHOLE ring column
-# without being the scatter (an int64 ring's split into words and its
-# re-join): their time grows with key capacity x window, not with the
-# batch. It nests in ``siddhi.state``; the benchmark's
-# ``step_ring_pass_ms`` reads it (``benchmarks/metrics/_ring_pass.py``).
-RING_PASS_SCOPE = "siddhi.ring_pass"
+def _new_ring(slots: int, dtype):
+    """A zeroed ring column of ``slots`` slots: one leaf, or for an int64
+    column its ``(low, high)`` uint32 word leaves (never ``[slots, 2]``: a
+    minor dimension of 2 is padded to a tile on the chip)."""
+    if np.dtype(dtype) == np.int64:
+        return (jnp.zeros((slots,), jnp.uint32), jnp.zeros((slots,), jnp.uint32))
+    return jnp.zeros((slots,), dtype)
+
+
+def _ring_read(ring, at):
+    """The ring column's values at the flat slots ``at``; a column held
+    as words is re-joined there, over ``at``'s elements and not the ring."""
+    if isinstance(ring, tuple):
+        low, high = ring
+        return int64_from_words(low[at], high[at])
+    return ring[at]
 
 
 def _ring_write(ring, slot, col):
-    """``ring.at[slot].set(col, mode="drop")``; an int64 ring is written
-    word by word, by two one-operand 32-bit scatters at the same slots."""
-    if ring.dtype != jnp.int64:
-        return ring.at[slot].set(col, mode="drop")
-    with jax.named_scope(RING_PASS_SCOPE):
-        low, high = int64_words(ring)
-    col_low, col_high = int64_words(col.astype(jnp.int64))
-    low = low.at[slot].set(col_low, mode="drop")
-    high = high.at[slot].set(col_high, mode="drop")
-    with jax.named_scope(RING_PASS_SCOPE):
-        return int64_from_words(low, high)
+    """``ring.at[slot].set(col, mode="drop")``; a column held as words is
+    two one-operand 32-bit scatters at the same slots, each in place."""
+    if isinstance(ring, tuple):
+        return tuple(plane.at[slot].set(word, mode="drop") for plane, word
+                     in zip(ring, int64_words(col.astype(jnp.int64))))
+    return ring.at[slot].set(col, mode="drop")
 
 
 def _per_key_layout(pk, valid_cur, num_keys: int):
@@ -132,7 +143,7 @@ class KeyedLengthWindowStage(WindowStage):
 
     def init_state(self, num_keys: int = 1) -> dict:
         W = self.length
-        buf = {k: jnp.zeros((num_keys * W,), dt) for k, dt in self.col_specs.items()}
+        buf = {k: _new_ring(num_keys * W, dt) for k, dt in self.col_specs.items()}
         return {"buf": buf, "total": jnp.zeros((num_keys,), jnp.int64)}
 
     @property
@@ -169,7 +180,7 @@ class KeyedLengthWindowStage(WindowStage):
 
         expired = {}
         for k in keys:
-            ring_v = state["buf"][k][flat]
+            ring_v = _ring_read(state["buf"][k], flat)
             expired[k] = jnp.where(from_batch, cols[k][batch_row], ring_v)
         expired[TS_KEY] = jnp.broadcast_to(now, (B,))  # LengthWindowProcessor:120
 
@@ -192,10 +203,11 @@ class KeyedLengthWindowStage(WindowStage):
 
     def contents(self, state):
         """Per-key probe surface for partitioned joins: ([K, W] cols,
-        [K, W] valid)."""
+        [K, W] valid); a column held as words comes back int64."""
         W = self.length
         K = state["total"].shape[0]
-        cols = {k: v.reshape(K, W) for k, v in state["buf"].items()}
+        cols = {k: (int64_from_words(*v) if isinstance(v, tuple) else v
+                    ).reshape(K, W) for k, v in state["buf"].items()}
         j = jnp.arange(W, dtype=jnp.int64)[None, :]
         valid = j < jnp.minimum(state["total"], W)[:, None]
         return cols, valid

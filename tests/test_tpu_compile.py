@@ -22,9 +22,7 @@ from jax.sharding import Mesh
 from jax.sharding import SingleDeviceSharding
 
 from siddhi_tpu import SiddhiManager
-from siddhi_tpu.observability.instruments import STATE_SCOPE
 from siddhi_tpu.ops.expressions import PADDED_KEY
-from siddhi_tpu.ops.keyed_windows import RING_PASS_SCOPE
 
 _STOCK = """
 @app:precision('{precision}')
@@ -215,45 +213,64 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch, price):
         elif primitive == "scatter-add":
             assert not result.startswith("("), (
                 f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
-    _assert_int64_rings_written_as_words(
+    _assert_int64_rings_are_word_leaves(
         compiled.as_text(), rows, doubles=int(price == "double"))
-    _assert_ring_passes_are_scoped(compiled.as_text(), rows)
 
 
-def _assert_int64_rings_written_as_words(hlo_text, rows, doubles=0):
-    """The ring write of ``ops/keyed_windows.py``: each of the two int64
-    ring columns (``__ts__``, ``volume``) is two one-operand u32 scatters
-    into ``[rows]`` (146 ns an update as one two-plane scatter against
-    5-8: PERF.md section 5, PR 32). The exception, written down: a
-    ``double`` is a pair of float32 on the chip with no bits to take (no
-    bitcast-convert from f64 there), so each of the ``doubles`` ring
-    columns stays ONE two-plane write; nothing else under
-    ``siddhi.state`` writes two planes."""
+_X64_CALL = re.compile(r'custom_call_target="(X64SplitLow|X64SplitHigh|X64Combine)"')
+_DEFINITION = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (.*)$")
+
+
+def _assert_int64_rings_are_word_leaves(hlo_text, rows, doubles=0):
+    """The ring layout of ``ops/keyed_windows.py``: each of the two int64
+    ring columns (``__ts__``, ``volume``) is two ``u32[rows]`` leaves of
+    the state, each written by a one-operand scatter whose operand is a
+    parameter of the program (the donated state's own leaf, updated in
+    place: 146 ns an update as one two-plane scatter against 5-8, PERF.md
+    section 5, PR 32). Nothing takes a ring apart or puts one together:
+    no ``X64SplitLow`` / ``X64SplitHigh`` / ``X64Combine`` custom call
+    (timed copies on the chip) and no elementwise fusion as long as a
+    ring (33 of cell 6's 152 ms step before PR 34). The exception,
+    written down: a ``double`` is a pair of float32 on the chip with no
+    bits to take (no bitcast-convert from f64 there), so each of the
+    ``doubles`` ring columns stays ONE two-plane write; nothing else
+    under ``siddhi.state`` writes two planes."""
+    lines = hlo_text.splitlines()
     ring = [result for scope, primitive, result in _scatter_ops(hlo_text)
             if scope == "siddhi.state" and primitive == "scatter"]
     assert [r for r in ring if r.startswith("(")] == [
         f"(f32[{rows}], f32[{rows}])"] * doubles, ring
     assert ring.count(f"u32[{rows}]") == 4, ring
-
-
-def _assert_ring_passes_are_scoped(hlo_text, rows):
-    """``siddhi.ring_pass`` (inside ``siddhi.state``) is on what takes an
-    int64 ring apart and puts it together again, whole-ring elementwise
-    passes (the benchmark's ``step_ring_pass_ms`` reads it), and on no
-    scatter: the two int64 rings' high-plane passes and re-joins are
-    there, over ``[rows]``, and every scatter keeps the plain
-    ``siddhi.state/scatter`` name that ``_scatters`` reads."""
-    scoped = [line for line in hlo_text.splitlines()
-              if RING_PASS_SCOPE in line]
-    assert all(f"{STATE_SCOPE}/{RING_PASS_SCOPE}/" in line for line in scoped)
-    primitives = {_OP_NAME.search(line)[1].rsplit("/", 1)[1]
-                  for line in scoped}
-    assert primitives == {"shift_right_arithmetic", "shift_left", "or"}
-    assert not [line for line in scoped if "scatter" in line]
-    passes = [line for line in scoped if " fusion(" in line]
-    assert len(passes) == 4, passes       # two columns: a pass, a re-join
-    assert all(f"u32[{rows}]" in line for line in passes)
-    assert len(_scatter_ops(hlo_text)) > 0
+    # the four fusions that hold those scatters, in the entry computation:
+    # the ring operand of each is the state's own leaf, alone or staged
+    # into fast memory by an asynchronous copy (a small ring), and is
+    # aliased to the fusion's result
+    defined = {m[1]: m[2] for m in map(_DEFINITION.search, lines) if m}
+    word_writes = [line for line in lines if re.search(
+        rf'= u32\[{rows}\]\S* fusion\(.*/siddhi\.state/scatter"', line)]
+    assert len(word_writes) == 4, word_writes
+    leaves = set()
+    for line in word_writes:
+        assert '"aliasing_operands":{"lists":[{"indices":["0",' in line, line
+        operand = re.search(r" fusion\((%[\w.-]+)", line)[1]
+        while (move := re.match(r".* copy-(?:done|start)\((%[\w.-]+)\)",
+                                defined[operand])):
+            operand = move[1]
+        leaf = re.match(rf"u32\[{rows}\]\S* parameter\((\d+)\)",
+                        defined[operand])
+        assert leaf, (operand, defined[operand])
+        leaves.add(leaf[1])
+    assert len(leaves) == 4, leaves
+    # (a ``double`` ring is a pair of planes to the compiler, which takes
+    # it apart and puts it together itself: the ``doubles``' own calls)
+    x64 = [line for line in lines if _X64_CALL.search(line)
+           and f"[{rows}]" in line]
+    assert len(x64) == 3 * doubles and not [
+        line for line in x64 if re.search(rf"[su](?:32|64)\[{rows}\]", line)], x64
+    passes = [line for line in lines
+              if re.search(rf"= \(?\w+\[{rows}\]", line)
+              and " fusion(" in line and "kind=kLoop" in line]
+    assert not passes, passes
 
 
 _TUMBLING = """
@@ -461,7 +478,7 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
     assert buckets.count(f"u32[{rows_per_shard}]") == 6, buckets
     ring_rows = max(a.shape[0] for a in
                     jax.tree_util.tree_leaves(state["win"])) // 4
-    _assert_int64_rings_written_as_words(text, ring_rows)
+    _assert_int64_rings_are_word_leaves(text, ring_rows)
 
 
 # ------------------------------------------------------------------------
@@ -470,20 +487,24 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
 # are metadata and not in it). A PR that means to leave a cell's program
 # alone (PR 33: a new scope, a new growth path) proves it here; a PR that
 # means to change one replaces that cell's digests with what this test
-# prints (PR 32 printed the first five: PERF.md section 6).
+# prints (PR 32 printed the first five: PERF.md section 6; PR 34 changed
+# the keyed ring's layout, so cells 1, 4 and 6, and only they, are new).
 _STEP_SHA256 = {
     "partition_len1k_10k.hot20_bulk": [
-        "f3e0facb264ce3676ba4d52488614e9c4867162a4cbab20a36b71e5cbd901842"],
+        "e03e073b1f88ecb8b345620c214f591d0517fdb7d5ab4fdb713db672d2b9b537"],
     "groupby_len1k_10k.uniform_bulk": [
         "7a62b53a00ec4f8fb6467bcbe998ae7b46666ea6f20ddf5ad627fcdc40ccafe5"],
     "pattern_ab_10k.rounds_bulk": [
         "58f6255a51ee3dbd38c347f8e70ad0e764a036bc46c0b5c2c85f840f77780b56",
         "dd60d4459a94eed6d147e4df268c4c98930b9226b1567827d7921905062b4166"],
     "partition_len1k_40k.hot20_bulk_x4": [
-        "8849587ba314b80ef8173ea4cbe34b26820ce801186f6a86189d01ca0613a102"],
+        "bbea5c96eb4be8b49b7d5bd3ee68567f48b412b8229a18da9c61f4452fb1d83b"],
     "timebatch_1s_10k.hot20_tick250": [
         "a055e7ea31b399f49b871ee11dec9001f9402c7dfb5695fb840daf5a3282ce43",
         "a46ee91e0411de179849c770668c3f573c42a301e26b436dd40114fe56361f27"],
+    "partition_len1k_100k.hot20_bulk_100k": [       # two key capacities
+        "9c9a4107cc075b0b77c0aca81a53a99927264de89248df0e0f5496fcb628a52b",
+        "de1a9a71831a91ea8d1e6a6698508a61f83fc8c638f56c69c71dd1b58431e6f0"],
 }
 
 
