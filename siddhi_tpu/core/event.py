@@ -307,6 +307,34 @@ def _pull(refs: list, rows: bool) -> list:
     return out
 
 
+def launch_step(step, state, *args, query: str, jr=None):
+    """The host->device side of a batch: ``step(state, *args)``, the call
+    of a jitted step and nothing else, under a ``siddhi.launch`` span
+    while spans are on, and charged to the batch's journey ``jr``. There
+    is no ``device_put`` on the hot path: the batch's numpy columns go up
+    inside this call, so ``h2d_bytes`` / ``h2d_arrays`` are the ``nbytes``
+    and count of the argument leaves that are numpy (a leaf already on the
+    device crosses nothing); ``state_leaves`` is what the call flattens
+    beside them. A step that binds further arguments itself (a join side's
+    probe surface) names them in ``step.bound``."""
+    if not spans_on():
+        return step(state, *args)
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(state)
+    n_state = len(leaves)
+    leaves += jax.tree_util.tree_leaves((args, getattr(step, "bound", ())))
+    up = [x for x in leaves if isinstance(x, (np.ndarray, np.generic))]
+    nbytes = sum(int(x.nbytes) for x in up)
+    with span("launch", query=query, h2d_bytes=nbytes, h2d_arrays=len(up),
+              state_leaves=n_state,
+              batch=jr.batch if jr is not None else None) as sp:
+        out = step(state, *args)
+    if jr is not None:
+        jr.launched(sp.ms, nbytes)
+    return out
+
+
 class LazyColumns(dict):
     """Column dict whose device-array values materialize to numpy on first
     access. A device->host pull is a synchronization that costs a fixed

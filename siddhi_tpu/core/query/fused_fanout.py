@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.analysis.locks import make_lock
-from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
+from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, launch_step, pack_pool_of
 from siddhi_tpu.observability import instruments, journey
 from siddhi_tpu.observability.tracing import span
 from siddhi_tpu.core.plan.selector_plan import GK_KEY, STR_RANK
@@ -302,7 +302,13 @@ class FusedFanoutRuntime(Receiver):
 
         return instruments.app_instruments_on(self.app_context)
 
-    def _prepare(self, batch: HostBatch):
+    def _keys(self) -> int:
+        """Group keys the members' keyers hold (one count a keyer)."""
+        return sum(len(k) for k in {id(m.keyer): m.keyer
+                                    for m in self.members
+                                    if m.keyer is not None}.values())
+
+    def _prepare(self, batch: HostBatch, jr=None):
         """Shared per-batch prep: group-key columns (deduplicated by
         keyer identity), per-member capacity/state, the fused input dict,
         and the fused step (re-jitted when the slot layout or any key
@@ -314,25 +320,27 @@ class FusedFanoutRuntime(Receiver):
         gk_cols: List[np.ndarray] = []
         slots: List[int] = []
         slot_of = {}
-        for m in self.members:
-            kid = id(m.keyer) if m.keyer is not None else 0
-            s = slot_of.get(kid)
-            if s is None:
-                s = slot_of[kid] = len(gk_cols)
-                gk_cols.append(np.zeros(cap, np.int32) if m.keyer is None
-                               else m.keyer(cols))
-            slots.append(s)
-        for m in self.members:
-            if m.keyer is not None:
-                m._ensure_capacity()
-            if m._state is None:
-                m._state = m._init_state()
-            prep = getattr(m, "prepare_cols", None)
-            if prep is not None and prep(cols):
-                # a join side grew its partition directory: the member's
-                # state shapes changed under the same (slots, capacities)
-                # signature — drop the fused step so it re-jits
-                self._step = None
+        with journey.keying(jr, f"fanout.{self.stream_id}", cap, self._keys):
+            for m in self.members:
+                kid = id(m.keyer) if m.keyer is not None else 0
+                s = slot_of.get(kid)
+                if s is None:
+                    s = slot_of[kid] = len(gk_cols)
+                    gk_cols.append(np.zeros(cap, np.int32)
+                                   if m.keyer is None else m.keyer(cols))
+                slots.append(s)
+            for m in self.members:
+                if m.keyer is not None:
+                    m._ensure_capacity()
+                if m._state is None:
+                    m._state = m._init_state()
+                prep = getattr(m, "prepare_cols", None)
+                if prep is not None and prep(cols):
+                    # a join side grew its partition directory: the
+                    # member's state shapes changed under the same (slots,
+                    # capacities) signature — drop the fused step so it
+                    # re-jits
+                    self._step = None
         cols_dev = dict(cols)   # jit boundary: raw (possibly device) arrays
         for s, gk in enumerate(gk_cols):
             cols_dev[_FGK.format(s)] = gk
@@ -449,9 +457,10 @@ class FusedFanoutRuntime(Receiver):
         # one journey per group batch: the shared dispatch/device stages
         # are recorded under EVERY member's name at finish
         jr = journey.begin(batch) if journey.enabled() else None
-        states, cols_dev = self._prepare(batch)
-        new_states, (outs, metas) = self._step(states, cols_dev,
-                                               self._now64())
+        states, cols_dev = self._prepare(batch, jr)
+        new_states, (outs, metas) = launch_step(
+            self._step, states, cols_dev, self._now64(),
+            query=f"fanout.{self.stream_id}", jr=jr)
         if jr is not None:
             jr.end_dispatch()
         tel.count(f"fanout.{self.stream_id}.dispatches")

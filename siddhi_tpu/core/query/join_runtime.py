@@ -31,7 +31,7 @@ import numpy as np
 
 from siddhi_tpu.core.eligibility import ReasonCode as _RC
 from siddhi_tpu.core.eligibility import reason as _reason
-from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
+from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, launch_step, pack_pool_of
 from siddhi_tpu.core.plan.selector_plan import FLUSH_KEY, GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
@@ -853,42 +853,47 @@ class JoinQueryRuntime(QueryRuntime):
             # shared _finish_device_batch tail consumes the context.
             # The split (host-keyed) tail is synchronous and does not
             # thread the journey, so grouped joins skip the allocation.
-            self._cur_journey = journey.begin(batch) \
+            jr = self._batch_journey = self._cur_journey = \
+                journey.begin(batch) \
                 if journey.enabled() and self.keyer is None else None
             side = self.sides[side_key]
             cols = batch.cols
             partitioned = self.partition_ctx is not None
             notify_host = None
-            if partitioned:
-                if side.keyer is not None:
-                    cols, pk = side.keyer.apply(cols)
-                    batch = HostBatch(cols)
-                    cols[PK_KEY] = np.asarray(pk, np.int32)
-                elif side.global_side:
-                    # non-partitioned stream inside a partition: the
-                    # reference hands the event to every EXISTING
-                    # instance (each holds its own window copy), so
-                    # broadcast each row across the key axis, valid only
-                    # for keys active at arrival — a later-created
-                    # instance must NOT see earlier global events
-                    # (JoinPartitionTestCase test10). _ensure_capacity
-                    # runs before K is read so growth precedes the tile.
-                    self._ensure_capacity()
-                    n_active = self.partition_ctx.active_keys()
-                    K = self._win_keys
-                    B = batch.capacity
-                    rep = {}
-                    for name, v in cols.items():
-                        rep[name] = np.repeat(np.asarray(v), K, axis=0)
-                    pk_tile = np.tile(np.arange(K, dtype=np.int32), B)
-                    rep[PK_KEY] = pk_tile
-                    rep[VALID_KEY] = rep[VALID_KEY] & (pk_tile < n_active)
-                    cols = rep
-                    batch = HostBatch(cols)
-                elif PK_KEY not in cols:
-                    cols[PK_KEY] = np.zeros(batch.capacity, np.int32)
-                if not side.global_side:   # global branch ensured already
-                    self._ensure_capacity()
+            with journey.keying(jr, self.name, batch.capacity,
+                                self._needed_sel_keys):
+                if partitioned:
+                    if side.keyer is not None:
+                        cols, pk = side.keyer.apply(cols)
+                        batch = HostBatch(cols)
+                        cols[PK_KEY] = np.asarray(pk, np.int32)
+                    elif side.global_side:
+                        # non-partitioned stream inside a partition: the
+                        # reference hands the event to every EXISTING
+                        # instance (each holds its own window copy), so
+                        # broadcast each row across the key axis, valid
+                        # only for keys active at arrival — a later-created
+                        # instance must NOT see earlier global events
+                        # (JoinPartitionTestCase test10). _ensure_capacity
+                        # runs before K is read so growth precedes the
+                        # tile.
+                        self._ensure_capacity()
+                        n_active = self.partition_ctx.active_keys()
+                        K = self._win_keys
+                        B = batch.capacity
+                        rep = {}
+                        for name, v in cols.items():
+                            rep[name] = np.repeat(np.asarray(v), K, axis=0)
+                        pk_tile = np.tile(np.arange(K, dtype=np.int32), B)
+                        rep[PK_KEY] = pk_tile
+                        rep[VALID_KEY] = rep[VALID_KEY] & (
+                            pk_tile < n_active)
+                        cols = rep
+                        batch = HostBatch(cols)
+                    elif PK_KEY not in cols:
+                        cols[PK_KEY] = np.zeros(batch.capacity, np.int32)
+                    if not side.global_side:   # global branch ensured already
+                        self._ensure_capacity()
             if side.host_window is not None:
                 now_h = int(self.app_context.timestamp_generator.current_time())
                 hctx = {"xp": np, "current_time": now_h}
@@ -966,6 +971,7 @@ class JoinQueryRuntime(QueryRuntime):
                         def call(st, c, now, _pc=probe_cols, _pv=probe_valid):
                             return jitted(st, _pc, _pv, c, now)
 
+                        call.bound = (probe_cols, probe_valid)
                         n = self._finish_device_batch(call, sub, _ovf_msg)
                         if n is not None:
                             notify = n if notify is None else min(notify, n)
@@ -1011,6 +1017,7 @@ class JoinQueryRuntime(QueryRuntime):
                             return jitted(st, probe_cols, probe_valid,
                                           cols, now)
 
+                        call.bound = (probe_cols, probe_valid)
                         notify = self._finish_device_batch(
                             call, cols, _ovf_msg)
             tel.histogram(f"join.probe_ms.{self.name}").record(
@@ -1055,7 +1062,8 @@ class JoinQueryRuntime(QueryRuntime):
             from siddhi_tpu.core.plan.selector_plan import STR_RANK
 
             cols[STR_RANK] = self.dictionary.rank_table()
-        self._state, out = step(self._state, cols, now)
+        self._state, out = launch_step(step, self._state, cols, now,
+                                       query=self.name)
         out_host = LazyColumns(out)
         meta = out_host.pop("__meta__", None)
         if meta is not None:

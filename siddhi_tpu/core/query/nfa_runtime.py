@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, pack_pool_of
+from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, launch_step, pack_pool_of
 from siddhi_tpu.core.plan.selector_plan import GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
@@ -357,22 +357,24 @@ class NFAQueryRuntime(QueryRuntime):
                 and j.fault_junction is not None) else None
             # batch-journey, as QueryRuntime.process_batch: fork the pack
             # stamp, open the dispatch stage; _run_nfa_step consumes it
-            self._cur_journey = journey.begin(batch) \
+            jr = self._cur_journey = journey.begin(batch) \
                 if journey.enabled() else None
             cols = batch.cols
             partitioned = self.partition_ctx is not None
-            if partitioned:
-                keyer = self.stream_keyers.get(stream_id)
-                if keyer is not None:
-                    cols, pk = keyer.apply(cols)
-                    cols[PK_KEY] = np.asarray(pk, np.int32)
+            with journey.keying(jr, self.name, batch.capacity,
+                                self._needed_sel_keys):
+                if partitioned:
+                    keyer = self.stream_keyers.get(stream_id)
+                    if keyer is not None:
+                        cols, pk = keyer.apply(cols)
+                        cols[PK_KEY] = np.asarray(pk, np.int32)
+                    else:
+                        cols[PK_KEY] = np.zeros(batch.capacity, np.int32)
+                    cols[GK_KEY] = cols[PK_KEY]
+                    self._ensure_capacity()
                 else:
-                    cols[PK_KEY] = np.zeros(batch.capacity, np.int32)
-                cols[GK_KEY] = cols[PK_KEY]
-            else:
-                cols[GK_KEY] = np.zeros(cols[VALID_KEY].shape[0], np.int32)
-            if partitioned:
-                self._ensure_capacity()
+                    cols[GK_KEY] = np.zeros(cols[VALID_KEY].shape[0],
+                                            np.int32)
             if self._state is None:
                 self._state = self._init_state()
             force_generic = self._host_hard_batch(stream_id, cols)
@@ -405,9 +407,10 @@ class NFAQueryRuntime(QueryRuntime):
                 from siddhi_tpu.core.plan.selector_plan import STR_RANK
 
                 jcols[STR_RANK] = self.dictionary.rank_table()
-            notify = self._run_nfa_step(lambda: step(
-                self._state, jcols,
-                np.int64(self.app_context.timestamp_generator.current_time())))
+            notify = self._run_nfa_step(lambda: launch_step(
+                step, self._state, jcols,
+                np.int64(self.app_context.timestamp_generator.current_time()),
+                query=self.name, jr=jr))
         if notify is not None and self.scheduler is not None:
             self.scheduler.notify_at(notify, self._timer_cb)
 
@@ -491,7 +494,7 @@ class NFAQueryRuntime(QueryRuntime):
                 pump.flush_owner(self)
             if self._state is None:
                 self._state = self._init_state()
-            self._cur_journey = journey.begin() \
+            jr = self._cur_journey = journey.begin() \
                 if journey.enabled() else None
             if self._timer_step is None:
                 fn = named_step(self.build_timer_step_fn(), "nfa_timer")
@@ -506,7 +509,8 @@ class NFAQueryRuntime(QueryRuntime):
                     family="nfa_timer",
                     cache_extra=str(self._shard_mesh or ""))
             notify = self._run_nfa_step(
-                lambda: self._timer_step(self._state, np.int64(ts)),
+                lambda: launch_step(self._timer_step, self._state,
+                                    np.int64(ts), query=self.name, jr=jr),
                 allow_pipeline=False)
         if notify is not None and self.scheduler is not None:
             self.scheduler.notify_at(notify, self._timer_cb)
@@ -589,7 +593,7 @@ class NFAQueryRuntime(QueryRuntime):
         if self.keyer is not None:
             out_host.pop("__overflow__", None)
             out_host.pop("__notify__", None)
-            out_host = self._host_keyed_select(out_host)
+            out_host = self._host_keyed_select(out_host, jr)
             size_hint = None
         self._timed_emit(self._host_batch(out_host, size_hint), jr,
                          rows_out=size_hint)

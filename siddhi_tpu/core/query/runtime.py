@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from siddhi_tpu.analysis.locks import make_lock
-from siddhi_tpu.core.event import CURRENT, EXPIRED, TIMER as TIMER_TYPE, Event, HostBatch, LazyColumns, StringDictionary, pack_pool_of
+from siddhi_tpu.core.event import CURRENT, EXPIRED, TIMER as TIMER_TYPE, Event, HostBatch, LazyColumns, StringDictionary, launch_step, pack_pool_of
 from siddhi_tpu.observability import instruments, journey
 from siddhi_tpu.observability.tracing import span
 from siddhi_tpu.observability.instruments import Slot
@@ -184,6 +184,8 @@ class QueryRuntime(Receiver):
         #                               fault-stream routing (@OnError)
         self._cur_journey = None    # batch-journey context of the batch in
         #                             process (observability/journey.py)
+        self._batch_journey = None  # the same, kept for the whole batch:
+        #                             every piece's launch is stamped on it
         # device-instrument plumbing (observability/instruments.py):
         # last drained raw lanes per slot (zero-pull scrape surface),
         # host-known capacity denominators, and the lazily-registered
@@ -795,11 +797,10 @@ class QueryRuntime(Receiver):
             # stage (host prep + step dispatch); _finish_device_batch
             # consumes it (one journey per delivered batch — routed
             # splits ride the first piece)
-            self._cur_journey = journey.begin(batch) \
-                if journey.enabled() else None
-            if self._cur_journey is not None \
-                    and self._now_override is not None:
-                self._cur_journey.timer_steps = 1
+            jr = self._batch_journey = self._cur_journey = \
+                journey.begin(batch) if journey.enabled() else None
+            if jr is not None and self._now_override is not None:
+                jr.timer_steps = 1
             notify_host = None
             if self.log_stages:
                 self._run_log_taps(batch)
@@ -808,17 +809,19 @@ class QueryRuntime(Receiver):
             if partitioned and self.host_window is not None:
                 # per-key host stages route rows by the pk column, so the
                 # partition key must be attached before the window runs
-                cols = batch.cols
-                if self.carried_pk:
-                    pk0 = cols.get(PK_KEY)
-                    if pk0 is None:
+                with journey.keying(jr, self.name, batch.capacity,
+                                    self._needed_sel_keys):
+                    cols = batch.cols
+                    if self.carried_pk:
+                        pk0 = cols.get(PK_KEY)
+                        if pk0 is None:
+                            pk0 = np.zeros(batch.capacity, np.int32)
+                    elif self.partition_keyer is not None:
+                        cols, pk0 = self.partition_keyer.apply(cols)
+                        batch = HostBatch(cols)
+                    else:
                         pk0 = np.zeros(batch.capacity, np.int32)
-                elif self.partition_keyer is not None:
-                    cols, pk0 = self.partition_keyer.apply(cols)
-                    batch = HostBatch(cols)
-                else:
-                    pk0 = np.zeros(batch.capacity, np.int32)
-                batch.cols[PK_KEY] = np.asarray(pk0, np.int32)
+                    batch.cols[PK_KEY] = np.asarray(pk0, np.int32)
                 pk_done = True
             if self.host_window is not None:
                 now_h = self._now()
@@ -849,29 +852,32 @@ class QueryRuntime(Receiver):
                     batch.cols, {"xp": np, "current_time": now_h}))
             cols = batch.cols
             pk = None
-            if partitioned:
-                if pk_done:
-                    # already attached (and carried through the host
-                    # window's emitted rows)
-                    pk = cols.get(PK_KEY)
-                    if pk is None:
-                        pk = np.zeros(batch.capacity, np.int32)
-                elif self.carried_pk:
-                    pk = cols.get(PK_KEY)
-                    if pk is None:
-                        pk = np.zeros(batch.capacity, np.int32)
-                elif self.partition_keyer is not None:
-                    cols, pk = self.partition_keyer.apply(cols)
-                    batch = HostBatch(cols)
-                cols[PK_KEY] = np.asarray(pk, np.int32)
-            if self.keyer is not None:
-                cols[GK_KEY] = self.keyer(cols, pk=pk if partitioned else None)
-            elif partitioned:
-                cols[GK_KEY] = cols[PK_KEY]
-            else:
-                cols[GK_KEY] = np.zeros(batch.capacity, np.int32)
-            if partitioned or self.keyer is not None:
-                self._ensure_capacity()
+            with journey.keying(jr, self.name, batch.capacity,
+                                self._needed_sel_keys):
+                if partitioned:
+                    if pk_done:
+                        # already attached (and carried through the host
+                        # window's emitted rows)
+                        pk = cols.get(PK_KEY)
+                        if pk is None:
+                            pk = np.zeros(batch.capacity, np.int32)
+                    elif self.carried_pk:
+                        pk = cols.get(PK_KEY)
+                        if pk is None:
+                            pk = np.zeros(batch.capacity, np.int32)
+                    elif self.partition_keyer is not None:
+                        cols, pk = self.partition_keyer.apply(cols)
+                        batch = HostBatch(cols)
+                    cols[PK_KEY] = np.asarray(pk, np.int32)
+                if self.keyer is not None:
+                    cols[GK_KEY] = self.keyer(
+                        cols, pk=pk if partitioned else None)
+                elif partitioned:
+                    cols[GK_KEY] = cols[PK_KEY]
+                else:
+                    cols[GK_KEY] = np.zeros(batch.capacity, np.int32)
+                if partitioned or self.keyer is not None:
+                    self._ensure_capacity()
             if self._state is None:
                 self._state = self._init_state()
             if self._step is None:
@@ -891,7 +897,6 @@ class QueryRuntime(Receiver):
                 from siddhi_tpu.parallel.mesh import prepare_routed_batches
 
                 notify = None
-                jr = self._cur_journey
                 with span("route.prepare", query=self.name,
                           batch=jr.batch if jr is not None else None) as sp:
                     pieces = prepare_routed_batches(self, cols)
@@ -950,14 +955,19 @@ class QueryRuntime(Receiver):
         ``decode_meta_suffix`` / ``instrument_slots``."""
         self.decode_meta_suffix(meta)
 
-    def _host_keyed_select(self, out_host: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    def _host_keyed_select(self, out_host: Dict[str, np.ndarray],
+                           jr=None) -> Dict[str, np.ndarray]:
         """Split-pipeline tail: when the group key is computed from a device
         stage's OUTPUT columns (pattern captures, joined rows), the keyer
         runs host-side between the stage and a separately-jitted selector
         step (GroupByKeyGenerator.java:37 over intermediate events)."""
-        pk = out_host.get(PK_KEY) if self.partition_ctx is not None else None
-        out_host[GK_KEY] = self.keyer(out_host, pk=pk)
-        self._ensure_capacity()
+        with journey.keying(jr, self.name,
+                            dict.__getitem__(out_host, VALID_KEY).shape[0],
+                            self._needed_sel_keys):
+            pk = (out_host.get(PK_KEY) if self.partition_ctx is not None
+                  else None)
+            out_host[GK_KEY] = self.keyer(out_host, pk=pk)
+            self._ensure_capacity()
         if self._sel_step is None:
             sel = self.selector_plan
 
@@ -976,7 +986,9 @@ class QueryRuntime(Receiver):
             self.app_context.telemetry.record_jit(
                 f"query.{self.name}.selector", hit=True)
         now = np.int64(self._now())
-        new_sel, sel_out = self._sel_step(self._state["sel"], dict(out_host), now)
+        new_sel, sel_out = launch_step(
+            self._sel_step, self._state["sel"], dict(out_host), now,
+            query=self.name, jr=jr)
         self._state["sel"] = new_sel
         out = LazyColumns(sel_out)
         meta = out.pop("__meta__", None)
@@ -1008,7 +1020,11 @@ class QueryRuntime(Receiver):
             from siddhi_tpu.core.plan.selector_plan import STR_RANK
 
             cols[STR_RANK] = self.dictionary.rank_table()
-        self._state, out = step(self._state, cols, now)
+        # the batch's journey, not ``jr``: a later piece of a split batch
+        # has none of its own, and its launch belongs to the batch's sum
+        self._state, out = launch_step(step, self._state, cols, now,
+                                       query=self.name,
+                                       jr=self._batch_journey)
         if jr is not None:
             jr.state_sized(self.state_bytes(), self.state_slots())
         # lazy pull: only columns a consumer actually reads cross the

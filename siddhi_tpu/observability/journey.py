@@ -31,6 +31,8 @@ histograms on ``GET /metrics``):
                  time, service is the worker's re-batching (~0).
 - ``dispatch`` — host work inside ``process_batch``: key computation,
                  capacity checks, routing prep, jitted-step dispatch.
+                 Two sub-stages have a span and a ring counter of their
+                 own (below): ``key`` and ``launch``.
 - ``device``   — observed device busy time. A pipelined batch rides in
                  flight; at drain the existing ``jax.Array.is_ready``
                  machinery tells which side was waiting: output NOT
@@ -57,6 +59,25 @@ ring record):
                  and ``rows_padded`` (the length the columns were pulled
                  at) beside ``rows_out`` (the meta's count of valid
                  rows). The bytes are on the ``siddhi.pull`` span.
+
+Two sub-stages of ``dispatch`` are counters of the ring record too, each
+stamped from its own span inside ``siddhi.query.step`` (``None`` where the
+stage did not run):
+
+- ``key_ms``    — ``siddhi.key`` (``keying``): the partition-key and
+                 group-key computation and the key-capacity check; a
+                 ``siddhi.grow`` is its child. Where a host window runs
+                 between the partition key and the group key there are
+                 two spans a batch, and ``key_ms`` is their sum.
+- ``launch_ms`` — ``siddhi.launch`` (``core/event.py`` ``launch_step``):
+                 the call of the jitted step and nothing else: the
+                 flatten of the arguments, the host->device transfer of
+                 the batch's numpy columns (there is no ``device_put`` on
+                 the hot path) and the enqueue. ``h2d_bytes`` is what
+                 crossed: the ``nbytes`` of the argument leaves that were
+                 numpy. A batch dispatched in pieces sums both over its
+                 pieces (``dispatch_ms`` ends with the first piece: a
+                 split batch's journey rides it).
 
 A device-routed query (``parallel/mesh.py``) stamps four more counters
 of the ring record, ``None`` for every other query: ``route_prep_ms`` and
@@ -258,7 +279,8 @@ class Journey:
                  "emit_ms", "pull_ms", "pulls", "rows_out", "rows_padded",
                  "route_prep_ms", "route_pieces", "shard_rows_max",
                  "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
-                 "state_bytes", "state_slots")
+                 "state_bytes", "state_slots", "key_ms", "launch_ms",
+                 "h2d_bytes", "_rec")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -285,6 +307,10 @@ class Journey:
         self.grow_ms: Optional[float] = None
         self.state_bytes: Optional[int] = None
         self.state_slots: Optional[int] = None
+        self.key_ms: Optional[float] = None
+        self.launch_ms: Optional[float] = None
+        self.h2d_bytes: Optional[int] = None
+        self._rec: Optional[dict] = None     # the ring record, once finished
 
     # one journey object is stamped on the batch at pack time; each
     # receiving query forks its own (stage times are per query)
@@ -339,6 +365,23 @@ class Journey:
         """This batch forced a key-capacity growth: the ``siddhi.grow``
         span's duration (a batch can force several: summed)."""
         self.grow_ms = (self.grow_ms or 0.0) + float(ms or 0.0)
+
+    def keyed(self, ms: Optional[float]) -> None:
+        """One ``siddhi.key`` span of this batch (summed: a host window
+        between the partition key and the group key makes two)."""
+        self.key_ms = (self.key_ms or 0.0) + float(ms or 0.0)
+
+    def launched(self, ms: Optional[float], nbytes: int) -> None:
+        """One ``siddhi.launch`` span of this batch, and the bytes its
+        arguments took to the device; summed over the pieces of a split
+        batch. A later piece may be launched after the first piece's
+        emit finished the journey (synchronous tail): the ring record
+        then takes the sum too."""
+        self.launch_ms = (self.launch_ms or 0.0) + float(ms or 0.0)
+        self.h2d_bytes = (self.h2d_bytes or 0) + int(nbytes)
+        if self._rec is not None:
+            self._rec["launch_ms"] = self.launch_ms
+            self._rec["h2d_bytes"] = self.h2d_bytes
 
     def state_sized(self, nbytes: int, slots: Optional[int]) -> None:
         """The query's state on the device as this batch's step left it:
@@ -401,7 +444,7 @@ class Journey:
                     _WALL[(app, name)] = [t0, now]
                 else:
                     wall[1] = now
-            _RING.append({
+            self._rec = {
                 "app": app, "queries": list(names),
                 "pack_ms": self.pack_ms, "queue_ms": self.queue_ms,
                 "dispatch_ms": self.dispatch_ms,
@@ -434,7 +477,14 @@ class Journey:
                 "grow_ms": self.grow_ms,
                 "state_bytes": self.state_bytes,
                 "state_slots": self.state_slots,
-            })
+                # the two sub-stages of dispatch that have a span of
+                # their own (``siddhi.key``, ``siddhi.launch``) and the
+                # bytes the launch took to the device; None: not run
+                "key_ms": self.key_ms,
+                "launch_ms": self.launch_ms,
+                "h2d_bytes": self.h2d_bytes,
+            }
+            _RING.append(self._rec)
 
 
 class _EmitStage:
@@ -459,6 +509,42 @@ class _EmitStage:
         self.jr.emit_ms = self._span.ms or 0.0
         self.jr.finish(self.app_context, self.names)
         return False
+
+
+class _KeyStage:
+    """``keying``: the one place the key sub-stage is timed."""
+
+    __slots__ = ("jr", "_size", "_before", "_span")
+
+    def __init__(self, jr, query, rows, size):
+        self.jr, self._size, self._before = jr, size, size()
+        self._span = span("key", query=query, rows=rows,
+                          batch=jr.batch if jr is not None else None)
+
+    def __enter__(self):
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        keys = self._size()
+        self._span.note(keys=keys, new_keys=max(0, keys - self._before))
+        self._span.__exit__(*exc)
+        if self.jr is not None:
+            self.jr.keyed(self._span.ms)
+        return False
+
+
+def keying(jr: Optional[Journey], query: str, rows: int, size):
+    """The key sub-stage of dispatch as a context manager: a
+    ``siddhi.key`` span around the partition-key and group-key
+    computation and the capacity check of ``rows`` rows, with ``keys``
+    (what ``size()`` says once they ran: the dictionary's size) and
+    ``new_keys`` (its growth: the ids this batch allocated); at the close
+    ``key_ms`` is stamped on ``jr``. The shared no-op, and ``size`` never
+    called, when spans are off."""
+    if not tracing.spans_on():
+        return tracing.NOOP
+    return _KeyStage(jr, query, rows, size)
 
 
 def emitting_journey() -> Optional[Journey]:

@@ -1,8 +1,9 @@
 """Structured tracing spans: one primitive, two records.
 
 A span covers one host-side stage of the pipeline (compile, plan, jit,
-pack, junction dispatch, query step, route prepare, meta pull, emit,
-output pull, sink publish, persist) at batch granularity. ``span(...)``
+pack, junction dispatch, query step with its two sub-stages key and
+launch, route prepare, meta pull, emit, output pull, sink publish,
+persist) at batch granularity. ``span(...)``
 is the ONLY way the engine opens one. While it is on, a span
 
 - lands in the Chrome-trace ring of ``TRACER`` (when ``TRACER`` is
@@ -73,6 +74,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
 
 NOOP = _NoopSpan()
 
@@ -108,6 +112,14 @@ class _Span:
         self.ms = (t1 - self._t0) / 1e6
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
+
+    def note(self, **args):
+        """Arguments known only once the stage has run (the keys a batch
+        allocated): added to both records while the span is open."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**{
+                k: _jsonable(v) for k, v in args.items() if v is not None})
 
 
 class Tracer:

@@ -239,6 +239,7 @@ def test_journey_off_leaves_no_trace():
     histograms appear, the ring gains no record and the span primitive
     hands out its shared no-op (so no profiler annotation is entered) —
     the off path is one flag check."""
+    from siddhi_tpu.core.event import launch_step
     from siddhi_tpu.observability import tracing
 
     m = _manager(2)
@@ -249,6 +250,13 @@ def test_journey_off_leaves_no_trace():
     assert not tracing.spans_on()
     assert tracing.span("pack") is tracing.span("query.step", query="pq")
     assert journey.pack_span() is tracing.NOOP is tracing.span("emit")
+    # the two sub-stages of dispatch: the key stage never sizes the
+    # dictionary, the launch is the step's call with nothing around it
+    assert journey.keying(None, "pq", 8, None) is tracing.NOOP
+    called = []
+    assert launch_step(lambda st, *a: called.append((st, a)) or "out",
+                       "state", 1, query="pq", jr=None) == "out"
+    assert called == [("state", (1,))]
     for i in range(3):
         h.send(["A", i])
     hists = rt.app_context.telemetry.snapshot().get("histograms", {})

@@ -25,7 +25,8 @@ RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "batch", "meta_pull_ms", "pull_ms", "rows_out", "rows_padded",
              "route_prep_ms", "route_pieces", "shard_rows_max",
              "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
-             "state_bytes", "state_slots"}
+             "state_bytes", "state_slots", "key_ms", "launch_ms",
+             "h2d_bytes"}
 FLUSH_KEYS = ("flush_rows", "timer_steps")
 ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
               "shard_capacity")
@@ -488,6 +489,32 @@ def test_a_split_batch_says_so_in_its_journey():
     assert sum(int(p["__valid__"].sum()) for p in out.pulled[1:]) == 16
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_split_batch_launches_once_a_piece_and_keeps_the_sum(depth):
+    """Each piece of a split batch is a ``siddhi.launch`` of its own
+    under the batch's id; the journey keeps their sum, also where the
+    first piece's emit finished it before the second was launched."""
+    m, _rt, h, _out = _routed(depth, rows_per_shard=8)     # quota 2 a pair
+    journey.enable()
+    TRACER.start()
+    h.send_columns({"k": np.array(["a"] * 16, object), "v": np.arange(16)})
+    (rec,) = journey.ring()
+    events = TRACER.stop()["traceEvents"]
+    journey.disable()
+    m.shutdown()
+    launches = [e for e in events if e["name"] == "launch"]
+    (step,) = [e for e in events if e["name"] == "query.step"]
+    assert rec["route_pieces"] == len(launches) == 2
+    for e in launches:
+        assert e["args"]["batch"] == rec["batch"]
+        assert e["args"]["query"] == "pq" and e["args"]["h2d_bytes"] > 0
+        assert step["ts"] <= e["ts"] \
+            and e["ts"] + e["dur"] <= step["ts"] + step["dur"]
+    assert rec["launch_ms"] == pytest.approx(
+        sum(e["dur"] for e in launches) / 1e3, rel=1e-3)
+    assert rec["h2d_bytes"] == sum(e["args"]["h2d_bytes"] for e in launches)
+
+
 def test_off_a_routed_query_leaves_no_span_and_no_journey(tmp_path):
     m, _rt, h, out = _routed()
     assert not spans_on()
@@ -498,6 +525,188 @@ def test_off_a_routed_query_leaves_no_span_and_no_journey(tmp_path):
     m.shutdown()
     assert len(out.pulled) == 2 and _engine_spans(tmp_path) == {}
     assert journey.ring() == ring_before
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_key_and_launch_nest_in_the_step_in_that_order(tmp_path, depth):
+    """The two sub-stages of dispatch: ``siddhi.key`` then
+    ``siddhi.launch``, both inside the batch's ``siddhi.query.step`` and
+    under its id; the launch has closed before the meta pull opens; a
+    key-capacity growth is a child of the key span; the journey's
+    ``key_ms`` and ``launch_ms`` are those spans' durations, inside
+    ``dispatch_ms``."""
+    m = _manager(pipeline_depth=depth)
+    rt = m.create_siddhi_app_runtime(PARTITIONED)
+    rt.add_callback("O", Columns())
+    h = rt.get_input_handler("S")
+
+    def send(keys):
+        h.send_columns({"k": np.array([f"k{i}" for i in range(keys)], object),
+                        "v": np.arange(keys)})
+
+    send(10)
+    rt.start_trace(str(tmp_path))
+    send(10)
+    send(40)                        # 16 -> 64: grows, and compiles anew
+    ring = journey.ring()
+    rt.stop_trace()
+    m.shutdown()
+    (spans,) = _engine_spans(tmp_path).values()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp[2], []).append(sp)
+    steps, keys = by_name["siddhi.query.step"], by_name["siddhi.key"]
+    launches, pulls = by_name["siddhi.launch"], by_name["siddhi.meta_pull"]
+    assert len(steps) == len(keys) == len(launches) == len(pulls) == 2
+    for step, key, launch, pull, rec in zip(steps, keys, launches, pulls,
+                                            ring):
+        assert _inside(key, step) and _inside(launch, step)
+        assert key[1] <= launch[0] and launch[1] <= pull[0]
+        assert key[3]["batch"] == launch[3]["batch"] == step[3]["batch"] \
+            == pull[3]["batch"] == rec["batch"]
+        assert key[3]["query"] == launch[3]["query"] == "pq"
+        assert rec["key_ms"] == pytest.approx((key[1] - key[0]) / 1e6,
+                                              rel=0.2)
+        assert rec["launch_ms"] == pytest.approx(
+            (launch[1] - launch[0]) / 1e6, rel=0.2)
+        assert rec["key_ms"] + rec["launch_ms"] <= rec["dispatch_ms"]
+        assert rec["h2d_bytes"] == launch[3]["h2d_bytes"] > 0
+    assert [(k[3]["rows"], k[3]["keys"], k[3]["new_keys"]) for k in keys] \
+        == [(16, 10, 0), (64, 40, 30)]
+    (grow,) = by_name["siddhi.grow"]
+    assert _inside(grow, keys[1])
+
+
+def test_launch_counts_the_numpy_leaves_that_cross():
+    """``h2d_bytes`` / ``h2d_arrays``: the numpy columns handed to the
+    step and its clock; what is already on the device counts nothing."""
+    import jax.numpy as jnp
+
+    from siddhi_tpu.core.event import launch_step
+
+    m = _manager(fuse_fanout="false")
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)
+    seen = {}
+    _spy(rt.query_runtimes["q1"], seen)
+    h = rt.get_input_handler("S")
+    _send(h, 0)
+    journey.enable()
+    TRACER.start()
+    _send(h, 1)
+    (rec,) = [r for r in journey.ring() if r["queries"] == ["q1"]]
+    state = {"a": jnp.zeros(4), "b": (jnp.zeros(2), np.zeros(3, np.int32))}
+    out = launch_step(lambda st, cols, now: (st, len(cols)), state,
+                      {"x": np.zeros(8, np.int64), "y": jnp.ones(8)},
+                      np.int64(5), query="made")
+    events = {e["args"]["query"]: e["args"]
+              for e in TRACER.stop()["traceEvents"] if e["name"] == "launch"}
+    journey.disable()
+    m.shutdown()
+    assert out == (state, 2)
+    assert events["made"] == {
+        "query": "made", "h2d_bytes": 64 + 8 + 12, "h2d_arrays": 3,
+        "state_leaves": 3, "batch": None}
+    _step, (q_state, cols, now) = seen["q1"]
+    up = [v for v in list(cols.values()) + [now]
+          if isinstance(v, (np.ndarray, np.generic))]
+    assert len(up) == len(cols) + 1         # a sent batch is all numpy
+    assert events["q1"]["h2d_arrays"] == len(up)
+    assert events["q1"]["h2d_bytes"] == sum(v.nbytes for v in up) \
+        == rec["h2d_bytes"]
+    assert events["q1"]["state_leaves"] == len(
+        jax.tree_util.tree_leaves(q_state))
+    assert events["q1"]["batch"] == rec["batch"]
+
+
+def _nfa_round():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(PATTERN)
+    rt.add_callback("MatchStream", Columns())
+    keys = np.array(["x", "y"], object)
+
+    def send(t):
+        rt.get_input_handler("AStream").send_columns(
+            {"k": keys, "v": np.zeros(2)}, timestamps=np.full(2, t))
+        rt.get_input_handler("BStream").send_columns(
+            {"k": keys, "v": np.ones(2)}, timestamps=np.full(2, t + 1))
+
+    return m, send, "query.step", ["nfa"], 2
+
+
+def _join_round():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(JOIN)
+    rt.add_callback("J", Columns())
+    one = np.array(["a"], object)
+
+    def send(t):
+        rt.get_input_handler("L").send_columns({"k": one, "v": np.array([t])})
+        rt.get_input_handler("R").send_columns({"k": one, "w": np.array([t])})
+
+    return m, send, "query.step", ["jq"], 2
+
+
+def _fanout_round():
+    m = _manager()
+    rt = m.create_siddhi_app_runtime(TWO_QUERIES)     # fuses q1 and q2
+    rt.add_callback("O", Columns())
+    h = rt.get_input_handler("S")
+    return m, lambda t: _send(h, t), "fanout.step", ["q1", "q2"], 1
+
+
+@pytest.mark.parametrize("family", ["nfa", "join", "fanout"])
+def test_every_step_family_opens_key_and_launch(family):
+    """The NFA, join and fused fan-out runtimes dispatch through the same
+    two sub-stages: one ``key`` and one ``launch`` a step, in that order
+    inside the step's span, under the batch's id, and on the journey."""
+    m, send, outer, queries, steps = {
+        "nfa": _nfa_round, "join": _join_round,
+        "fanout": _fanout_round}[family]()
+    send(1_000)                     # compiles
+    journey.enable()
+    TRACER.start()
+    send(2_000)
+    ring = journey.ring()
+    events = [e for e in TRACER.stop()["traceEvents"] if e.get("ph") == "X"]
+    journey.disable()
+    m.shutdown()
+    outers = [e for e in events if e["name"] == outer]
+    keys = [e for e in events if e["name"] == "key"]
+    launches = [e for e in events if e["name"] == "launch"]
+    assert len(outers) == len(keys) == len(launches) == len(ring) == steps
+    for o, key, launch, rec in zip(outers, keys, launches, ring):
+        assert o["ts"] <= key["ts"] \
+            and key["ts"] + key["dur"] <= launch["ts"] \
+            and launch["ts"] + launch["dur"] <= o["ts"] + o["dur"]
+        assert key["args"]["batch"] == launch["args"]["batch"] \
+            == rec["batch"] == o["args"]["batch"]
+        assert rec["queries"] == queries and set(rec) == RING_KEYS
+        assert rec["key_ms"] == pytest.approx(key["dur"] / 1e3, rel=1e-3)
+        assert rec["launch_ms"] == pytest.approx(launch["dur"] / 1e3,
+                                                 rel=1e-3)
+        assert rec["h2d_bytes"] == launch["args"]["h2d_bytes"] > 0
+        assert launch["args"]["h2d_arrays"] > 0
+        assert launch["args"]["state_leaves"] > 0
+
+
+def test_off_the_launch_is_the_call_and_reads_no_argument(monkeypatch):
+    """Spans off: ``launch_step`` is ``step(state, *args)`` after one flag
+    check; no leaf is flattened, no attribute computed."""
+    from siddhi_tpu.core import event
+
+    def no_flatten(_tree):
+        raise AssertionError("an attribute was evaluated")
+
+    monkeypatch.setattr(jax.tree_util, "tree_leaves", no_flatten)
+    assert not spans_on()
+    got = event.launch_step(lambda st, a, b: (st, a + b), "state", 1, 2,
+                            query="q")
+    assert got == ("state", 3)
+    assert journey.keying(None, "q", 8, no_flatten) is journey.keying(
+        None, "q", 8, no_flatten)           # the shared no-op: never sized
+    TRACER.enabled = True
+    with pytest.raises(AssertionError, match="an attribute was evaluated"):
+        event.launch_step(lambda st: st, "state", query="q")
 
 
 def _spy(q, seen, key=None):
