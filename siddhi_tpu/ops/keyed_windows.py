@@ -34,12 +34,29 @@ the same way (``int64_words`` / ``int64_from_words``). A ``double`` has no
 bits to take on that chip (a pair of float32 there, and the compiler
 refuses to bitcast it), so a ``double`` column stays ONE float64 leaf and
 ONE two-plane write. The other keyed stages keep their int64 rings whole.
+
+How a ring write is LOWERED on that chip goes by the ring's size: staged
+whole in fast memory while small, swept through it in windows behind a
+sort of the compiler's own up to 108 M slots, and from 114.7 M slots up
+one update after another (85-93 ns each: 6.0 ms for 65,536 rows into
+``[131,072,000]``, the same for a 1-byte mask). Handed slots that ARE
+sorted and unique, and told so, it takes the windowed path at every size
+(1.9 ms there; PERF.md section 7). So ``KeyedLengthWindowStage`` gives
+every row a slot of its own (a row that is not written gets an
+out-of-range one, dropped) and ``_ring_write`` sorts each leaf's
+``(slot, word)`` pairs itself, one two-operand sort a leaf, as the
+compiler does where it sorts: sorts that share a key are MERGED by the
+compiler into one sort with every column as payload, which compiles in
+minutes (PR 28), so each sort's operands sit behind a barrier of their
+own. The writes are traced in ``siddhi.ring_write`` inside
+``siddhi.state``; the benchmark's ``step_ring_write_ms`` reads it.
 """
 
 from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -92,13 +109,34 @@ def _ring_read(ring, at):
     return ring[at]
 
 
+RING_WRITE_SCOPE = "siddhi.ring_write"
+_INT32_MAX = np.iinfo(np.int32).max
+
+
+def _plane_write(plane, slot, word):
+    """One ring leaf written at ``slot``. int32 slots are the caller's
+    word that they are unique (``KeyedLengthWindowStage.apply``): they and
+    their words are sorted here, behind a barrier that keeps this sort
+    apart from the other leaves' (module docstring), and the scatter is
+    told both. int64 slots (a ring beyond 31 bits of slots) are written
+    as they come."""
+    if slot.dtype != jnp.int32:
+        return plane.at[slot].set(word, mode="drop")
+    slot, word = lax.sort(lax.optimization_barrier((slot, word)),
+                          num_keys=1, is_stable=False)
+    return plane.at[slot].set(word, mode="drop", indices_are_sorted=True,
+                              unique_indices=True)
+
+
 def _ring_write(ring, slot, col):
-    """``ring.at[slot].set(col, mode="drop")``; a column held as words is
-    two one-operand 32-bit scatters at the same slots, each in place."""
-    if isinstance(ring, tuple):
-        return tuple(plane.at[slot].set(word, mode="drop") for plane, word
-                     in zip(ring, int64_words(col.astype(jnp.int64))))
-    return ring.at[slot].set(col, mode="drop")
+    """``ring.at[slot].set(col, mode="drop")`` leaf by leaf; a column held
+    as words is two one-operand 32-bit scatters at the same slots, each
+    in place."""
+    with jax.named_scope(RING_WRITE_SCOPE):
+        if isinstance(ring, tuple):
+            return tuple(_plane_write(plane, slot, word) for plane, word
+                         in zip(ring, int64_words(col.astype(jnp.int64))))
+        return _plane_write(ring, slot, col)
 
 
 def _per_key_layout(pk, valid_cur, num_keys: int):
@@ -184,9 +222,18 @@ class KeyedLengthWindowStage(WindowStage):
             expired[k] = jnp.where(from_batch, cols[k][batch_row], ring_v)
         expired[TS_KEY] = jnp.broadcast_to(now, (B,))  # LengthWindowProcessor:120
 
-        # write the last min(W, n_key) arrivals of each key (unique slots)
+        # write the last min(W, n_key) arrivals of each key (unique slots);
+        # a row that is not written gets an out-of-range slot of its own
+        # (dropped), so that every slot is unique and ``_ring_write`` may
+        # say so. That needs K*W + B slots in an int32: beyond it, the one
+        # shared out-of-range slot and the plain write.
         write = valid_cur & (occ >= counts[pk] - W)
-        slot = jnp.where(write, pk * W + seq % W, jnp.int64(K * W)).astype(jnp.int64)
+        at = pk * W + seq % W
+        if K * W + B <= _INT32_MAX:
+            slot = jnp.where(write, at, K * W + jnp.arange(B, dtype=jnp.int64)
+                             ).astype(jnp.int32)
+        else:
+            slot = jnp.where(write, at, jnp.int64(K * W))
         new_buf = {k: _ring_write(state["buf"][k], slot, cols[k]) for k in state["buf"]}
 
         # order base: original batch position (global under device routing,

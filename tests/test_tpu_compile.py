@@ -203,7 +203,8 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch, price):
     # emulated 64-bit value (two 32-bit planes), which gets no sorted path
     # on the chip: 71-96 ns an update against 5 (PERF.md, PR 26).
     capacity = avals[0]["sel"]["a0"].shape[1]
-    scatters = _scatters(compiled.as_text())
+    text = compiled.as_text()
+    scatters = _scatters(text)
     assert any(scope == "siddhi.state" for scope, _, _ in scatters)
     for scope, primitive, result in scatters:
         if scope == "siddhi.select":
@@ -214,7 +215,77 @@ def test_keyed_ring_step_compiles(one_chip, keys, batch, price):
             assert not result.startswith("("), (
                 f"a 64-bit histogram is back: {scope}/{primitive} -> {result}")
     _assert_int64_rings_are_word_leaves(
-        compiled.as_text(), rows, doubles=int(price == "double"))
+        text, rows, doubles=int(price == "double"))
+    _assert_ring_writes_are_told_their_slots(
+        text, rows, doubles=int(price == "double"))
+
+
+_RING_WRITE = "siddhi.ring_write"
+# what a ring write's fusion holds of VMEM says how it is lowered (PERF.md
+# section 7): windows of the ring passed through it, or nothing but the
+# updates, which then go one after another (85-93 ns each on the chip)
+_WINDOWED_VMEM, _SERIAL_VMEM = 16_359_424, 135_168
+_OPERANDS = re.compile(r" [\w-]+\((%[^)]*)\)")
+
+
+def _operands(definition):
+    """The operand names of an instruction's right-hand side."""
+    found = _OPERANDS.search(definition)
+    return found[1].split(", ") if found else []
+
+
+def _donated_leaf(defined, fusion_line):
+    """``(type, parameter number)`` of the parameter behind a ring write's
+    first operand, which the fusion aliases to its result: the donated
+    state's own leaf, alone or staged into fast memory (whole by a copy,
+    or in slices put together by a ``ConcatBitcast``), never a fresh copy
+    of the ring."""
+    assert '"aliasing_operands":{"lists":[{"indices":["0",' in fusion_line, (
+        fusion_line)
+    operand = _operands(fusion_line)[0]
+    while not (leaf := re.match(r"(\w+\[\d+\])\S* parameter\((\d+)\)",
+                                defined[operand])):
+        assert re.search(r" (?:copy|slice)-(?:done|start)\(|ConcatBitcast",
+                         defined[operand]), (operand, defined[operand])
+        operand = _operands(defined[operand])[0]
+    return leaf[1], leaf[2]
+
+
+def _sorts_behind(defined, name):
+    """The right-hand sides of the sorts that ``name`` is computed from."""
+    seen, todo, sorts = set(), [name], set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in defined:
+            continue
+        seen.add(name)
+        if " sort(" in defined[name]:
+            sorts.add(defined[name])
+        else:
+            todo += _operands(defined[name])
+    return sorts
+
+
+def _assert_ring_writes_are_told_their_slots(hlo_text, rows, doubles=0):
+    """Every ring leaf of the step (eleven: ``symbol``, ``price``, the
+    word leaves of ``volume`` and ``__ts__``, ``__pk__``, ``__gk__``,
+    three null masks) is written under ``siddhi.ring_write`` by a scatter
+    that is TOLD its slots are sorted and unique, behind a sort of its
+    own of (slot, word): two operands, three where the word is a
+    ``double``'s pair of planes. Sorts that share their key merge into one
+    with every column as payload unless each is kept apart, and that one
+    compiles in minutes (``ops/keyed_windows.py``; PERF.md, PR 28)."""
+    lines = hlo_text.splitlines()
+    writes = [line for line in lines if " scatter(" in line
+              and f'/{_RING_WRITE}/scatter"' in line]
+    assert len(writes) == 11, writes
+    for line in writes:
+        assert re.search(rf"= \(?\w+\[{rows}\]", line), line
+        assert "indices_are_sorted=true, unique_indices=true" in line, line
+    sorts = [line for line in lines if " sort(" in line
+             and f'/{_RING_WRITE}/sort"' in line]
+    assert sorted(len(_operands(line)) for line in sorts) == (
+        [2] * (11 - doubles) + [3] * doubles), sorts
 
 
 _X64_CALL = re.compile(r'custom_call_target="(X64SplitLow|X64SplitHigh|X64Combine)"')
@@ -236,31 +307,25 @@ def _assert_int64_rings_are_word_leaves(hlo_text, rows, doubles=0):
     ``doubles`` ring columns stays ONE two-plane write; nothing else
     under ``siddhi.state`` writes two planes."""
     lines = hlo_text.splitlines()
-    ring = [result for scope, primitive, result in _scatter_ops(hlo_text)
-            if scope == "siddhi.state" and primitive == "scatter"]
+    scatters = _scatter_ops(hlo_text)
+    ring = [result for scope, primitive, result in scatters
+            if scope == _RING_WRITE and primitive == "scatter"]
     assert [r for r in ring if r.startswith("(")] == [
         f"(f32[{rows}], f32[{rows}])"] * doubles, ring
     assert ring.count(f"u32[{rows}]") == 4, ring
+    assert not [s for s in scatters if s[0] == "siddhi.state"
+                and (s[2].startswith("(") or f"[{rows}]" in s[2])], scatters
     # the four fusions that hold those scatters, in the entry computation:
     # the ring operand of each is the state's own leaf, alone or staged
     # into fast memory by an asynchronous copy (a small ring), and is
     # aliased to the fusion's result
     defined = {m[1]: m[2] for m in map(_DEFINITION.search, lines) if m}
     word_writes = [line for line in lines if re.search(
-        rf'= u32\[{rows}\]\S* fusion\(.*/siddhi\.state/scatter"', line)]
+        rf'= u32\[{rows}\]\S* fusion\(.*/{_RING_WRITE}/scatter"', line)]
     assert len(word_writes) == 4, word_writes
-    leaves = set()
-    for line in word_writes:
-        assert '"aliasing_operands":{"lists":[{"indices":["0",' in line, line
-        operand = re.search(r" fusion\((%[\w.-]+)", line)[1]
-        while (move := re.match(r".* copy-(?:done|start)\((%[\w.-]+)\)",
-                                defined[operand])):
-            operand = move[1]
-        leaf = re.match(rf"u32\[{rows}\]\S* parameter\((\d+)\)",
-                        defined[operand])
-        assert leaf, (operand, defined[operand])
-        leaves.add(leaf[1])
-    assert len(leaves) == 4, leaves
+    leaves = {_donated_leaf(defined, line) for line in word_writes}
+    assert len(leaves) == 4 and {t for t, _ in leaves} == {f"u32[{rows}]"}, (
+        leaves)
     # (a ``double`` ring is a pair of planes to the compiler, which takes
     # it apart and puts it together itself: the ``doubles``' own calls)
     x64 = [line for line in lines if _X64_CALL.search(line)
@@ -271,6 +336,92 @@ def _assert_int64_rings_are_word_leaves(hlo_text, rows, doubles=0):
               if re.search(rf"= \(?\w+\[{rows}\]", line)
               and " fusion(" in line and "kind=kLoop" in line]
     assert not passes, passes
+
+
+_RING_SIZES = (655_360, 16_384_000, 131_072_000)
+
+
+def _ring_avals(one_chip, dtype, n, slot_dtype="int32", rows=65_536):
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return aval((n,), dtype), aval((rows,), slot_dtype), aval((rows,), dtype)
+
+
+@pytest.fixture(scope="module")
+def ring_writes(one_chip):
+    """ONE program: ``keyed_windows._ring_write`` of 65,536 updates into a
+    donated ``u32[n]`` and a donated ``pred[n]`` for the three ``n``, each
+    ring with slots and words of its own. (The six sorts share a
+    signature, so the program compiles in about what one ring takes.)"""
+    from siddhi_tpu.ops.keyed_windows import _ring_write
+
+    args = {f"{dtype}[{n}]": _ring_avals(one_chip, dtype, n)
+            for n in _RING_SIZES for dtype in ("uint32", "bool")}
+    rings, slots, words = ({k: v[i] for k, v in args.items()}
+                           for i in range(3))
+
+    def write(rings, slots, words):
+        return {k: _ring_write(rings[k], slots[k], words[k]) for k in rings}
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(write, donate_argnums=0).lower(
+        rings, slots, words).compile()
+    _report("six ring writes of 65,536 updates", compiled,
+            time.perf_counter() - t0)
+    return compiled.as_text()
+
+
+def _ring_write_fusion(hlo_text, result):
+    """(fusion line, {name: definition}) of the one fusion whose result is
+    a ring of type ``result`` and whose root is a scatter."""
+    lines = hlo_text.splitlines()
+    found = [line for line in lines if re.search(
+        rf'= {re.escape(result)}\S* fusion\(.*/scatter"', line)]
+    assert len(found) == 1, found
+    return found[0], {m[1]: m[2] for m in map(_DEFINITION.search, lines) if m}
+
+
+def _scoped_vmem(fusion_line):
+    return [int(size) for size in re.findall(
+        r'"size":"(\d+)"', re.search(
+            r'"used_scoped_memory_configs":\[([^\]]*)\]', fusion_line)[1])]
+
+
+# (f) the ring write alone, at a ring staged whole in VMEM, at cells 1 and
+# 4's ring (where the compiler sorts and windows by itself) and at cell
+# 6's (where, left alone, it goes update by update: the last case).
+@pytest.mark.parametrize("n", _RING_SIZES)
+@pytest.mark.parametrize("hlo_dtype", ["u32", "pred"])
+def test_a_ring_write_is_told_its_slots_and_windowed(ring_writes, hlo_dtype, n):
+    result = f"{hlo_dtype}[{n}]"
+    scatter, = [line for line in ring_writes.splitlines()
+                if re.search(rf"= {re.escape(result)}\S* scatter\(", line)]
+    assert f'/{_RING_WRITE}/scatter"' in scatter
+    assert "indices_are_sorted=true, unique_indices=true" in scatter
+    fusion, defined = _ring_write_fusion(ring_writes, result)
+    assert _donated_leaf(defined, fusion)[0] == result
+    sorts = set().union(*(_sorts_behind(defined, operand)
+                          for operand in _operands(fusion)[1:]))
+    assert [len(_operands(rhs)) for rhs in sorts] == [2], sorts
+    if n > 655_360:
+        assert _scoped_vmem(fusion) == [_WINDOWED_VMEM]
+
+
+def test_a_plain_write_of_cell_6s_ring_still_goes_update_by_update(one_chip):
+    """Why ``_ring_write`` sorts: left to the compiler, 65,536 updates
+    into ``u32[131,072,000]`` take the serial lowering (no sort, no flag,
+    135,168 B of VMEM). The day this reads 16,359,424 the compiler windows
+    such a ring by itself, and the sort and the flags can go."""
+    n = 131_072_000
+    text = jax.jit(
+        lambda ring, slot, word: ring.at[slot].set(word, mode="drop"),
+        donate_argnums=0).lower(
+            *_ring_avals(one_chip, "uint32", n, "int64")).compile().as_text()
+    fusion, defined = _ring_write_fusion(text, f"u32[{n}]")
+    assert _donated_leaf(defined, fusion) == (f"u32[{n}]", "0")
+    assert " sort(" not in text and "indices_are_sorted=true" not in text
+    assert _scoped_vmem(fusion) == [_SERIAL_VMEM]
 
 
 _TUMBLING = """
@@ -488,23 +639,24 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
 # alone (PR 33: a new scope, a new growth path) proves it here; a PR that
 # means to change one replaces that cell's digests with what this test
 # prints (PR 32 printed the first five: PERF.md section 6; PR 34 changed
-# the keyed ring's layout, so cells 1, 4 and 6, and only they, are new).
+# the keyed ring's layout and PR 36 how its writes are handed their slots,
+# so cells 1, 4 and 6, and only they, were new each time).
 _STEP_SHA256 = {
     "partition_len1k_10k.hot20_bulk": [
-        "e03e073b1f88ecb8b345620c214f591d0517fdb7d5ab4fdb713db672d2b9b537"],
+        "86d87d364886479770fdb83acfff6b632f3dacf2d01408b251fd6735f26b8ef5"],
     "groupby_len1k_10k.uniform_bulk": [
         "7a62b53a00ec4f8fb6467bcbe998ae7b46666ea6f20ddf5ad627fcdc40ccafe5"],
     "pattern_ab_10k.rounds_bulk": [
         "58f6255a51ee3dbd38c347f8e70ad0e764a036bc46c0b5c2c85f840f77780b56",
         "dd60d4459a94eed6d147e4df268c4c98930b9226b1567827d7921905062b4166"],
     "partition_len1k_40k.hot20_bulk_x4": [
-        "bbea5c96eb4be8b49b7d5bd3ee68567f48b412b8229a18da9c61f4452fb1d83b"],
+        "df738d0bcbb69dd9e145d99e09d81ad42167bdda9d6fcf1c87015b04a09bd180"],
     "timebatch_1s_10k.hot20_tick250": [
         "a055e7ea31b399f49b871ee11dec9001f9402c7dfb5695fb840daf5a3282ce43",
         "a46ee91e0411de179849c770668c3f573c42a301e26b436dd40114fe56361f27"],
     "partition_len1k_100k.hot20_bulk_100k": [       # two key capacities
-        "9c9a4107cc075b0b77c0aca81a53a99927264de89248df0e0f5496fcb628a52b",
-        "de1a9a71831a91ea8d1e6a6698508a61f83fc8c638f56c69c71dd1b58431e6f0"],
+        "2d061f2d72cb319f4de50dd40f4c8a0075df794be5aa10e3098f3278f9ee98c1",
+        "3230499bcb4279486e82f49244eb532341a659110469bac57244cd01f07dba8c"],
 }
 
 
