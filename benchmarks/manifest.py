@@ -1,12 +1,14 @@
 """Finds everything a cell needs by NAME: the cell in ``BENCHMARK.json``,
-its configuration file, its traffic file, its reference module and the
-reader of each of its per-layer metrics. A later PR adds a cell, a
-configuration, a traffic mix or a metric by adding files and an entry in
+its configuration file, its traffic file, the feed kind that reads the
+traffic file, its reference module and the reader of each of its
+per-layer metrics. A later PR adds a cell, a configuration, a traffic
+mix, a metric or a feed kind by adding files and an entry in
 ``BENCHMARK.json``; nothing here lists them.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -24,8 +26,10 @@ def _load_json(path):
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
 def _module(path):
-    """A module loaded from its file: a reference or a metric reader."""
+    """A module loaded from its file, once: a reference, a metric reader
+    or a feed kind."""
     if not os.path.isfile(path):
         raise ManifestError(f"no such file: {os.path.relpath(path, ROOT)}")
     name = "benchmarks._by_name." + os.path.relpath(
@@ -34,6 +38,20 @@ def _module(path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def feed_kind(kind: str, root: str = ROOT):
+    """The module of the feed kind a traffic file names:
+    ``benchmarks/feeds/<kind>.py`` (``generator.py`` has the contract)."""
+    there = os.path.join(root, "benchmarks", "feeds")
+    path = os.path.join(there, f"{kind}.py")
+    if not os.path.isfile(path):
+        have = sorted(f[:-3] for f in os.listdir(there)
+                      if f.endswith(".py") and not f.startswith("_"))
+        raise ManifestError(
+            f"no feed kind {kind!r}: there is no "
+            f"{os.path.relpath(path, root)}; benchmarks/feeds has {have}")
+    return _module(path)
 
 
 class Cell:
@@ -59,6 +77,9 @@ class Cell:
             here, "traffic", self.entry["traffic"] + ".json"))
         self.family = _module(os.path.join(
             here, "references", self.config["family"] + ".py"))
+        # a missing kind fails here, where a missing reference does, before
+        # JAX is imported; ``generator.make_feed`` is the one that uses it
+        feed_kind(self.traffic["kind"], root)
 
     def sized(self, rehearsal: bool):
         """(sizes, traffic) as run: the two files' own, or with their

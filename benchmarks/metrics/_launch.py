@@ -27,7 +27,7 @@ to the last one's end, as ``tracereduce.reduce`` takes it):
 A trace of a program without the span (the parent of PR 35) gives
 ``None``: nothing to read, nothing returned. The file is read once a
 process with ``jax.profiler.ProfileData``, one pass, beside the passes
-of ``tracereduce``, ``_spans``, ``_route``, ``_flush`` and ``_ring_pass``.
+of ``tracereduce``, ``_spans``, ``_route`` and ``_flush``.
 """
 
 from __future__ import annotations

@@ -80,15 +80,21 @@ def _sample_batches(check, seed, n_setup, n_sent):
 
 def _delivered(config, collector, rt, feed):
     """What the callback received, by the role each column plays in the
-    reference. The key column comes as dictionary ids: decoded through
-    the app's dictionary, as ``decode_events`` does, to key indices."""
+    reference. A column of strings comes as dictionary ids: decoded through
+    the app's dictionary, as ``decode_events`` does, and the feed's table
+    of that column to the indices the feed drew (-1: a string it never
+    sent). Which roles are strings, and of which table, the configuration
+    says in ``output["strings"]``; one that says nothing has the key."""
+    out = config["output"]
+    strings = out.get("strings", {"key": "key"})
+    decode = rt.app_context.string_dictionary.decode
     got = {}
-    for role, attr in config["output"]["columns"].items():
+    for role, attr in out["columns"].items():
         col = collector.column(attr)
-        if role == "key":
+        if role in strings:
             ids = col.astype(np.int64)
-            index_of = {s: i for i, s in enumerate(feed.names.tolist())}
-            decode = rt.app_context.string_dictionary.decode
+            index_of = {s: i for i, s in
+                        enumerate(feed.tables[strings[role]].tolist())}
             table = np.array([index_of.get(decode(i), -1)
                               for i in range(int(ids.max(initial=-1)) + 1)],
                              np.int64)
@@ -298,7 +304,7 @@ def _run(args, cell, device, peak, cache_dir, errors, meter, mark,
     # counts, and the clock stops when the last of them is answered (a
     # count of whole batches at a fixed close would step by one batch)
     answered = [i for i in in_window if np.isfinite(done[i])]
-    events_done = feed.rows * len(answered)
+    events_done = sum(len(feed.batch(i).keys) for i in answered)
     window_s = max((done[i] for i in answered),
                    default=t0 + args.seconds) - t0
     values = {"events_per_s": events_done / window_s, "setup_s": setup_s}

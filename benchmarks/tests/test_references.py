@@ -75,7 +75,7 @@ def test_pattern_equals_its_loop(seed):
 
 @pytest.mark.parametrize("workload", [
     "partition_len1k_10k.hot20_bulk", "groupby_len1k_10k.uniform_bulk",
-    "pattern_ab_10k.rounds_bulk"])
+    "pattern_ab_10k.rounds_bulk", "partition_len1k_10k.zipf_scrambled"])
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_the_control_fails_the_comparison(workload, seed):
     """One precision below the configuration's, at the most favourable

@@ -1,5 +1,5 @@
 """``step_ring_write_ms``: the device's time in ``siddhi.ring_write``, the
-keyed length window's ring writes, in the three partition cells.
+keyed length window's ring writes, in the four partition cells.
 
 On a cut of a real trace: one step of ``partition_len1k_100k.hot20_bulk_100k``
 on one v5e chip from PR 36's traced chip run (key capacity 131,072, eleven
@@ -20,7 +20,8 @@ from benchmarks import manifest
 HERE = os.path.dirname(os.path.abspath(__file__))
 NAME = "step_ring_write_ms"
 CELLS = ["partition_len1k_10k.hot20_bulk", "partition_len1k_40k.hot20_bulk_x4",
-         "partition_len1k_100k.hot20_bulk_100k"]
+         "partition_len1k_100k.hot20_bulk_100k",
+         "partition_len1k_10k.zipf_scrambled"]
 
 
 def _cut(name):
@@ -76,7 +77,6 @@ def test_a_real_trace_of_cell_6_gives_one_steps_ring_writes():
 
 
 @pytest.mark.parametrize("cut", ["trace_v5e_partition_cut.json.gz",
-                                 "trace_v5e_ring_pass_cut.json.gz",
                                  "trace_v5e_x4_route_cut.json.gz",
                                  "trace_v5e_tumbling_cut.json.gz"])
 def test_an_older_cut_names_no_ring_write(cut):
