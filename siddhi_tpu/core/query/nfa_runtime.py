@@ -37,15 +37,18 @@ from siddhi_tpu.ops.nfa import NFAStage
 from siddhi_tpu.query_api.definitions import StreamDefinition
 
 
-def _nfa_meta(out: dict, new_nfa: dict, ins_on: bool) -> dict:
-    """Append the ``nfa_runs`` instrument lane (live partial-match
-    slots) behind the packed meta prefix — computed from state the step
-    already holds (``observability/instruments.py``); inert when the
+def _nfa_meta(out: dict, new_nfa: dict, ins_on: bool, rounds) -> dict:
+    """Append the instrument lanes behind the packed meta prefix:
+    ``nfa_rounds`` always (the generic engine's round count; 0 where a
+    step ran no round loop), then ``nfa_runs`` (live partial-match
+    slots, from state the step already holds:
+    ``observability/instruments.py``), inert when the
     ``profile_device_instruments`` knob is off."""
+    lanes = [out["__meta__"], jnp.asarray(
+        0 if rounds is None else rounds, jnp.int64).reshape(1)]
     if ins_on:
-        out["__meta__"] = jnp.concatenate(
-            [out["__meta__"],
-             jnp.sum(new_nfa["active"], dtype=jnp.int64).reshape(1)])
+        lanes.append(jnp.sum(new_nfa["active"], dtype=jnp.int64).reshape(1))
+    out["__meta__"] = jnp.concatenate(lanes)
     return out
 
 
@@ -139,13 +142,28 @@ class NFAQueryRuntime(QueryRuntime):
             self._timer_step = None
 
     def _step_instrument_slots(self):
-        """Every NFA step (per-stream and timer sweep) appends the live
-        active-run count — see ``_nfa_meta``."""
+        """Every NFA step (per-stream and timer sweep) appends the round
+        count of the generic engine and, with the instruments on, the
+        live active-run count — see ``_nfa_meta``."""
         from siddhi_tpu.observability.instruments import Slot
 
-        if not self._instruments_on():
-            return []
-        return [Slot("nfa_runs")]
+        slots = [Slot("nfa_rounds")]
+        if self._instruments_on():
+            slots.append(Slot("nfa_runs"))
+        return slots
+
+    def _record_instrument(self, slot, vals, telemetry: bool,
+                           jr=None) -> None:
+        """The round count lands on the batch's journey (``nfa_rounds``)
+        and in the counter ``nfa.<query>.rounds`` whether or not the
+        instruments are on; every lane then as any other."""
+        if slot.name == "nfa_rounds":
+            rounds = int(vals[0])
+            self.app_context.telemetry.count(f"nfa.{self.name}.rounds",
+                                             rounds)
+            if jr is not None:
+                jr.nfa_rounds = rounds
+        super()._record_instrument(slot, vals, telemetry, jr)
 
     def _instrument_capacity(self, name):
         if name == "nfa_runs":
@@ -253,11 +271,13 @@ class NFAQueryRuntime(QueryRuntime):
             out_cols = dict(out_cols)
             overflow = out_cols.pop("__overflow__", None)
             notify = out_cols.pop("__notify__", None)
+            rounds = out_cols.pop("__rounds__", None)
             if strrank is not None:
                 out_cols[STR_RANK] = strrank
             return _select_and_meta(
                 state, new_nfa, out_cols, overflow, notify, ctx,
-                batch_rows=cols[VALID_KEY].shape[0] if compact else None)
+                batch_rows=cols[VALID_KEY].shape[0] if compact else None,
+                rounds=rounds)
 
         return step
 
@@ -284,7 +304,7 @@ class NFAQueryRuntime(QueryRuntime):
         ins_on = self._instruments_on()
 
         def tail(state, new_nfa, out_cols, overflow, notify, ctx,
-                 batch_rows=None):
+                 batch_rows=None, rounds=None):
             padded = None
             if split:
                 out, new_sel = out_cols, state["sel"]
@@ -308,7 +328,7 @@ class NFAQueryRuntime(QueryRuntime):
                 if notify is not None:
                     out["__notify__"] = notify
             with jax.named_scope(META_SCOPE):
-                out = _nfa_meta(pack_meta(out), new_nfa, ins_on)
+                out = _nfa_meta(pack_meta(out), new_nfa, ins_on, rounds)
             if padded is not None:
                 # the meta counted the padded mask: it says more than the
                 # width where the rows do not fit
@@ -378,6 +398,9 @@ class NFAQueryRuntime(QueryRuntime):
             if self._state is None:
                 self._state = self._init_state()
             force_generic = self._host_hard_batch(stream_id, cols)
+            # 0 for the two-step kernel: the series exists either way
+            self.app_context.telemetry.count(
+                f"nfa.{self.name}.generic_steps", int(force_generic))
             jit_key = (f"query.{self.name}.nfa.{stream_id}"
                        + (".generic" if force_generic else ""))
             step = self._steps.get((stream_id, force_generic))
@@ -577,7 +600,7 @@ class NFAQueryRuntime(QueryRuntime):
             if jr is not None:
                 jr.pre_drain(journey.ready_of(meta))
             meta = self._pull_meta(meta, jr)
-            self.decode_meta_suffix(meta)
+            self.decode_meta_suffix(meta, jr)
             overflow, notify, size_hint = int(meta[0]), int(meta[1]), int(meta[2])
         else:
             ovf = out_host.pop("__overflow__", None)

@@ -80,6 +80,9 @@ TELEMETRY_PREFIXES = (
                      # gauges, key-capacity growths counter
                      # (core/query/runtime.py, parallel/mesh.py; generic
                      # gauge and counter families)
+    "nfa",           # a pattern or sequence query's generic-engine
+                     # steps and their rounds (core/query/nfa_runtime.py;
+                     # generic counter family)
     "autopilot",     # closed-loop controller: mode gauge, tick/freeze
                      # counters, per-(knob,direction,reason) decision
                      # counters (siddhi_tpu/autopilot/ ->
@@ -98,6 +101,7 @@ DEVICE_SLOTS = (
     "win_fill",        # window ring live rows (keyed: hottest key)
     "groups",          # distinct group keys touched by the batch
     "nfa_runs",        # live NFA partial-match slots
+    "nfa_rounds",      # the generic NFA engine's rounds for the batch
     "shard_rows",      # per-shard routed rows (device-routed exchange)
     "route_residual",  # receive capacity left on the fullest shard
     "fill.left",       # join build directory fill per partition

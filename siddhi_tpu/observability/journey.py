@@ -98,6 +98,12 @@ send that crosses the boundary advances the clock, before that send's own
 step: the TIMER step's journey and span carry that send's ``batch`` id,
 so the fields add up per send.
 
+A pattern or sequence query (``core/query/nfa_runtime.py``) stamps one
+more, ``None`` for every other query: ``nfa_rounds``, the rounds the
+generic NFA engine's loop ran for the batch (the most rows one partition
+key had in it; 0 where the two-step kernel ran), from the meta's
+``nfa_rounds`` lane at the drain.
+
 Every query stamps three more: ``grow_ms``, the ``siddhi.grow`` spans
 of the key-capacity growths this batch forced (``None``: none), and
 ``state_bytes`` / ``state_slots``, the state its step left on the device
@@ -280,7 +286,7 @@ class Journey:
                  "route_prep_ms", "route_pieces", "shard_rows_max",
                  "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
                  "state_bytes", "state_slots", "key_ms", "launch_ms",
-                 "h2d_bytes", "_rec")
+                 "h2d_bytes", "nfa_rounds", "_rec")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -310,6 +316,7 @@ class Journey:
         self.key_ms: Optional[float] = None
         self.launch_ms: Optional[float] = None
         self.h2d_bytes: Optional[int] = None
+        self.nfa_rounds: Optional[int] = None
         self._rec: Optional[dict] = None     # the ring record, once finished
 
     # one journey object is stamped on the batch at pack time; each
@@ -483,6 +490,9 @@ class Journey:
                 "key_ms": self.key_ms,
                 "launch_ms": self.launch_ms,
                 "h2d_bytes": self.h2d_bytes,
+                # a pattern or sequence query's: the generic engine's
+                # rounds for this batch; None for every other
+                "nfa_rounds": self.nfa_rounds,
             }
             _RING.append(self._rec)
 

@@ -74,6 +74,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -102,6 +103,9 @@ from siddhi_tpu.query_api.execution import (
 from siddhi_tpu.query_api.expressions import Expression, Variable
 
 CURRENT, EXPIRED, TIMER, RESET = 0, 1, 2, 3
+# the generic engine's round loop (a ``while_loop`` inside the state
+# scope): the benchmark's ``step_nfa_rounds_ms`` reads it from a trace
+ROUNDS_SCOPE = "siddhi.nfa_rounds"
 ANY_MAX = 2 ** 30
 # numpy on purpose: jnp scalars at module level initialize the backend
 # at import (graftlint R1 — the force_host_devices breaker class)
@@ -1994,7 +1998,9 @@ class NFAStage:
                   [state[g] for g in self.scope_cols],
                   out_valid0, out_caps0, out_ts0, state["nfa_overflow"])
 
-        res = lax.while_loop(lambda c: c[0] < n_rounds, round_body, carry0)
+        with jax.named_scope(ROUNDS_SCOPE):
+            res = lax.while_loop(lambda c: c[0] < n_rounds, round_body,
+                                 carry0)
         (_r, active2, stepi2, bits2, vbits2, sts2, adl_2, adl2_2, capdone2,
          consumed2, armed2, caps2, scs2, out_valid, out_caps, out_ts,
          overflow2) = res
@@ -2012,6 +2018,7 @@ class NFAStage:
         out = self._flatten_out(out_valid, out_caps, out_ts, ts, cols, pk, B)
         out["__overflow__"] = (overflow2 > state["nfa_overflow"]).astype(jnp.int32)
         out["__notify__"] = _notify_of(self._next_deadline(new_state))
+        out["__rounds__"] = n_rounds.astype(jnp.int32)
         return new_state, out
 
     # ................................................ scheduler TIMER sweep

@@ -516,6 +516,40 @@ def test_nfa_steps_compile(one_chip, keys, batch):
         _report(f"C nfa {stream} step B={batch} K=16384", compiled, seconds)
 
 
+# (b2) the sequence cell's query: the generic round engine (a
+# ``while_loop`` in ``siddhi.nfa_rounds``) at K = 16,384 and S = 32.
+@pytest.mark.parametrize("keys,batch", [
+    (10_000, 1_024),
+    pytest.param(10_000, 16_384, marks=pytest.mark.slow),
+])
+def test_sequence_step_compiles(one_chip, keys, batch):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "sequence_peak_10k.json"), encoding="utf-8") as f:
+        app = json.load(f)["app"]
+    manager = SiddhiManager()
+    rt = manager.create_siddhi_app_runtime(app)
+    steps = rt.query_runtimes["seq"]._steps = _SpyingSteps()
+    names = np.array([f"S{i}" for i in range(keys)], dtype=object)
+    for c0 in range(0, keys + batch, batch):
+        k = names[(c0 + np.arange(batch)) % keys]
+        rt.get_input_handler("Stream1").send_columns(
+            {"symbol": k, "price": 30.0 + np.arange(batch) % 3.0,
+             "volume": np.ones(batch, np.int32)},
+            timestamps=c0 + np.arange(batch, dtype=np.int64))
+    manager.shutdown()
+    assert sorted(steps.seen) == [("Stream1", True)]   # the generic engine
+    step, avals = steps.seen[("Stream1", True)]
+    assert avals[0]["nfa"]["active"].shape == (16_384, 32)
+    compiled, seconds = _compile_for(one_chip, step, avals)
+    _report(f"sequence step B={batch} K=16384", compiled, seconds)
+    text = compiled.as_text()
+    assert " while(" in text and "siddhi.nfa_rounds" in text
+
+
 # (d) the routed path on a mesh of the four described chips.
 def _mesh4(topo):
     from siddhi_tpu.parallel.mesh import KEY_AXIS
@@ -640,15 +674,17 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
 # means to change one replaces that cell's digests with what this test
 # prints (PR 32 printed the first five: PERF.md section 6; PR 34 changed
 # the keyed ring's layout and PR 36 how its writes are handed their slots,
-# so cells 1, 4 and 6, and only they, were new each time).
+# so cells 1, 4 and 6, and only they, were new each time; the NFA steps'
+# meta gained the generic engine's round count, so cell 3's two are new
+# beside the sequence cell's one).
 _STEP_SHA256 = {
     "partition_len1k_10k.hot20_bulk": [
         "86d87d364886479770fdb83acfff6b632f3dacf2d01408b251fd6735f26b8ef5"],
     "groupby_len1k_10k.uniform_bulk": [
         "7a62b53a00ec4f8fb6467bcbe998ae7b46666ea6f20ddf5ad627fcdc40ccafe5"],
     "pattern_ab_10k.rounds_bulk": [
-        "58f6255a51ee3dbd38c347f8e70ad0e764a036bc46c0b5c2c85f840f77780b56",
-        "dd60d4459a94eed6d147e4df268c4c98930b9226b1567827d7921905062b4166"],
+        "11d9db3fc361df5d1f8ea954cf72229d348da1110a11b0e5fe75813abc75c7d7",
+        "301ba1d07cb4aaebf8ac69d9d09344a99e914cb640b7bd53ae6b73e833fe103a"],
     "partition_len1k_40k.hot20_bulk_x4": [
         "df738d0bcbb69dd9e145d99e09d81ad42167bdda9d6fcf1c87015b04a09bd180"],
     "timebatch_1s_10k.hot20_tick250": [
@@ -657,6 +693,8 @@ _STEP_SHA256 = {
     "partition_len1k_100k.hot20_bulk_100k": [       # two key capacities
         "2d061f2d72cb319f4de50dd40f4c8a0075df794be5aa10e3098f3278f9ee98c1",
         "3230499bcb4279486e82f49244eb532341a659110469bac57244cd01f07dba8c"],
+    "sequence_peak_10k.hot20_walk16k": [
+        "094cf38bc6c3f58688c74f8a4fdbce37d09a685f637b49b60369b4cfce98f869"],
 }
 
 
