@@ -37,19 +37,29 @@ from siddhi_tpu.ops.nfa import NFAStage
 from siddhi_tpu.query_api.definitions import StreamDefinition
 
 
-def _nfa_meta(out: dict, new_nfa: dict, ins_on: bool, rounds) -> dict:
+def _nfa_meta(out: dict, new_nfa: dict, ins_on: bool, rounds,
+              tiles) -> dict:
     """Append the instrument lanes behind the packed meta prefix:
-    ``nfa_rounds`` always (the generic engine's round count; 0 where a
-    step ran no round loop), then ``nfa_runs`` (live partial-match
+    ``nfa_rounds`` always (the generic engine's round count in the low
+    32 bits, its loop's tile count in the high 32: ``_split_rounds``; 0
+    where a step ran no loop, so the two-step and timer programs carry
+    the lane they always had), then ``nfa_runs`` (live partial-match
     slots, from state the step already holds:
     ``observability/instruments.py``), inert when the
     ``profile_device_instruments`` knob is off."""
-    lanes = [out["__meta__"], jnp.asarray(
-        0 if rounds is None else rounds, jnp.int64).reshape(1)]
+    lane = 0 if rounds is None else (
+        rounds.astype(jnp.int64)
+        + jnp.left_shift(tiles.astype(jnp.int64), 32))
+    lanes = [out["__meta__"], jnp.asarray(lane, jnp.int64).reshape(1)]
     if ins_on:
         lanes.append(jnp.sum(new_nfa["active"], dtype=jnp.int64).reshape(1))
     out["__meta__"] = jnp.concatenate(lanes)
     return out
+
+
+def _split_rounds(lane: int):
+    """The ``nfa_rounds`` lane as (rounds, tiles)."""
+    return lane & 0xFFFFFFFF, lane >> 32
 
 
 class StreamProxy(Receiver):
@@ -154,15 +164,20 @@ class NFAQueryRuntime(QueryRuntime):
 
     def _record_instrument(self, slot, vals, telemetry: bool,
                            jr=None) -> None:
-        """The round count lands on the batch's journey (``nfa_rounds``)
-        and in the counter ``nfa.<query>.rounds`` whether or not the
-        instruments are on; every lane then as any other."""
+        """The round and tile counts land on the batch's journey
+        (``nfa_rounds``, ``nfa_tiles``) and in the counters
+        ``nfa.<query>.rounds`` / ``.tiles`` whether or not the instruments
+        are on; every lane then as any other (``nfa_rounds`` as the
+        rounds alone)."""
         if slot.name == "nfa_rounds":
-            rounds = int(vals[0])
-            self.app_context.telemetry.count(f"nfa.{self.name}.rounds",
-                                             rounds)
+            rounds, tiles = _split_rounds(int(vals[0]))
+            tel = self.app_context.telemetry
+            tel.count(f"nfa.{self.name}.rounds", rounds)
+            tel.count(f"nfa.{self.name}.tiles", tiles)
             if jr is not None:
                 jr.nfa_rounds = rounds
+                jr.nfa_tiles = tiles
+            vals = np.asarray([rounds], np.int64)
         super()._record_instrument(slot, vals, telemetry, jr)
 
     def _instrument_capacity(self, name):
@@ -272,12 +287,13 @@ class NFAQueryRuntime(QueryRuntime):
             overflow = out_cols.pop("__overflow__", None)
             notify = out_cols.pop("__notify__", None)
             rounds = out_cols.pop("__rounds__", None)
+            tiles = out_cols.pop("__tiles__", None)
             if strrank is not None:
                 out_cols[STR_RANK] = strrank
             return _select_and_meta(
                 state, new_nfa, out_cols, overflow, notify, ctx,
                 batch_rows=cols[VALID_KEY].shape[0] if compact else None,
-                rounds=rounds)
+                rounds=rounds, tiles=tiles)
 
         return step
 
@@ -304,7 +320,7 @@ class NFAQueryRuntime(QueryRuntime):
         ins_on = self._instruments_on()
 
         def tail(state, new_nfa, out_cols, overflow, notify, ctx,
-                 batch_rows=None, rounds=None):
+                 batch_rows=None, rounds=None, tiles=None):
             padded = None
             if split:
                 out, new_sel = out_cols, state["sel"]
@@ -328,7 +344,8 @@ class NFAQueryRuntime(QueryRuntime):
                 if notify is not None:
                     out["__notify__"] = notify
             with jax.named_scope(META_SCOPE):
-                out = _nfa_meta(pack_meta(out), new_nfa, ins_on, rounds)
+                out = _nfa_meta(pack_meta(out), new_nfa, ins_on, rounds,
+                                tiles)
             if padded is not None:
                 # the meta counted the padded mask: it says more than the
                 # width where the rows do not fit

@@ -98,11 +98,12 @@ send that crosses the boundary advances the clock, before that send's own
 step: the TIMER step's journey and span carry that send's ``batch`` id,
 so the fields add up per send.
 
-A pattern or sequence query (``core/query/nfa_runtime.py``) stamps one
+A pattern or sequence query (``core/query/nfa_runtime.py``) stamps two
 more, ``None`` for every other query: ``nfa_rounds``, the rounds the
-generic NFA engine's loop ran for the batch (the most rows one partition
-key had in it; 0 where the two-step kernel ran), from the meta's
-``nfa_rounds`` lane at the drain.
+generic NFA engine ran for the batch (the most rows one partition key
+had in it), and ``nfa_tiles``, the iterations its loop took to run them
+(a round wider than a tile takes several); both 0 where the two-step
+kernel ran, both from the meta's ``nfa_rounds`` lane at the drain.
 
 Every query stamps three more: ``grow_ms``, the ``siddhi.grow`` spans
 of the key-capacity growths this batch forced (``None``: none), and
@@ -286,7 +287,7 @@ class Journey:
                  "route_prep_ms", "route_pieces", "shard_rows_max",
                  "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
                  "state_bytes", "state_slots", "key_ms", "launch_ms",
-                 "h2d_bytes", "nfa_rounds", "_rec")
+                 "h2d_bytes", "nfa_rounds", "nfa_tiles", "_rec")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -317,6 +318,7 @@ class Journey:
         self.launch_ms: Optional[float] = None
         self.h2d_bytes: Optional[int] = None
         self.nfa_rounds: Optional[int] = None
+        self.nfa_tiles: Optional[int] = None
         self._rec: Optional[dict] = None     # the ring record, once finished
 
     # one journey object is stamped on the batch at pack time; each
@@ -491,8 +493,10 @@ class Journey:
                 "launch_ms": self.launch_ms,
                 "h2d_bytes": self.h2d_bytes,
                 # a pattern or sequence query's: the generic engine's
-                # rounds for this batch; None for every other
+                # rounds and its loop's tiles for this batch; None for
+                # every other
                 "nfa_rounds": self.nfa_rounds,
+                "nfa_tiles": self.nfa_tiles,
             }
             _RING.append(self._rec)
 

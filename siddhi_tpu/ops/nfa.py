@@ -20,10 +20,12 @@ fixed-capacity slot tensors:
 
 K = partition keys (1 when unpartitioned), S = slot capacity. One device
 step processes a whole batch: rows are grouped per key (`_per_key_layout`)
-and a ``lax.while_loop`` runs one *round* per same-key occurrence — rows in
-a round have distinct keys, so each round's slot updates are one parallel
-gather/scatter over every key at once. Pending-match scans across 10k keys
-become a single [B, S] mask computation.
+into *rounds*, round r holding each key's r-th row of the batch, so the
+rows of a round have distinct keys. A ``lax.while_loop`` runs every round
+as key-distinct tiles of at most W rows (``_tile_rows``: a share of the
+batch, never more than K), one iteration a tile: it gathers its keys'
+slot views [W, S], updates them in parallel and scatters them back, so a
+round costs what its own rows cost and not what the batch's B x S would.
 
 Semantics reproduced (reference file:line):
 - PATTERN keeps pending matches across non-matching events; SEQUENCE kills
@@ -113,6 +115,22 @@ FAR_FUTURE = np.int64(2 ** 62)
 # T0 sentinel for capture-less armed heads: within counts from the first
 # capture; 2**60 keeps T0 + within far below int64 overflow
 _T0_FAR = np.int64(2 ** 60)
+# the generic engine's tile: a loop iteration takes at most 1/_TILE_SHARE
+# of a batch's rows, and no fewer than _TILE_FLOOR where the batch and the
+# key capacity allow. The share was chosen on a TPU v5e at 16,384-row
+# batches over 16,384 keys: a batch's loop took 49.9 ms at W = 1,024, 54.6
+# at 2,048 and 77.8 at 4,096 (PERF.md, cell 8): an iteration costs about
+# 1.0 ms of its own there and a row 0.8 us, so the rows of a 128-row tile
+# cost a tenth of its iteration and narrower tiles only add iterations
+_TILE_SHARE = 16
+_TILE_FLOOR = 128
+
+
+def _tile_rows(B: int, K: int) -> int:
+    """W, the rows of one iteration of the generic engine's loop: never
+    wider than the batch, nor than the key capacity (a round holds at most
+    K distinct keys, so an unpartitioned pattern takes a row a tile)."""
+    return min(B, K, max(_TILE_FLOOR, B // _TILE_SHARE))
 
 
 # --------------------------------------------------------------------- plan
@@ -1277,18 +1295,55 @@ class NFAStage:
         return head_fast(state, cols)
 
     def _apply_stream_generic(self, stream_id: str, state: dict, cols: dict, ctx: dict):
-        """The generic per-round engine (see class docstring)."""
+        """The generic engine (see the module docstring): round r takes
+        every key's r-th CURRENT row of the batch, and runs as tiles of at
+        most W rows (``_tile_rows``), one ``while_loop`` iteration each.
+
+        Before the loop the batch's CURRENT rows are put in (round, key)
+        order and every column is gathered into it once. An iteration
+        takes the next W positions, cut at the end of their round: rows of
+        distinct keys, sorted by key. It gathers their keys' state views
+        ``[W, S]``, runs the round body over them, scatters the views back
+        at the keys (sorted, unique: the padding lanes carry the
+        out-of-range keys ``K + lane``) and writes the tile's
+        ``[W, S + 1]`` emissions at its positions of an output buffer.
+        Round r's tiles all run before round r + 1's, so each key meets
+        its rows in arrival order; the body reads nothing across rows.
+        After the loop one gather puts the emissions back in row order,
+        as ``_flatten_out`` expects them."""
         plan = self.plan
         S = plan.slots
         L = plan.last_step
         K = state["consumed"].shape[0]
         B = cols[VALID_KEY].shape[0]
-        ts = cols[TS_KEY]
+        W = _tile_rows(B, K)
         valid_cur = cols[VALID_KEY] & (cols[TYPE_KEY] == CURRENT)
         pk = jnp.clip(cols.get(PK_KEY, jnp.zeros(B, jnp.int32)).astype(jnp.int32), 0, K - 1)
 
-        _o, _i, occ, _c, _s = _per_key_layout(pk, valid_cur, K)
+        order, _i, occ, _c, _s = _per_key_layout(pk, valid_cur, K)
         n_rounds = jnp.max(jnp.where(valid_cur, occ, -1)) + 1
+        n_rows = jnp.sum(valid_cur, dtype=jnp.int32)
+        # (round, key) order: the key order, stably sorted by round; rows
+        # that take no round (not CURRENT) last
+        rnd, by_round = lax.sort(
+            (jnp.where(valid_cur, occ, B).astype(jnp.int32)[order],
+             order.astype(jnp.int32)), num_keys=1, is_stable=True)
+        pos = jnp.arange(B, dtype=jnp.int32)
+        last_of_round = jnp.concatenate([rnd[1:] != rnd[:-1],
+                                         jnp.ones(1, bool)])
+        round_end = lax.cummin(jnp.where(last_of_round, pos + 1, B),
+                               reverse=True)
+
+        def in_tile_order(col):
+            # padded by W rows, so the last tile's slice stays in bounds
+            col = col[by_round]
+            return jnp.concatenate([col, jnp.zeros((W,) + col.shape[1:],
+                                                   col.dtype)])
+
+        cols_sorted = {n: in_tile_order(c) for n, c in cols.items()
+                       if jnp.ndim(c) >= 1 and c.shape[0] == B}
+        pk_sorted = in_tile_order(pk)
+        lanes = jnp.arange(W, dtype=jnp.int32)
 
         # ops consuming this stream, in step order (absent sides included —
         # their matches are violations, not advances)
@@ -1302,9 +1357,10 @@ class NFAStage:
         cap_names = list(self.cap_cols)
         arm_j = plan.arm_step()
 
-        def capture_current(CP, CD, mask2d, cap: CaptureSpec, reset_counter: bool):
-            """Write the current event into a capture (last + indexed slot +
-            counter) for slots selected by mask2d [B,S]."""
+        def capture_current(CP, CD, mask2d, cap: CaptureSpec, src):
+            """Write the current event (the tile's columns ``src``) into a
+            capture (last + indexed slot + counter) for slots selected by
+            mask2d [W, S]."""
             cid = cap.cid
             if cap.last_ring:
                 # shift the ring BEFORE the new event overwrites `last`:
@@ -1312,52 +1368,60 @@ class NFAStage:
                 # restarts is masked by the counter at read time.
                 for j in range(cap.last_ring, 0, -1):
                     for a in cap.definition.attributes:
-                        src = (cap_lastk_col(cid, j - 1, a.name) if j > 1
-                               else cap_col(cid, a.name))
+                        src_n = (cap_lastk_col(cid, j - 1, a.name) if j > 1
+                                 else cap_col(cid, a.name))
                         dst = cap_lastk_col(cid, j, a.name)
-                        CP[dst] = jnp.where(mask2d, CP[src], CP[dst])
-                        CP[dst + "?"] = jnp.where(mask2d, CP[src + "?"],
+                        CP[dst] = jnp.where(mask2d, CP[src_n], CP[dst])
+                        CP[dst + "?"] = jnp.where(mask2d, CP[src_n + "?"],
                                                   CP[dst + "?"])
             for a in cap.definition.attributes:
                 n = cap_col(cid, a.name)
-                CP[n] = jnp.where(mask2d, cols[a.name][:, None], CP[n])
-                CP[n + "?"] = jnp.where(mask2d, cols[a.name + "?"][:, None], CP[n + "?"])
+                CP[n] = jnp.where(mask2d, src[a.name][:, None], CP[n])
+                CP[n + "?"] = jnp.where(mask2d, src[a.name + "?"][:, None], CP[n + "?"])
             n = cap_col(cid, TS_KEY)
-            CP[n] = jnp.where(mask2d, ts[:, None], CP[n])
+            CP[n] = jnp.where(mask2d, src[TS_KEY][:, None], CP[n])
             if cap.is_count:
                 cnt_n = cap_cnt_col(cid)
-                before = jnp.where(reset_counter, 0, CP[cnt_n])
+                before = CP[cnt_n]
                 for i in range(cap.n_idx):
                     sel = mask2d & (before == i)
                     for a in cap.definition.attributes:
                         ni = cap_idx_col(cid, i, a.name)
-                        CP[ni] = jnp.where(sel, cols[a.name][:, None], CP[ni])
-                        CP[ni + "?"] = jnp.where(sel, cols[a.name + "?"][:, None],
+                        CP[ni] = jnp.where(sel, src[a.name][:, None], CP[ni])
+                        CP[ni + "?"] = jnp.where(sel, src[a.name + "?"][:, None],
                                                  CP[ni + "?"])
                 CP[cnt_n] = jnp.where(mask2d, before + 1, CP[cnt_n])
             CD = jnp.where(mask2d, CD | (1 << cid), CD)
             return CP, CD
 
-        def round_body(carry):
-            (r, active, stepi, bits, vbits, sts, adl, adl2, capdone, consumed,
-             armed, caps, scs, out_valid, out_caps, out_ts, overflow) = carry
-            m = valid_cur & (occ == r)
-            rows_pk = jnp.where(m, pk, K)
+        def tile_body(carry):
+            (s, tiles, active, stepi, bits, vbits, sts, adl, adl2, capdone,
+             consumed, armed, caps, scs, out_valid, out_caps, out_ts,
+             overflow) = carry
+            # positions [s, e): the next W rows of s's round
+            e = jnp.minimum(s + W, round_end[s])
+            m = lanes < e - s
+            tc = {n: lax.dynamic_slice_in_dim(c, s, W)
+                  for n, c in cols_sorted.items()}
+            ts = tc[TS_KEY]
+            keys = lax.dynamic_slice_in_dim(pk_sorted, s, W)
+            # where the views go back: sorted and unique, padding past K
+            rows_pk = jnp.where(m, keys, K + lanes)
 
             V = {
-                "A": active[pk],
-                "ST": stepi[pk],
-                "BT": bits[pk],
-                "VB": vbits[pk],
-                "T0": sts[pk],
-                "ADL": adl[pk],
-                "AD2": adl2[pk],
-                "CD": capdone[pk],
-                "CP": {n: caps[n][pk] for n in cap_names},
-                "SC": [scs[g][pk] for g in range(len(self.scope_cols))],
+                "A": active[keys],
+                "ST": stepi[keys],
+                "BT": bits[keys],
+                "VB": vbits[keys],
+                "T0": sts[keys],
+                "ADL": adl[keys],
+                "AD2": adl2[keys],
+                "CD": capdone[keys],
+                "CP": {n: caps[n][keys] for n in cap_names},
+                "SC": [scs[g][keys] for g in range(len(self.scope_cols))],
             }
-            CONS = consumed[pk]
-            ARMD = armed[pk]
+            CONS = consumed[keys]
+            ARMD = armed[keys]
             ts2d = ts[:, None]
 
             # ---- arming: a key's very first row arms the head wait.
@@ -1366,7 +1430,7 @@ class NFAStage:
             # sentinel that every later capture min()s down to its ts
             # (AbsentPatternTestCase q42: the quiet stretch does not count)
             arm_capless = arm_j is not None and all(
-                s.capture is None for s in plan.steps[arm_j].sides)
+                sd.capture is None for sd in plan.steps[arm_j].sides)
             if arm_j is not None:
                 need = m & ~ARMD
                 onehot0 = need[:, None] & (jnp.arange(S)[None, :] == 0)
@@ -1379,8 +1443,8 @@ class NFAStage:
             # ---- expiry + deadline cascade (before matching: a row at
             # ts past a deadline sees the advanced state)
             V = self._expire(V, ts2d)
-            emit = jnp.zeros((B, S), bool)
-            ets = jnp.broadcast_to(ts2d, (B, S))
+            emit = jnp.zeros((W, S), bool)
+            ets = jnp.broadcast_to(ts2d, (W, S))
             fork_reqs: List = []
             V, emit, ets = self._cascade(V, ts2d, emit, ets, fork_reqs)
 
@@ -1388,7 +1452,7 @@ class NFAStage:
                                      V["T0"], V["CD"])
             CP = V["CP"]
 
-            # eval dict: current attrs [B,1], captures [B,S]
+            # eval dict: current attrs [W,1], captures [W,S]
             ev = dict(CP)
             # a count capture with no occurrences yet reads NULL (the
             # reference's empty StateEvent chain): mask `last` by cnt==0
@@ -1396,7 +1460,7 @@ class NFAStage:
             # across count restarts. `@present` synthetics carry the bare
             # StateEvent presence checks (`e2 is null`, `e2[last-k] is
             # null`): their null mask IS absence.
-            ones2d = jnp.ones((B, S), bool)
+            ones2d = jnp.ones((W, S), bool)
             pres_cols: List[str] = []
 
             def _pres(ev_d, name, absent):
@@ -1423,8 +1487,8 @@ class NFAStage:
                         ev[nj] = CP[nj] | (cnt <= j)
             if in_def is not None:
                 for a in in_def.attributes:
-                    ev[a.name] = cols[a.name][:, None]
-                    ev[a.name + "?"] = cols[a.name + "?"][:, None]
+                    ev[a.name] = tc[a.name][:, None]
+                    ev[a.name + "?"] = tc[a.name + "?"][:, None]
             ev[TS_KEY] = ts2d
             # fresh-start eval dict: capture references are NULL (a fresh
             # chain has captured nothing — a freed slot's stale values
@@ -1432,15 +1496,15 @@ class NFAStage:
             ev_fresh = dict(ev)
             for n in cap_names:
                 if n.endswith("?"):
-                    ev_fresh[n] = jnp.ones((B, 1), ev[n].dtype)
+                    ev_fresh[n] = jnp.ones((W, 1), ev[n].dtype)
                 else:
-                    ev_fresh[n] = jnp.zeros((B, 1), ev[n].dtype)
+                    ev_fresh[n] = jnp.zeros((W, 1), ev[n].dtype)
             for n in pres_cols:   # fresh chains have captured nothing
-                ev_fresh[n + "?"] = jnp.ones((B, 1), bool)
+                ev_fresh[n + "?"] = jnp.ones((W, 1), bool)
 
             # ---- phase 1: match masks against pre-event state; the
             # furthest-advanced op wins a slot (no per-event forking)
-            win = jnp.full((B, S), -1, jnp.int32)
+            win = jnp.full((W, S), -1, jnp.int32)
             conds: List[jnp.ndarray] = []
             at_masks: List[jnp.ndarray] = []
             adv_masks: List[jnp.ndarray] = []
@@ -1451,8 +1515,8 @@ class NFAStage:
             for oi, (st, side) in enumerate(ops):
                 j = st.index
                 cond = side.cond(ev, ctx) if side.cond is not None \
-                    else jnp.ones((B, 1), bool)
-                cond = jnp.broadcast_to(cond, (B, S))
+                    else jnp.ones((W, 1), bool)
+                cond = jnp.broadcast_to(cond, (W, S))
                 conds.append(cond)
                 if side.absent:
                     # a matching event on an absent side while the slot
@@ -1461,11 +1525,11 @@ class NFAStage:
                     if st.kind in ("and", "or"):
                         v = v & ((BT & side.bit) == 0)
                     viols.append(v)
-                    at_masks.append(jnp.zeros((B, S), bool))
-                    adv_masks.append(jnp.zeros((B, S), bool))
+                    at_masks.append(jnp.zeros((W, S), bool))
+                    adv_masks.append(jnp.zeros((W, S), bool))
                     adv_fork_masks.append([])
                     continue
-                viols.append(jnp.zeros((B, S), bool))
+                viols.append(jnp.zeros((W, S), bool))
                 at = A & (ST == j) & m[:, None] & cond
                 if st.kind == "count":
                     cnt = CP[cap_cnt_col(side.capture.cid)]
@@ -1474,8 +1538,8 @@ class NFAStage:
                     # a side is consumed once (LogicalPreStateProcessor):
                     # an already-matched side must not re-match/overwrite
                     at = at & ((BT & side.bit) == 0)
-                adv = jnp.zeros((B, S), bool)
-                fork_all = jnp.zeros((B, S), bool)
+                adv = jnp.zeros((W, S), bool)
+                fork_all = jnp.zeros((W, S), bool)
                 fork_srcs: List[Tuple[int, jnp.ndarray]] = []
                 for p in self._advance_sources(j):
                     src = plan.steps[p]
@@ -1550,8 +1614,8 @@ class NFAStage:
                         A2 = A2 & ~(v & dead)
 
             # ---- phase 2: apply the winning transition per slot
-            emit2 = jnp.zeros((B, S), bool)
-            kill = jnp.zeros((B, S), bool)
+            emit2 = jnp.zeros((W, S), bool)
+            kill = jnp.zeros((W, S), bool)
             sticky_emit_ops: List[Tuple[jnp.ndarray, StepSpec, SideSpec]] = []
             phase2_forks: List[Tuple[jnp.ndarray, int, SideSpec]] = []
             # (mask, src_step): sticky count parents to re-arm (zero their
@@ -1603,8 +1667,7 @@ class NFAStage:
                     continue
                 if st.kind == "count":
                     # entering resets the counter; absorbing continues it
-                    CP2, CD2 = capture_current(CP2, CD2, eff, cap,
-                                               reset_counter=False)
+                    CP2, CD2 = capture_current(CP2, CD2, eff, cap, tc)
                     if arm_capless:
                         T0 = jnp.where(eff, jnp.minimum(T0, ts2d), T0)
                     ST2 = jnp.where(eff, j, ST2)
@@ -1644,8 +1707,7 @@ class NFAStage:
                         phase2_forks.append((done, j, None))
                         count_resets.append((done, st))
                 elif st.kind == "stream":
-                    CP2, CD2 = capture_current(CP2, CD2, eff, cap,
-                                               reset_counter=False)
+                    CP2, CD2 = capture_current(CP2, CD2, eff, cap, tc)
                     if arm_capless:
                         T0 = jnp.where(eff, jnp.minimum(T0, ts2d), T0)
                     if j == L:
@@ -1663,8 +1725,7 @@ class NFAStage:
                             # already complete — emit now, keep absorbing
                             emit2 = emit2 | eff
                 else:  # and / or
-                    CP2, CD2 = capture_current(CP2, CD2, eff, cap,
-                                               reset_counter=False)
+                    CP2, CD2 = capture_current(CP2, CD2, eff, cap, tc)
                     if arm_capless:
                         T0 = jnp.where(eff, jnp.minimum(T0, ts2d), T0)
                     bt2 = BT2 | jnp.where(eff, side.bit, 0)
@@ -1672,7 +1733,7 @@ class NFAStage:
                     if st.kind == "and":
                         full = (bt2 & nb) == nb
                     else:
-                        full = jnp.ones((B, S), bool)
+                        full = jnp.ones((W, S), bool)
                     done = eff & full
                     if st.sticky:
                         # re-arm the logical parent on completion
@@ -1733,31 +1794,27 @@ class NFAStage:
             # ---- sticky emissions at the last step: emit parent captures
             # + the current event, parent survives
             CPe = None
-            semit = jnp.zeros((B, S), bool)
+            semit = jnp.zeros((W, S), bool)
             for eff, st, side in sticky_emit_ops:
                 if CPe is None:
                     CPe = dict(CP2)
                     CDe = CD2
-                CPe, CDe = capture_current(CPe, CDe, eff, side.capture,
-                                           reset_counter=False)
+                CPe, CDe = capture_current(CPe, CDe, eff, side.capture, tc)
                 semit = semit | eff
             emit_all = emit_all | (semit & m[:, None])
 
             # ---- emission snapshot BEFORE fork allocation: forks may
             # reuse slots freed by emitting matches and would clobber the
             # capture columns the emission reads
-            out_cd = jnp.where(emit_all, CD2, out_caps["__capdone__"][:, :S])
+            out_cd = jnp.where(emit_all, CD2, 0)
             if CPe is not None:
                 ov2 = {n: jnp.where(semit, CPe[n],
-                                    jnp.where(emit_all, CP2[n], out_caps[n][:, :S]))
+                                    jnp.where(emit_all, CP2[n], _zero(CP2[n])))
                        for n in cap_names}
                 out_cd = jnp.where(semit, CDe, out_cd)
             else:
-                ov2 = {n: jnp.where(emit_all, CP2[n], out_caps[n][:, :S])
+                ov2 = {n: jnp.where(emit_all, CP2[n], _zero(CP2[n]))
                        for n in cap_names}
-            new_out_valid = out_valid.at[:, :S].set(out_valid[:, :S] | emit_all)
-            new_out_ts = out_ts.at[:, :S].set(
-                jnp.where(emit_all, ets, out_ts[:, :S]))
 
             # ---- allocate forked children (sticky advances)
             V2 = {"A": A2, "ST": ST2, "BT": BT2, "VB": VB2, "T0": T0,
@@ -1770,8 +1827,7 @@ class NFAStage:
                 V2, dstm, overflow = self._alloc_forks(V2, req & m[:, None], overflow)
                 if side is not None and side.capture is not None:
                     V2["CP"], V2["CD"] = capture_current(
-                        V2["CP"], V2["CD"], dstm, side.capture,
-                        reset_counter=False)
+                        V2["CP"], V2["CD"], dstm, side.capture, tc)
                 V2 = self._enter(V2, dstm, target, ts2d)
                 V2 = self._start_capture_scopes(V2, dstm, target - 1, ts2d)
             A2, ST2, BT2, VB2 = V2["A"], V2["ST"], V2["BT"], V2["VB"]
@@ -1835,9 +1891,9 @@ class NFAStage:
                     seq_absorbing = terms[0]
                     for t in terms[1:]:
                         seq_absorbing = seq_absorbing | t
-            fresh_any = jnp.zeros((B,), bool)
-            direct = jnp.zeros((B,), bool)
-            direct_op = jnp.full((B,), -1, jnp.int32)
+            fresh_any = jnp.zeros((W,), bool)
+            direct = jnp.zeros((W,), bool)
+            direct_op = jnp.full((W,), -1, jnp.int32)
             fresh_reqs: List[Tuple[jnp.ndarray, int, int, SideSpec]] = []
             for oi, (st, side) in enumerate(ops):
                 if side.absent:
@@ -1846,7 +1902,7 @@ class NFAStage:
                 if not self._fresh_ok(j):
                     continue
                 fcond = (side.cond(ev_fresh, ctx)[:, 0]
-                         if side.cond is not None else jnp.ones((B,), bool))
+                         if side.cond is not None else jnp.ones((W,), bool))
                 f = m & every_ok & fcond
                 if seq_absorbing is not None:
                     f = f & ~seq_absorbing
@@ -1892,12 +1948,10 @@ class NFAStage:
                         fresh_reqs.append((f, j, side.bit, side))
                 fresh_any = fresh_any | f
 
-            new_out_valid = new_out_valid.at[:, S].set(new_out_valid[:, S] | direct)
-
             # ---- allocate fresh slots
             NF = len(fresh_reqs)
             if NF:
-                req = jnp.stack([fr[0] for fr in fresh_reqs], axis=1)  # [B,NF]
+                req = jnp.stack([fr[0] for fr in fresh_reqs], axis=1)  # [W,NF]
                 free = ~A2
                 n_free = jnp.sum(free, axis=1)
                 fs = jnp.argsort(
@@ -1908,11 +1962,11 @@ class NFAStage:
                 overflow = overflow + jnp.sum(req & ~can).astype(jnp.int32)
                 slot_of = jnp.where(
                     can, jnp.take_along_axis(fs, jnp.clip(rank, 0, S - 1), axis=1), S)
-                bidx = jnp.arange(B)
+                bidx = jnp.arange(W)
                 for k, (f, step_val, bits_val, side) in enumerate(fresh_reqs):
                     slot = slot_of[:, k]
                     cap = side.capture
-                    onehot = jnp.zeros((B, S + 1), bool).at[bidx, slot].set(
+                    onehot = jnp.zeros((W, S + 1), bool).at[bidx, slot].set(
                         True)[:, :S]
                     A2 = A2 | onehot
                     T0 = jnp.where(onehot, ts2d, T0)
@@ -1931,23 +1985,25 @@ class NFAStage:
                                               tmp["CD"], tmp["SC"])
                     BT2 = jnp.where(onehot, bits_val, BT2)
                     if cap is not None:
-                        CP2, CD2 = capture_current(CP2, CD2, onehot, cap,
-                                                   reset_counter=False)
+                        CP2, CD2 = capture_current(CP2, CD2, onehot, cap, tc)
                         scV2 = self._start_capture_scopes(
                             {"CD": CD2, "SC": SC2}, onehot,
                             fresh_cap_step(self.plan, step_val, bits_val), ts2d)
                         CD2, SC2 = scV2["CD"], scV2["SC"]
 
-            consumed2 = consumed.at[rows_pk].set(
-                jnp.where(m, CONS | fresh_any | direct, CONS), mode="drop")
-            armed2 = armed.at[rows_pk].set(
-                jnp.where(m, ARMD, armed[pk]), mode="drop") if arm_j is not None \
-                else armed
+            # ---- scatter views back at the tile's keys
+            def put(dst, view):
+                return dst.at[rows_pk].set(view, mode="drop",
+                                           indices_are_sorted=True,
+                                           unique_indices=True)
+
+            consumed2 = put(consumed, CONS | fresh_any | direct)
+            armed2 = put(armed, ARMD) if arm_j is not None else armed
 
             # ---- direct-emission column (fresh match completing instantly)
             ov3 = {}
             for n in cap_names:
-                col_S = out_caps[n][:, S]
+                col_S = jnp.zeros((W,), self.cap_cols[n])
                 for oi, (st, side) in enumerate(ops):
                     cap = side.capture
                     if cap is None:
@@ -1962,48 +2018,62 @@ class NFAStage:
                         base = n[len(f"c{cap.cid}__"):]
                     elif n.startswith(f"c{cap.cid}i0__"):
                         base = n[len(f"c{cap.cid}i0__"):]
-                    if base is not None and base in cols:
-                        col_S = jnp.where(dm, cols[base], col_S)
+                    if base is not None and base in tc:
+                        col_S = jnp.where(dm, tc[base], col_S)
                 ov3[n] = jnp.concatenate([ov2[n], col_S[:, None]], axis=1)
-            direct_cd = out_caps["__capdone__"][:, S]
+            direct_cd = jnp.zeros((W,), jnp.int32)
             for oi, (st, side) in enumerate(ops):
                 if side.capture is None:
                     continue
                 dm = direct & (direct_op == oi)
                 direct_cd = jnp.where(dm, jnp.int32(1 << side.capture.cid), direct_cd)
             ov3["__capdone__"] = jnp.concatenate([out_cd, direct_cd[:, None]], axis=1)
-            new_out_ts = new_out_ts.at[:, S].set(
-                jnp.where(direct, ts, out_ts[:, S]))
 
-            # ---- scatter views back (rows in this round only)
-            def put(dst, view):
-                return dst.at[rows_pk].set(view, mode="drop")
+            # ---- the tile's emissions, once, at its positions [s, s + W)
+            # (lanes past the tile emit nothing; a later tile writes over
+            # them, or they are rows that take no round)
+            def place(buf, rows):
+                return lax.dynamic_update_slice_in_dim(buf, rows, s, axis=0)
 
-            return (r + 1, put(active, A2), put(stepi, ST2), put(bits, BT2),
-                    put(vbits, VB2), put(sts, T0), put(adl, ADL2_),
-                    put(adl2, AD22_), put(capdone, CD2), consumed2, armed2,
+            t_valid = jnp.concatenate([emit_all, direct[:, None]], axis=1)
+            t_ts = jnp.concatenate([jnp.where(emit_all, ets, ts2d), ts2d],
+                                   axis=1)
+            return (e, tiles + 1, put(active, A2), put(stepi, ST2),
+                    put(bits, BT2), put(vbits, VB2), put(sts, T0),
+                    put(adl, ADL2_), put(adl2, AD22_), put(capdone, CD2),
+                    consumed2, armed2,
                     {n: put(caps[n], CP2[n]) for n in cap_names},
                     [put(scs[g], SC2[g]) for g in range(len(self.scope_cols))],
-                    new_out_valid, ov3, new_out_ts, overflow)
+                    place(out_valid, t_valid),
+                    {n: place(out_caps[n], v) for n, v in ov3.items()},
+                    place(out_ts, t_ts), overflow)
 
-        out_valid0 = jnp.zeros((B, S + 1), bool)
-        out_caps0 = {n: jnp.zeros((B, S + 1), dt) for n, dt in self.cap_cols.items()}
-        out_caps0["__capdone__"] = jnp.zeros((B, S + 1), jnp.int32)
-        out_ts0 = jnp.broadcast_to(ts[:, None], (B, S + 1))
+        # the output buffers, in tile order, W rows longer than the batch
+        out_valid0 = jnp.zeros((B + W, S + 1), bool)
+        out_caps0 = {n: jnp.zeros((B + W, S + 1), dt)
+                     for n, dt in self.cap_cols.items()}
+        out_caps0["__capdone__"] = jnp.zeros((B + W, S + 1), jnp.int32)
+        out_ts0 = jnp.broadcast_to(cols_sorted[TS_KEY][:, None], (B + W, S + 1))
 
-        carry0 = (jnp.int32(0), state["active"], state["stepi"], state["bits"],
-                  state["vbits"], state["sts"], state["adl"], state["adl2"],
-                  state["capdone"], state["consumed"], state["armed"],
+        carry0 = (jnp.int32(0), jnp.int32(0), state["active"], state["stepi"],
+                  state["bits"], state["vbits"], state["sts"], state["adl"],
+                  state["adl2"], state["capdone"], state["consumed"],
+                  state["armed"],
                   {n: state[n] for n in cap_names},
                   [state[g] for g in self.scope_cols],
                   out_valid0, out_caps0, out_ts0, state["nfa_overflow"])
 
         with jax.named_scope(ROUNDS_SCOPE):
-            res = lax.while_loop(lambda c: c[0] < n_rounds, round_body,
-                                 carry0)
-        (_r, active2, stepi2, bits2, vbits2, sts2, adl_2, adl2_2, capdone2,
-         consumed2, armed2, caps2, scs2, out_valid, out_caps, out_ts,
-         overflow2) = res
+            res = lax.while_loop(lambda c: c[0] < n_rows, tile_body, carry0)
+        (_s, tiles, active2, stepi2, bits2, vbits2, sts2, adl_2, adl2_2,
+         capdone2, consumed2, armed2, caps2, scs2, out_valid, out_caps,
+         out_ts, overflow2) = res
+        # back to row order: row by_round[p] sits at position p
+        at = jnp.zeros(B, jnp.int32).at[by_round].set(pos,
+                                                        unique_indices=True)
+        out_valid = out_valid[at]
+        out_caps = {n: c[at] for n, c in out_caps.items()}
+        out_ts = out_ts[at]
 
         new_state = dict(state)
         new_state.update(active=active2, stepi=stepi2, bits=bits2,
@@ -2015,10 +2085,12 @@ class NFAStage:
         for n in cap_names:
             new_state[n] = caps2[n]
 
-        out = self._flatten_out(out_valid, out_caps, out_ts, ts, cols, pk, B)
+        out = self._flatten_out(out_valid, out_caps, out_ts, cols[TS_KEY],
+                                cols, pk, B)
         out["__overflow__"] = (overflow2 > state["nfa_overflow"]).astype(jnp.int32)
         out["__notify__"] = _notify_of(self._next_deadline(new_state))
         out["__rounds__"] = n_rounds.astype(jnp.int32)
+        out["__tiles__"] = tiles
         return new_state, out
 
     # ................................................ scheduler TIMER sweep
@@ -2203,6 +2275,10 @@ def fresh_cap_step(plan: NFAPlan, rest_step: int, bits_val: int) -> int:
     if rest_step > 0 and plan.steps[rest_step - 1].kind == "stream":
         return rest_step - 1
     return rest_step
+
+
+def _zero(x):
+    return jnp.zeros((), x.dtype)
 
 
 def _gather_like(arm_ts, req, dst_mask):

@@ -26,7 +26,7 @@ RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "route_prep_ms", "route_pieces", "shard_rows_max",
              "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
              "state_bytes", "state_slots", "key_ms", "launch_ms",
-             "h2d_bytes", "nfa_rounds"}
+             "h2d_bytes", "nfa_rounds", "nfa_tiles"}
 FLUSH_KEYS = ("flush_rows", "timer_steps")
 ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
               "shard_capacity")
@@ -211,7 +211,7 @@ def test_an_nfa_app_leaves_journeys_with_every_ring_key():
         # and those of a folded tumbling window
         assert [rec[k] for k in FLUSH_KEYS] == [None] * 2
         # the two-step kernel runs no round loop
-        assert rec["nfa_rounds"] == 0
+        assert rec["nfa_rounds"] == 0 and rec["nfa_tiles"] == 0
         assert rec["dispatch_ms"] > 0 and rec["meta_pull_ms"] > 0
     assert len({rec["batch"] for rec in ring}) == 4
     heads, tails = ring[0::2], ring[1::2]

@@ -8,7 +8,9 @@ on the generic NFA engine. Held to the plain reference of the benchmark
 program), whose event-at-a-time loop is held to the rows upstream's test
 expects. The app text, the feed kind (``walk``) and the key mix are the
 configuration's and the cell's own files; the sizes are their rehearsal
-entries (40 keys, 256-row batches). CPU backend, seeded.
+entries (40 keys, 256-row batches), and, where a round has to be wider
+than the engine's tile, 500 keys and 1,024-row batches. CPU backend,
+seeded.
 """
 
 import numpy as np
@@ -16,7 +18,9 @@ import pytest
 
 from benchmarks import drive, generator, manifest, run
 from benchmarks.references import sequence
+from siddhi_tpu import SiddhiManager, StreamCallback
 from siddhi_tpu.observability import journey
+from siddhi_tpu.ops import nfa
 from siddhi_tpu.observability.export import prometheus_text
 
 CELL = "sequence_peak_10k.hot20_walk16k"
@@ -128,6 +132,8 @@ def _drive(cell, sizes, feed, n):
         for i in range(n):
             sender.send(i)
         got = run._delivered(cell.config, collector, rt, feed)
+        got["key_capacity"] = rt.query_runtimes["seq"]._win_keys \
+            if "seq" in rt.query_runtimes else None
         counters = rt.app_context.telemetry.snapshot()["counters"]
         scraped = prometheus_text(manager)
         ring = [j for j in journey.ring()
@@ -193,3 +199,102 @@ def test_the_rounds_are_a_meta_lane_whatever_the_instruments():
     assert on == ["nfa_rounds", "nfa_runs"] and off == ["nfa_rounds"]
     assert [j["nfa_rounds"] for j in ring] == [
         int(np.bincount(feed.batch(i).keys).max()) for i in range(3)]
+
+
+# ------------------------------------------------------------------- (e)
+
+def _rounds_and_tiles(keys, W):
+    """A batch's rounds (its most rows of one key) and the tiles they take:
+    the sum over rounds r of ceil(n_r / W), n_r the keys with more than r
+    rows."""
+    counts = np.bincount(keys)
+    n_r = [int((counts > r).sum()) for r in range(counts.max())]
+    return len(n_r), sum(-(-n // W) for n in n_r), n_r[0]
+
+
+@pytest.mark.parametrize("seed", [5, 2 ** 31 + 5])
+def test_a_round_wider_than_a_tile_runs_as_several(seed):
+    """500 keys, 1,024-row batches: round 0 holds some 260 keys, over
+    twice the tile, and the rows still equal the reference row for row;
+    each batch's tiles are what its keys make them, its rounds unmoved."""
+    cell = manifest.Cell(CELL)
+    sizes, traffic = cell.sized(True)
+    sizes["keys"] = 500
+    sizes["batch_rows"] = traffic["batch_rows"] = 1_024
+    feed = generator.make_feed(cell.config, sizes, traffic, seed)
+    n = len(feed.warm) + feed.fill_batches + 2 * len(feed.pool)
+    got, counters, scraped, ring = _drive(cell, sizes, feed, n)
+    want = sequence.reference(cell.config, sizes, feed, n)
+    assert len(want["key"]) > 500
+    assert sequence.compare(cell.config, want, got) == [
+        ("rows_missing", 0, 0), ("order_mismatch_rows", 0, 0)]
+    W = nfa._tile_rows(1_024, got["key_capacity"])
+    reckoned = [_rounds_and_tiles(feed.batch(i).keys, W) for i in range(n)]
+    assert max(first for _r, _t, first in reckoned) > 2 * W
+    rounds = [r for r, _t, _f in reckoned]
+    tiles = [t for _r, t, _f in reckoned]
+    assert rounds == want["rounds_per_batch"].tolist()
+    assert [j["nfa_rounds"] for j in ring] == rounds
+    assert [j["nfa_tiles"] for j in ring] == tiles
+    assert sum(tiles) > sum(rounds)
+    assert counters["nfa.seq.rounds"] == sum(rounds)
+    assert counters["nfa.seq.tiles"] == sum(tiles)
+    assert f'name="nfa.seq.tiles"}} {sum(tiles)}' in scraped
+
+
+class _Rows(StreamCallback):
+    def __init__(self):
+        super().__init__()
+        self.rows = []
+
+    def receive(self, events):
+        self.rows.extend(e.data for e in events)
+
+
+# upstream's CountPatternTestCase.testQuery17 (tests/test_pattern_corpus.py):
+# AABB AABB A, four seconds, ABB AABB gives three matches
+_EVERY_A2_B = """@app:playback define stream InputStream (name string);
+    @info(name = 'q')
+    from every e1=InputStream[(e1.name == 'A')]<2>
+       -> e2=InputStream[(e2.name == 'B')]
+       within 3 seconds
+    select 'rule1' as ruleId, count() as numOfEvents
+    insert into OutputStream;
+"""
+_Q17 = list("AABBAABBA") + list("ABBAABB")
+_Q17_TS = np.array([1_000 + 100 * i + (4_000 if i >= 9 else 0)
+                    for i in range(len(_Q17))], np.int64)
+
+
+def _q17_rows(one_batch: bool):
+    journey.enable()
+    try:
+        manager = SiddhiManager()
+        rt = manager.create_siddhi_app_runtime(_EVERY_A2_B)
+        got = _Rows()
+        rt.add_callback("OutputStream", got)
+        h = rt.get_input_handler("InputStream")
+        if one_batch:
+            h.send_columns({"name": np.array(_Q17, dtype=object)},
+                           timestamps=_Q17_TS)
+        else:
+            for name, t in zip(_Q17, _Q17_TS):
+                h.send(int(t), [name])
+        counters = rt.app_context.telemetry.snapshot()["counters"]
+        ring = [j for j in journey.ring() if j["queries"] == ["q"]]
+        manager.shutdown()
+    finally:
+        journey.disable()
+    return got.rows, counters, ring
+
+
+def test_an_unpartitioned_count_pattern_takes_a_row_a_tile():
+    """K = 1: the tile is one row, so a batch of the one key takes a tile
+    a row, as many as its rounds, and gives the rows the same events give
+    one at a time (upstream's three matches)."""
+    rows, counters, ring = _q17_rows(one_batch=True)
+    one_by_one, _c, _r = _q17_rows(one_batch=False)
+    assert rows == one_by_one and len(rows) == 3
+    assert [(j["nfa_rounds"], j["nfa_tiles"]) for j in ring] == [
+        (len(_Q17), len(_Q17))]
+    assert counters["nfa.q.tiles"] == counters["nfa.q.rounds"] == len(_Q17)

@@ -517,7 +517,26 @@ def test_nfa_steps_compile(one_chip, keys, batch):
 
 
 # (b2) the sequence cell's query: the generic round engine (a
-# ``while_loop`` in ``siddhi.nfa_rounds``) at K = 16,384 and S = 32.
+# ``while_loop`` in ``siddhi.nfa_rounds``, one iteration a tile of W rows
+# of one round) at K = 16,384 and S = 32.
+# what the compiler itself emits to carry a leaf through the loop (no
+# op_name of the program's)
+_STATE_MOVES = ("get-tuple-element", "copy-start", "copy-done",
+                "custom-call", "slice-start", "slice-done", "bitcast")
+_INSTR = re.compile(
+    r'\s*(?:ROOT )?%[\w.\-]+ = (?:\w+)(\[[\d,]*\])\S* ([a-z][\w\-]*)\('
+    r'(?:.*op_name="([^"]*)")?')
+
+
+def _computation(hlo_text, name):
+    """``(opcode, shape, op_name)`` of every array-valued instruction of
+    one computation of a compiled program, layouts stripped."""
+    lines = hlo_text.split(f"\n{name} ", 1)[1].split("\n}", 1)[0]
+    return [(m[2], m[1], m[3] or "") for m in map(_INSTR.match,
+                                                  lines.splitlines()[1:])
+            if m]
+
+
 @pytest.mark.parametrize("keys,batch", [
     (10_000, 1_024),
     pytest.param(10_000, 16_384, marks=pytest.mark.slow),
@@ -525,6 +544,8 @@ def test_nfa_steps_compile(one_chip, keys, batch):
 def test_sequence_step_compiles(one_chip, keys, batch):
     import json
     import os
+
+    from siddhi_tpu.ops.nfa import _tile_rows
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -548,6 +569,17 @@ def test_sequence_step_compiles(one_chip, keys, batch):
     _report(f"sequence step B={batch} K=16384", compiled, seconds)
     text = compiled.as_text()
     assert " while(" in text and "siddhi.nfa_rounds" in text
+    # the loop works on a tile of W rows, never on the whole batch: no
+    # result in its body is [B, S] or [B, S + 1] but the [K, S] state's
+    # own carriage and write-back (at B = K the shapes are alike)
+    body = _computation(text, re.search(r" while\(.*?body=(%[\w.\-]+)",
+                                        text)[1])
+    W = _tile_rows(batch, 16_384)
+    assert W < batch and [r for r in body if r[1].startswith(f"[{W},")]
+    whole = [r for r in body if re.match(rf"\[{batch},3[23]\]", r[1])]
+    assert all((op in _STATE_MOVES and not name) or (
+        op == "fusion" and name.endswith("/scatter"))
+        for op, _shape, name in whole), whole
 
 
 # (d) the routed path on a mesh of the four described chips.
@@ -676,7 +708,8 @@ def test_device_routed_step_compiles(topo, exchange, marker, window, keys,
 # the keyed ring's layout and PR 36 how its writes are handed their slots,
 # so cells 1, 4 and 6, and only they, were new each time; the NFA steps'
 # meta gained the generic engine's round count, so cell 3's two are new
-# beside the sequence cell's one).
+# beside the sequence cell's one; the generic engine's tiles changed the
+# sequence cell's alone, the tile count riding the round count's lane).
 _STEP_SHA256 = {
     "partition_len1k_10k.hot20_bulk": [
         "86d87d364886479770fdb83acfff6b632f3dacf2d01408b251fd6735f26b8ef5"],
@@ -694,7 +727,7 @@ _STEP_SHA256 = {
         "2d061f2d72cb319f4de50dd40f4c8a0075df794be5aa10e3098f3278f9ee98c1",
         "3230499bcb4279486e82f49244eb532341a659110469bac57244cd01f07dba8c"],
     "sequence_peak_10k.hot20_walk16k": [
-        "094cf38bc6c3f58688c74f8a4fdbce37d09a685f637b49b60369b4cfce98f869"],
+        "01a0cca0e1769fa2030bbffba7782bfe6199e3935edafcfa881ba1d1c0028fac"],
 }
 
 
