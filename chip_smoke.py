@@ -296,7 +296,7 @@ def _knobs(rt):
     ac = rt.app_context
     return {k: getattr(ac, k) for k in (
         "precision", "pipeline_depth", "fuse_fanout", "program_cache",
-        "profile_device_instruments", "join_partitions", "nfa_slots")}
+        "profile_device_instruments", "nfa_slots")}
 
 
 def _float_tolerance(precision, window, max_abs):

@@ -21,7 +21,7 @@ dead declaration. All of those are findings:
   construction produces.
 
 F-string slot names normalize interpolations to ``*``
-(``Slot(f"fill.{side}")`` matches ``fill.left``/``fill.right``), same
+(``Slot(f"fill.{side}")`` would match ``fill.left``/``fill.right``), same
 as R3's template discipline.
 """
 

@@ -3,9 +3,7 @@
 The engine measures everything — critical-path verdicts naming the
 bottleneck stage (``observability/journey.py``), device-instrument
 fills and shard skew, quota-utilization gauges, per-program jit-compile
-counts — but every performance knob was still set by hand; the only
-adaptive behaviors were PanJoin Wp growth and the AUTO join-partition
-default. This package closes the observe→decide→actuate loop
+counts — but every performance knob was still set by hand. This package closes the observe→decide→actuate loop
 (ROADMAP item 4), generalizing PanJoin's adaptive repartitioning
 across the whole engine while every re-merge it touches keeps the
 ordered-emission discipline:

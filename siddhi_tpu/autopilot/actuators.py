@@ -15,8 +15,6 @@ may only change when/where work runs:
                       ``app_context.pipeline_depth`` at every submit.
 - ``ingest_pool``     ``IngestPackPool.resize`` (ordered merge keeps
                       sub-batch sequence numbers authoritative).
-- ``join_partitions`` Wp shrink through the same rebuild path the
-                      PanJoin growth side uses (``_rebuild_side``).
 - ``route_shards``    blue/green re-install via the canonical-snapshot
                       cross-restore path (``device_route_query_step``
                       on an already-routed runtime).
@@ -90,26 +88,6 @@ def _apply_ingest_pool(rt, direction) -> Optional[Tuple[int, int]]:
         pool.resize(new)
     ctx.ingest_pool = new
     return old, new
-
-
-def _apply_join_partitions(rt, direction) -> Optional[Tuple[int, int]]:
-    """Shrink-only: Wp GROWTH stays where it always was (the engine
-    grows pre-dispatch inside ``prepare_batch`` the moment occupancy
-    demands it); the autopilot's contribution is the reverse path —
-    releasing over-provisioned sub-windows after a skew burst passes."""
-    if direction != DOWN:
-        return None
-    changed = None
-    for qr in rt.query_runtimes.values():
-        eng = getattr(qr, "engine", None)
-        if eng is None or not hasattr(eng, "shrink_partitions"):
-            continue
-        with qr._lock:   # no batch mid-step while the directory rebuilds
-            shrunk = eng.shrink_partitions()
-        for _side, (old_wp, new_wp) in (shrunk or {}).items():
-            changed = (old_wp, new_wp) if changed is None else \
-                (max(changed[0], old_wp), max(changed[1], new_wp))
-    return changed
 
 
 def _apply_route_shards(rt, direction) -> Optional[Tuple[int, int]]:
@@ -200,10 +178,6 @@ ACTUATORS: Dict[str, Actuator] = _declare(
     Actuator(name="ingest_pool", knob="ingest_pool", lo=0, hi=8,
              doc="IngestPackPool worker count (ordered-merge resize)",
              apply=_apply_ingest_pool),
-    Actuator(name="join_partitions", knob="join_partition_slack", lo=1,
-             hi=64,
-             doc="device-join Wp shrink (growth stays in prepare_batch)",
-             apply=_apply_join_partitions),
     Actuator(name="route_shards", knob="route_shards", lo=2, hi=64,
              doc="routed shard count (canonical blue/green re-install)",
              apply=_apply_route_shards),

@@ -86,10 +86,6 @@ def _pack_bound(sig: SignalSnapshot) -> Optional[str]:
     return None
 
 
-def _join_overprovisioned(sig: SignalSnapshot) -> Optional[str]:
-    return DOWN if sig.join_shrinkable else None
-
-
 def _shard_pressure(sig: SignalSnapshot) -> Optional[str]:
     if not sig.routed:
         return None
@@ -131,8 +127,6 @@ RULES = (
                when=_device_bound),
     PolicyRule(name="pack_bound", actuator="ingest_pool",
                when=_pack_bound),
-    PolicyRule(name="join_overprovisioned", actuator="join_partitions",
-               when=_join_overprovisioned),
     PolicyRule(name="shard_pressure", actuator="route_shards",
                when=_shard_pressure),
     PolicyRule(name="queue_pressure", actuator="admission_cap",

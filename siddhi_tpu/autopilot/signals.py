@@ -4,8 +4,7 @@ Everything here is assembled from surfaces the engine ALREADY exports:
 ``journey.critical_path_report`` (stage quantiles + named bottleneck),
 the app's ``TelemetryRegistry`` snapshot (``pipeline.*.inflight``,
 ``ingest.pool.*``, ``quota.*`` utilization gauges, per-program jit
-compile counts) and the device-join engines' host occupancy mirrors.
-A collect() NEVER issues a device pull — the same scrape-path
+compile counts). A collect() NEVER issues a device pull — the same scrape-path
 discipline as ``GET /metrics`` (gauges read drained instrument lanes
 or host mirrors; see ``observability/instruments.py``).
 """
@@ -39,9 +38,6 @@ class SignalSnapshot:
     pool_workers: Optional[int] = None
     pool_utilization: float = 0.0
     pool_queue_depth: float = 0.0
-    # device-join sides whose Wp could shrink back after a skew burst:
-    # {query: {side: (current_wp, shrink_target)}}
-    join_shrinkable: Dict[str, dict] = field(default_factory=dict)
     # routed queries: {query: shard_count}
     routed: Dict[str, int] = field(default_factory=dict)
     fused_groups: int = 0
@@ -100,14 +96,6 @@ def collect(app_runtime) -> SignalSnapshot:
         except Exception:  # noqa: BLE001 — observation must never throw
             sig.bottlenecks = {}
     for qname, qr in app_runtime.query_runtimes.items():
-        eng = getattr(qr, "engine", None)
-        if eng is not None and hasattr(eng, "shrink_candidates"):
-            try:
-                cands = eng.shrink_candidates()
-            except Exception:  # noqa: BLE001 — host mirror read only
-                cands = {}
-            if cands:
-                sig.join_shrinkable[qname] = cands
         layout = getattr(qr, "_route_layout", None)
         if layout is not None:
             sig.routed[qname] = int(layout.n)

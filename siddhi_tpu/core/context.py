@@ -254,34 +254,20 @@ class SiddhiAppContext:
         # per-shard bounded WAL (batches) backing the shard rebuild
         # protocol; 0 disables shard WALs. Key siddhi_tpu.agg_shard_wal.
         self.agg_shard_wal = 1024
-        # device join engine (core/join/): 'device' attaches the
-        # PanJoin-style partitioned probe engine to eligible stream-stream
-        # window joins (pipeline/fusion-eligible fused insert+probe step);
+        # device join engine (core/join/): 'device' attaches the fused
+        # insert+probe engine to eligible stream-stream window joins
+        # (pipeline/fusion-eligible; a keyed join probes a key-ordered
+        # view of the other side, its work and output following its
+        # matches, and its time windows grow with their content);
         # 'legacy' keeps the reference synchronous broadcast-probe path
         # wholesale. Key siddhi_tpu.join_engine.
         self.join_engine = "device"
-        # build-side hash partitions per join side (pow2, clamped to 64);
-        # partition-local probes cut the [N, W] probe surface ~P-fold.
-        # 0 = auto: 8 on accelerator backends, 1 on the CPU fallback —
-        # the directory's gathers + emission-order sort lose to the
-        # vectorized broadcast compare on a scalar core (a CPU timing;
-        # AUTO is not measured on the chip: PERF.md), while P = 1 keeps
-        # the fused in-state step (pipeline/fusion/mesh eligibility) at
-        # legacy speed. An
-        # explicit value is always honored. Key siddhi_tpu.join_partitions.
+        # the device join engine keeps no hash-partitioned directory
+        # since its keyed probe reads a key-ordered view of the ring: 0,
+        # no partitions, and no knob sets it. The benchmark records it
+        # among the engine knobs of every result line
+        # (benchmarks/drive.py engine_knobs).
         self.join_partitions = 0
-        # per-partition sub-window slack factor: each [P, W*slack/P]
-        # sub-window tolerates key skew up to slack/P of the ring before
-        # adaptive growth (or, with growth off, a partition overflow
-        # naming this knob). Key siddhi_tpu.join_partition_slack.
-        self.join_partition_slack = 2
-        # adaptive sub-window growth (PanJoin re-partitioning): the host
-        # mirrors each side's ring occupancy and grows Wp (capped at
-        # pow2(W)) before a skewed batch could overflow a partition. Off
-        # = static provisioning; overflow becomes FatalQueryError naming
-        # siddhi_tpu.join_partition_slack. Key
-        # siddhi_tpu.join_partition_grow.
-        self.join_partition_grow = True
         # multicore ingest front door (core/stream/input/pack_pool.py):
         # ingest_pool > 0 shards HostBatch pack/encode work across that
         # many worker threads as sequence-numbered sub-batches with an

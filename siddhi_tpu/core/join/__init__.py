@@ -1,21 +1,22 @@
-"""Device-resident partitioned join engine (see ``engine.py``).
+"""Device-resident join engine (see ``engine.py``).
 
 ``attach_join_engine`` is the single planner hook: it classifies a
 freshly-built ``JoinQueryRuntime`` (engine-eligible / pipeline-eligible /
 legacy), instantiates the engine for eligible shapes, and registers the
-join observability surface (``siddhi_join_partition_rows`` occupancy
-gauges + ``siddhi_join_probe_ms`` / ``siddhi_join_insert_ms``
-histograms, exported by ``observability/export.py``)."""
+join observability surface (each mirrored side's
+``siddhi_join_window_rows`` / ``siddhi_join_hot_key_rows`` gauges, the
+``join.<query>.out_width`` and ``window.<query>.capacity`` gauges +
+``siddhi_join_probe_ms`` / ``siddhi_join_insert_ms`` histograms,
+exported by ``observability/export.py``)."""
 
 from __future__ import annotations
 
 from siddhi_tpu.core.join.engine import (  # noqa: F401 — public surface
     ENGINE_STATE_KEYS,
-    PIDX_KEYS,
     SEQ_KEY,
     DeviceJoinEngine,
     engine_ineligibility,
-    extract_partition_keys,
+    extract_join_keys,
     pipeline_ineligibility,
 )
 
@@ -42,11 +43,11 @@ def attach_join_engine(rt, on_expr) -> None:
         return
     if rt.engine_reason is not None:
         return
-    pspec = extract_partition_keys(
+    pspec = extract_join_keys(
         on_expr, rt.sides["left"], rt.sides["right"], rt.dictionary) \
         if on_expr is not None else None
     rt.engine = DeviceJoinEngine(rt, pspec)
-    rt._instr_spec = None   # engine suffix (seq + fills) joins the spec
+    rt._instr_spec = None   # engine suffix (seq + matches) joins the spec
     _register_metrics(rt)
 
 
@@ -60,15 +61,17 @@ def _register_metrics(rt) -> None:
     tel.histogram(f"join.probe_ms.{rt.name}")
     tel.histogram(f"join.insert_ms.{rt.name}")
     eng = rt.engine
-    for side_key, plan in eng.plans.items():
-        if not plan.use_pidx:
-            continue
-        for p in range(eng.P):
-            # zero-pull gauge backend: partition_occupancy reads the
-            # last DRAINED fill.<side> instrument lanes (host ring
-            # mirror when instruments are off) — a scrape never touches
-            # device state (observability/instruments.py)
-            tel.gauge(
-                f"join.partition_rows.{rt.name}.{side_key}.{p}",
-                lambda e=eng, s=side_key, i=p: float(
-                    e.partition_occupancy(s)[i]))
+    # the keyed probe's output width (the last step's program) and the
+    # slots of a growing time-window ring
+    tel.gauge(f"join.{rt.name}.out_width", lambda e=eng: float(e.last_width))
+    if any(p.grows for p in eng.plans.values()):
+        tel.gauge(f"window.{rt.name}.capacity",
+                  lambda e=eng: float(e.ring_capacity))
+    for side_key in eng.rows:
+        # the live rows of each mirrored side, and of its most frequent
+        # key (skew), from the host mirrors: a scrape never touches
+        # device state
+        tel.gauge(f"join.window_rows.{rt.name}.{side_key}",
+                  lambda e=eng, s=side_key: float(e.window_rows(s)))
+        tel.gauge(f"join.hot_key_rows.{rt.name}.{side_key}",
+                  lambda e=eng, s=side_key: float(e.hot_key_rows(s)))

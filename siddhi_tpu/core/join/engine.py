@@ -1,51 +1,61 @@
-"""Device-resident partitioned join engine (PanJoin on device).
+"""Device-resident join engine.
 
-The legacy probe path (``core/query/join_runtime.build_side_step_fn``)
-evaluates the ``on`` condition as one ``[N, W]`` broadcast compare of the
-N trigger rows against the other side's whole W-slot ring and then
-materializes every ``[N, W+1]`` joined column. This module replaces that
-probe surface for eligible stream-stream window joins with a
-PanJoin-style partitioned sub-structure ("A Partition-based Adaptive
-Stream Join", PAPERS.md): each side's build state is indexed by a
-hash-partitioned ``[P, W/P]`` sub-window directory with per-partition
-occupancy, and a trigger row gathers ONLY its own hash partition of the
-other side — the condition evaluates on ``[N, Wp]`` and the join
-materializes ``[N, Wp+1]`` instead of ``[N, W+1]``, a ~P-fold cut of the
-probe surface. One jitted step per arriving chunk performs
-insert-into-own-side + the masked partition-local probe of the other
-side, and stamps an explicit cross-stream sequence number into the meta
-so left/right batches have a total order the CompletionPump can respect
+One jitted step per arriving chunk inserts the chunk into its own side's
+window and probes the other side's window, and stamps an explicit
+cross-stream sequence number into the meta so that left and right
+batches have a total order the CompletionPump can respect
 (``join_runtime._pipeline_ok``).
 
-Bit-identity with the legacy path (``tools/quick_join_check.py``) is
-preserved by construction:
+**The keyed probe.** Where the ``on`` condition has an equality conjunct
+over hashable key types (int/long/bool/string; floats keep the broadcast
+compare: ``-0.0 == 0.0`` and NaN break equal values => equal keys) and
+the host mirrors the probed side's ring exactly (a length or time window
+with no filter or transform: ``_RowMirror``), a probe does work and
+writes output in proportion to its matches, not to N x W:
 
-- the sub-window directory stores each member's global arrival sequence
-  number (``gseq``); the member's legacy ring slot is ``gseq % W`` and
-  its liveness is ``gseq >= floor`` (length windows: ``total - W``; time
-  windows: ``expired_upto``) — the directory enumerates exactly the rows
-  ``WindowStage.contents`` would, just partition-major;
-- matched pairs re-sort by an explicit emission-order key
-  ``trigger_row * (W + 1) + legacy_slot`` (one-sided/outer rows take
-  slot ``W``), reproducing the legacy row-major ``[N, W+1]`` order
-  exactly — the PR-7 okey convention applied within one step.
+- a key-ordered view of the probed ring: its slots sorted by (key, rank),
+  the rank being the row's arrival order among the slots (dead rows and
+  rows with a null key rank after every live one);
+- each trigger row's range of matching rows in that view (a binary
+  search), which is its key's live rows, oldest first;
+- one expansion of those ranges into ``width`` output rows, trigger row
+  after trigger row, where ``width`` is a power of two the host knows is
+  enough BEFORE dispatch: its mirrors count every key's live rows, so
+  the batch's candidate pairs are known exactly (``prepare_batch``).
+  The width is a high-water mark: it grows (at least ``_GROWTH`` times
+  where the probed ring grew since, its window filling), it never
+  shrinks, so a steady stream compiles no new program.
 
-Partitioning engages only when the ``on`` condition carries an equality
-conjunct over hashable key types (int/long/bool/string — floats keep the
-broadcast compare: ``-0.0 == 0.0`` and NaN would break the equal-values
-=> equal-hash invariant); without one the engine runs the same fused
-step with the legacy-layout probe (P = 1), which is what keeps the
-pipeline/fusion eligibility wins independent of the probe pruning.
+The rest of the ``on`` condition is evaluated on the expanded pairs. The
+emission order is the reference's: trigger row, then the probed rows in
+their arrival order (``JoinProcessor`` walks the findable window oldest
+first), then the trigger's one-sided row (outer join, RESET). The
+device scope is ``siddhi.join_probe`` inside ``siddhi.state``; the pairs
+a step emitted ride the meta as the ``join_matches`` lane.
+
+**Growing time windows.** A time window's live rows depend on the
+traffic, not the query, so a mirrored time side's ring grows with its
+content: before a batch is dispatched, the host mirror says how many rows
+will be live after it, and a ring too small is re-laid into the power of
+two that holds them and at least ``_GROWTH`` times the ring
+(``TimeWindowStage.grown``, leaf by leaf, under a ``siddhi.grow``
+span), so it never overflows.
+
+Every other shape (no equality key, a filtered or transformed side, a
+window-less other side) probes the other side's whole window with the
+broadcast ``[N, W]`` compare of the legacy path, read oldest first.
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from siddhi_tpu.core.plan.selector_plan import FLUSH_KEY, GK_KEY, STR_RANK
 from siddhi_tpu.observability.instruments import (META_SCOPE, SELECT_SCOPE,
@@ -59,14 +69,24 @@ from siddhi_tpu.query_api.definitions import AttrType
 _LOG = logging.getLogger("siddhi_tpu.join.engine")
 
 CURRENT, EXPIRED, TIMER, RESET = 0, 1, 2, 3
-_BIG = np.int64(2 ** 62)
+# the keyed probe's least output width: a few hundred pairs compile no
+# program of their own (a width is a program; 1,024 rows cost nothing)
+_MIN_WIDTH = 1024
+# the least factor by which a growing time ring (and the width of a keyed
+# probe of it) grows: each size is a program of every side step that reads
+# it, so a window that fills from empty passes through few of them (a 10 s
+# window at 262,144 rows/s: rings of 65,536, 524,288 and 4,194,304 slots)
+_GROWTH = 8
 
-# state keys of the per-side partition directories + the cross-stream
-# sequence counter — stripped from snapshots (canonical capture is the
-# legacy ring layout) and rebuilt at restore (rebuild_probe_state)
-PIDX_KEYS = ("lpidx", "rpidx")
+# device scope of the keyed probe (inside STATE_SCOPE): the sort of the
+# probed ring, the range search and the expansion into matched pairs
+PROBE_SCOPE = "siddhi.join_probe"
+
+# state key of the cross-stream sequence counter — stripped from
+# snapshots (canonical capture is the legacy ring layout) and reset at
+# restore (rebuild_probe_state)
 SEQ_KEY = "jseq"
-ENGINE_STATE_KEYS = PIDX_KEYS + (SEQ_KEY,)
+ENGINE_STATE_KEYS = (SEQ_KEY,)
 
 _HASHABLE = (AttrType.INT, AttrType.LONG, AttrType.BOOL, AttrType.STRING)
 
@@ -140,13 +160,14 @@ def pipeline_ineligibility(rt) -> Optional[str]:
 
 # ---------------------------------------------------- equality extraction
 
-def extract_partition_keys(on_expr, left, right, dictionary):
+def extract_join_keys(on_expr, left, right, dictionary):
     """Find an equality conjunct ``<left-side expr> == <right-side expr>``
     in the ``on`` condition (top level, or one conjunct of a top-level
     And) whose two values are hashable types, and compile each side's
     value closure against that side's OWN (unprefixed) columns. Returns
-    ``{"left": fn, "right": fn}`` or None. Both closures cast to the
-    promoted dtype before hashing so equal values always co-partition."""
+    ``{"left": fn, "right": fn, "dtype": int32 | int64}`` or None (int64
+    where a side's key is a long). Both closures' values are compared as
+    integers, so equal values always sort together."""
     from siddhi_tpu.core.plan.resolvers import SingleStreamResolver
     from siddhi_tpu.ops.expressions import compile_expr
     from siddhi_tpu.ops.types import promote
@@ -215,6 +236,9 @@ def extract_partition_keys(on_expr, left, right, dictionary):
                 promote(types["left"], types["right"])
             except Exception:  # noqa: BLE001 — incomparable types
                 return None
+        # the keyed probe sorts and searches keys in this width
+        fns["dtype"] = (np.int64 if AttrType.LONG in types.values()
+                        else np.int32)
         return fns
 
     hit = try_eq(on_expr)
@@ -228,33 +252,63 @@ def extract_partition_keys(on_expr, left, right, dictionary):
     return None
 
 
-# ------------------------------------------------------------ hashing
+# ------------------------------------------------------------ the probe
 
-_MIX1 = 0xBF58476D1CE4E5B9
-_MIX2 = 0x94D049BB133111EB
+def _lower_bound(sorted_cols, queries):
+    """For each query, the first position of the lexicographically sorted
+    columns ``sorted_cols`` not below it (``queries``: one array a column):
+    a binary search of as many steps as the length has bits, all queries
+    at once. (``jnp.searchsorted`` right after the sort that feeds it
+    compiles three times as long on the TPU: 70 s against 25 s at 4 M.)"""
+    n = sorted_cols[0].shape[0]
+    lo = jnp.zeros(queries[0].shape, jnp.int32)
+    hi = jnp.full(queries[0].shape, n, jnp.int32)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        at = jnp.minimum(mid, n - 1)
+        below = jnp.zeros(lo.shape, bool)
+        equal = jnp.ones(lo.shape, bool)
+        for col, q in zip(sorted_cols, queries):
+            v = col[at]
+            below = below | (equal & (v < q))
+            equal = equal & (v == q)
+        go = lo < hi
+        return (jnp.where(go & below, mid + 1, lo),
+                jnp.where(go & ~below, mid, hi))
+
+    return lax.fori_loop(0, n.bit_length(), halve, (lo, hi))[0]
 
 
-def hash_partition_dev(vals, P: int):
-    """splitmix64 finalizer -> partition id [0, P) (device). P pow2."""
-    h = jnp.asarray(vals).astype(jnp.int64).astype(jnp.uint64)
-    h = (h ^ (h >> jnp.uint64(30))) * jnp.uint64(_MIX1)
-    h = (h ^ (h >> jnp.uint64(27))) * jnp.uint64(_MIX2)
-    h = h ^ (h >> jnp.uint64(31))
-    return (h & jnp.uint64(P - 1)).astype(jnp.int32)
+def _key_ranges(okey, orank, tkey, tbase, Wo: int):
+    """The probed ring sorted by (key, rank), and each trigger's range in
+    it: (sorted ranks, lo, hi), the range of trigger i being the rows of
+    key ``tkey[i]`` whose rank lies in ``[tbase[i], tbase[i] + Wo)``. A
+    32-bit key and its rank pack into one int64 and sort alone (a
+    one-operand sort compiles in half the time of a two-operand one on
+    the TPU); a long key sorts as two operands."""
+    n = tkey.shape[0]
+    lo_hi = jnp.concatenate([tbase, tbase + Wo])
+    if okey.dtype == jnp.int32:
+        packed = lax.sort((okey.astype(jnp.int64) << 32) + orank)
+        srank = (packed & 0xFFFFFFFF).astype(jnp.int32)
+        bounds = _lower_bound(
+            (packed,), ((jnp.concatenate([tkey, tkey]).astype(jnp.int64)
+                         << 32) + lo_hi,))
+    else:
+        skey, srank = lax.sort((okey, orank), num_keys=2)
+        bounds = _lower_bound((skey, srank),
+                              (jnp.concatenate([tkey, tkey]), lo_hi))
+    return srank, bounds[:n], bounds[n:]
 
 
-def hash_partition_np(vals, P: int):
-    """Host mirror of ``hash_partition_dev`` — MUST stay bit-identical
-    (snapshot rebuild re-partitions the restored rings with it)."""
-    h = np.asarray(vals).astype(np.int64).astype(np.uint64)
-    h = (h ^ (h >> np.uint64(30))) * np.uint64(_MIX1)
-    h = (h ^ (h >> np.uint64(27))) * np.uint64(_MIX2)
-    h = h ^ (h >> np.uint64(31))
-    return (h & np.uint64(P - 1)).astype(np.int32)
+def _other(side_key: str) -> str:
+    return "right" if side_key == "left" else "left"
 
 
-def _pow2(n: int, start: int = 1) -> int:
-    k = max(start, 1)
+def _pow2(n: int) -> int:
+    k = 1
     while k < n:
         k *= 2
     return k
@@ -263,14 +317,15 @@ def _pow2(n: int, start: int = 1) -> int:
 # ------------------------------------------------------------ side plans
 
 class _SidePlan:
-    """Per-side partition-directory parameters (``use_pidx`` False =
-    this side keeps the legacy-layout probe surface)."""
+    """Per-side parameters: the window kind and ring size, and whether
+    the host mirrors the ring (``mirrored``) and so may grow it
+    (``grows``: a time window)."""
 
-    __slots__ = ("kind", "W", "use_pidx", "Wp", "key_fn", "pidx_key",
-                 "win_key")
+    __slots__ = ("kind", "W", "key_fn", "win_key", "stage", "mirrored",
+                 "grows")
 
-    def __init__(self, side_key: str, side, pspec, P: int, slack: int):
-        stage = side.window_stage
+    def __init__(self, side_key: str, side, pspec):
+        stage = self.stage = side.window_stage
         if isinstance(stage, LengthWindowStage):
             self.kind, self.W = "length", int(stage.length)
         elif isinstance(stage, TimeWindowStage):
@@ -278,412 +333,425 @@ class _SidePlan:
         else:
             self.kind, self.W = "none", 1
         self.win_key = "lwin" if side_key == "left" else "rwin"
-        self.pidx_key = "lpidx" if side_key == "left" else "rpidx"
         self.key_fn = pspec[side_key] if pspec is not None else None
-        # partitioning pays only when the ring meaningfully exceeds the
-        # partition count (tiny rings keep the full-surface probe), and
-        # engages only when the host can mirror the ring's partition
-        # occupancy EXACTLY for the adaptive sub-window growth: every
-        # valid CURRENT row inserts at slot seq % W (length AND time
-        # rings share that mechanic), so in-step filters/transforms —
-        # which drop or rewrite rows device-side — keep the full-surface
-        # probe (still fused, pipelined and fusion-eligible)
-        self.use_pidx = (self.kind != "none" and self.key_fn is not None
-                         and P > 1 and self.W >= 4 * P
+        # the host can follow the ring row for row only where every valid
+        # CURRENT row goes in: in-step filters/transforms drop or rewrite
+        # rows device-side
+        self.mirrored = (self.kind != "none" and self.key_fn is not None
                          and not side.filters and not side.transforms)
-        self.Wp = (_pow2((self.W * slack + P - 1) // P)
-                   if self.use_pidx else 0)
-
-    # liveness floor: members with gseq >= floor are exactly the rows the
-    # legacy contents() view reports live
-    def live_floor(self, win_state):
-        if self.kind == "length":
-            return jnp.maximum(win_state["total"] - self.W, jnp.int64(0))
-        return jnp.maximum(win_state["expired_upto"], jnp.int64(0))
+        self.grows = self.mirrored and self.kind == "time"
 
     def live_floor_np(self, win_state):
+        """The sequence number of the oldest row still in the window: the
+        rows the ``contents()`` view reports live are those at or above
+        it."""
         if self.kind == "length":
             return max(int(win_state["total"]) - self.W, 0)
         return max(int(win_state["expired_upto"]), 0)
 
 
+class _Vocab:
+    """Dense ids for key values, shared by both sides: a value keeps the
+    id it was first given, so per-key counts never move."""
+
+    def __init__(self):
+        self._vals = np.zeros(0, np.int64)     # sorted values
+        self._ids = np.zeros(0, np.int64)      # their ids
+        self.n = 0
+
+    def ids(self, vals) -> np.ndarray:
+        vals = np.asarray(vals, np.int64)
+        pos = np.searchsorted(self._vals, vals)
+        hit = (self._vals[np.minimum(pos, self.n - 1)] == vals if self.n
+               else np.zeros(vals.shape, bool))
+        if not hit.all():
+            new = np.unique(vals[~hit])
+            allv = np.concatenate([self._vals, new])
+            alli = np.concatenate([self._ids, np.arange(
+                self.n, self.n + len(new), dtype=np.int64)])
+            order = np.argsort(allv, kind="stable")
+            self._vals, self._ids = allv[order], alli[order]
+            self.n += len(new)
+            pos = np.searchsorted(self._vals, vals)
+        return self._ids[pos]
+
+
+def _key_ids(plan, vocab: _Vocab, cols, rows) -> np.ndarray:
+    """Key ids of the ``rows`` (a mask) of a host batch, by the side's
+    own key closure; -1 where the key is null."""
+    B = rows.shape[0]
+    vals, mask = plan.key_fn(cols, {"xp": np})
+    vals = np.broadcast_to(np.asarray(vals), (B,))[rows]
+    null = (np.broadcast_to(np.asarray(mask, bool), (B,))[rows]
+            if mask is not None else np.zeros(len(vals), bool))
+    ids = np.full(len(vals), -1, np.int64)
+    ids[~null] = vocab.ids(vals[~null])
+    return ids
+
+
+class _RowMirror:
+    """Host copy of one side's window as the device's ring holds it: each
+    live row's key id (-1: a null key) and, for a time window, its clock,
+    oldest first, and the live rows per key. ``advance`` applies a step's
+    own bookkeeping (a length window keeps its last ``W`` rows; a time
+    window advances its expired prefix by the count of the device's
+    ``apply``), so ``held`` and ``counts`` are exactly what the device
+    holds after the step, with no pull."""
+
+    def __init__(self, plan, vocab: _Vocab):
+        self.plan = plan
+        self.vocab = vocab
+        # [ids, clock or None, the clock's least]: a chunk a step
+        self.chunks = collections.deque()
+        self.held = 0
+        self.total = 0
+        self.counts = np.zeros(0, np.int64)  # live non-null rows per id
+        self.nulls = 0                       # live null-key rows
+
+    def count_of(self, ids) -> int:
+        """Live rows that share a key with each of ``ids``, summed: the
+        candidate pairs of trigger rows with those keys."""
+        ids = np.asarray(ids, np.int64)
+        known = ids[(ids >= 0) & (ids < len(self.counts))]
+        return int(self.counts[known].sum()
+                   + self.nulls * np.count_nonzero(ids < 0))
+
+    def _add(self, ids, sign: int) -> None:
+        real = ids[ids >= 0]
+        if len(self.counts) < self.vocab.n:
+            self.counts = np.concatenate([self.counts, np.zeros(
+                self.vocab.n - len(self.counts), np.int64)])
+        self.counts += sign * np.bincount(real, minlength=len(self.counts))
+        self.nulls += sign * int(np.count_nonzero(ids < 0))
+
+    def advance(self, ids, clock, now: int) -> np.ndarray:
+        """Insert one step's valid CURRENT rows (``ids``, ``clock``: the
+        rows' timestamps, or the clock attribute of an externalTime
+        window) and expire what the step's window expires at ``now``;
+        returns the ids of the rows that left, oldest first (the rows the
+        window emits as EXPIRED)."""
+        plan = self.plan
+        n = len(ids)
+        if plan.kind == "time":
+            n_exp = self._time_expiries(clock, now)
+        if n:
+            self.chunks.append(
+                [ids, clock, int(clock.min())] if plan.kind == "time"
+                else [ids, None, None])
+            self._add(ids, +1)
+            self.held += n
+            self.total += n
+        if plan.kind == "length":
+            n_exp = self.held - min(self.total, plan.W)
+        return self._drop(n_exp)
+
+    def _time_expiries(self, clock, now: int) -> int:
+        """How many rows the time window's ``apply`` expires in this step:
+        held rows whose clock is past the cutoff, and batch rows past it
+        with a later valid row (``TimeWindowStage.apply_current``)."""
+        stage = self.plan.stage
+        t = int(stage.time_ms)
+        if stage.external:
+            cutoff = int(clock.max()) - t if len(clock) else -(2 ** 62)
+        else:
+            cutoff = now - t
+        n = sum(int(np.count_nonzero(clock_c <= cutoff))
+                for _ids, clock_c, least in self.chunks if least <= cutoff)
+        if len(clock) > 1:
+            if stage.external:
+                later = np.maximum.accumulate(clock[::-1])[::-1][1:]
+                n += int(np.count_nonzero(later >= clock[:-1] + t))
+            else:
+                n += int(np.count_nonzero(clock[:-1] <= cutoff))
+        return n
+
+    def _drop(self, n: int) -> np.ndarray:
+        out = []
+        while n > 0:
+            ids_c, clock_c, _least = self.chunks[0]
+            k = min(n, len(ids_c))
+            out.append(ids_c[:k])
+            if k == len(ids_c):
+                self.chunks.popleft()
+            elif clock_c is None:
+                self.chunks[0] = [ids_c[k:], None, None]
+            else:
+                self.chunks[0] = [ids_c[k:], clock_c[k:],
+                                  int(clock_c[k:].min())]
+            n -= k
+        gone = (np.concatenate(out) if out else np.zeros(0, np.int64))
+        if len(gone):
+            self._add(gone, -1)
+            self.held -= len(gone)
+        return gone
+
+    def reset_from(self, ids, clock, total: int) -> None:
+        """Start over from the rows a restored ring holds, oldest first."""
+        self.chunks.clear()
+        self.counts = np.zeros(0, np.int64)
+        self.nulls = self.held = 0
+        self.total = total
+        if len(ids):
+            self.chunks.append([ids, clock, None if clock is None
+                                else int(clock.min())])
+            self._add(ids, +1)
+            self.held = len(ids)
+
+
 class DeviceJoinEngine:
-    """Owns the per-side partition plans and builds the fused
+    """Owns the per-side plans and row mirrors and builds the fused
     insert+probe step of each side (``JoinQueryRuntime`` delegates its
     ``build_side_step_fn`` here when attached)."""
 
     def __init__(self, runtime, pspec):
         self.rt = runtime
-        ac = runtime.app_context
-        cfg_p = int(getattr(ac, "join_partitions", 0) or 0)
-        if cfg_p <= 0:
-            # auto: partition pruning is meant to pay where gathers are
-            # wide and cheap (accelerators; P=8 there is NOT measured on
-            # a chip yet — PERF.md); the CPU backend keeps the fused
-            # full-surface probe, which holds legacy throughput while
-            # still buying pipeline/fusion/mesh eligibility. A backend
-            # that cannot initialize raises here.
-            import jax
-
-            cfg_p = 1 if jax.default_backend() == "cpu" else 8
-        P = _pow2(cfg_p)
-        self.P = max(1, min(P, 64))
-        self.slack = max(1, int(getattr(ac, "join_partition_slack", 2)))
-        # adaptive sub-window growth (PanJoin's re-partitioning): when a
-        # batch would push one partition's ring occupancy past Wp, the
-        # host grows Wp BEFORE dispatch (capped at pow2(W), where skew
-        # cannot overflow) instead of dying mid-stream. Off = static
-        # provisioning; overflow is then a FatalQueryError naming
-        # siddhi_tpu.join_partition_slack.
-        self.grow = bool(getattr(ac, "join_partition_grow", True))
-        # host mirrors of each side's ring partition occupancy: slot =
-        # seq % W is pure ring mechanics (length AND time rings), so the
-        # mirror is EXACT with zero device pulls — a partition's live
-        # members are a subset of its ring slots, which bounds the
-        # directory pressure (see prepare_batch)
-        self._mirror: Dict[str, dict] = {}
-        # per-side (total, [P] occ) memo of the mirror bincount: the P
-        # registered partition gauges each read one lane, and a scrape
-        # must not pay P ring passes (content-keyed, not time-keyed —
-        # exactness is preserved)
-        self._occ_memo: Dict[str, tuple] = {}
         self.plans: Dict[str, _SidePlan] = {
-            k: _SidePlan(k, runtime.sides[k], pspec, self.P, self.slack)
+            k: _SidePlan(k, runtime.sides[k], pspec)
             for k in ("left", "right")
         }
+        self.key_dtype = pspec["dtype"] if pspec is not None else None
+        # each mirrored side's rows, and each keyed side's output width
+        # (a high-water mark: the power of two its largest batch needed)
+        self.vocab = _Vocab()
+        self.rows: Dict[str, _RowMirror] = {
+            k: _RowMirror(p, self.vocab)
+            for k, p in self.plans.items() if p.mirrored}
+        self.keyed = {k: self._keyed(k) for k in self.plans}
+        self.width = {k: _MIN_WIDTH for k in self.plans}
+        # the probed ring's slots when each side's width was last set
+        self._width_ring = {k: self.plans[_other(k)].W for k in self.plans}
+        self.last_width = 0
+
+    def _keyed(self, side_key: str) -> bool:
+        """Does this side's step probe with the keyed probe? It needs the
+        equality key, the probed side's mirror, this side's keys on the
+        host (no transform), and its triggers known there: rows it emits
+        as EXPIRED are known only where it is mirrored or window-less."""
+        plan = self.plans[side_key]
+        side = self.rt.sides[side_key]
+        other = _other(side_key)
+        return (plan.key_fn is not None and self.plans[other].mirrored
+                and not side.transforms
+                and (plan.mirrored or plan.kind == "none"
+                     or not self.rt.expired_read))
 
     @property
-    def partitioned_probe(self) -> bool:
-        return any(p.use_pidx for p in self.plans.values())
+    def ring_capacity(self) -> int:
+        """Slots of the largest growing ring (the ``window.<query>.
+        capacity`` gauge); 0 where no side grows."""
+        return max((p.W for p in self.plans.values() if p.grows),
+                   default=0)
+
+    def window_rows(self, side_key: str) -> int:
+        """Live rows of a mirrored side's window, from its host mirror
+        (no device pull): the ``join.window_rows`` gauge."""
+        return self.rows[side_key].held
+
+    def hot_key_rows(self, side_key: str) -> int:
+        """Live rows of a mirrored side's most frequent key (a null key
+        counts as one key), from its host mirror: the key skew, the
+        ``join.hot_key_rows`` gauge."""
+        mirror = self.rows[side_key]
+        return max(int(mirror.counts.max(initial=0)), mirror.nulls)
 
     # ------------------------------------------------------------- state
 
-    def init_pidx_state(self) -> dict:
+    def init_state(self) -> dict:
         """Engine-private state keys to merge into the runtime's state
-        pytree (empty directories + the cross-stream sequence)."""
-        st = {SEQ_KEY: jnp.int64(0)}
-        for plan in self.plans.values():
-            if plan.use_pidx:
-                st[plan.pidx_key] = {
-                    "gseq": jnp.full((self.P, plan.Wp), -1, jnp.int64),
-                    "cnt": jnp.zeros((self.P,), jnp.int64),
-                }
-        return st
-
-    def partition_occupancy(self, side_key: str) -> np.ndarray:
-        """Live members per partition of one side ([P] int64) — the
-        ``siddhi_join_partition_rows`` gauge backend. ZERO device pulls
-        by construction (a /metrics scrape must never touch the device,
-        transfer-guard-verified): the primary source is the last drained
-        ``fill.<side>`` instrument lanes, which the step computes from
-        the directory it already holds and ships on the meta pull that
-        happens anyway (``observability/instruments.py``); with
-        instruments off (``profile_device_instruments: false``) the
-        host ring-occupancy mirror answers instead — exact for length
-        rings, an upper bound for time rings whose expired rows linger
-        in their slots until overwritten."""
-        plan = self.plans[side_key]
-        if not plan.use_pidx:
-            return np.zeros(self.P, np.int64)
-        last = getattr(self.rt, "_instr_last", {}).get(f"fill.{side_key}")
-        if last is not None and np.asarray(last).shape[0] == self.P:
-            return np.asarray(last, np.int64)
-        mir = self._mirror.get(side_key)
-        if mir is None:
-            return np.zeros(self.P, np.int64)
-        memo = self._occ_memo.get(side_key)
-        if memo is not None and memo[0] == mir["total"]:
-            return memo[1]
-        ring = mir["ring"]
-        occ = np.bincount(ring[ring >= 0],
-                          minlength=self.P).astype(np.int64)[: self.P]
-        self._occ_memo[side_key] = (mir["total"], occ)
-        return occ
+        pytree: the cross-stream sequence."""
+        return {SEQ_KEY: jnp.int64(0)}
 
     # ------------------------------------------------------ restore path
 
     def rebuild_probe_state(self) -> None:
-        """Re-derive the partition directories (and host occupancy
-        mirrors) from the (canonical) ring state after a snapshot
-        restore — the snapshot stores only the legacy ``[W]`` ring layout
-        (``strip_engine_state``), so a legacy revision restores into the
-        engine and vice versa bit-identically. Live rows re-insert in
-        global-sequence order; partition offsets may differ from the
-        never-restored trajectory, but probe results cannot (membership
-        and ``gseq`` are identical)."""
+        """Re-derive the host row mirrors from the (canonical) ring state
+        after a snapshot restore — the snapshot stores only the legacy
+        ``[W]`` ring layout (``strip_engine_state``), so a legacy revision
+        restores into the engine and vice versa bit-identically."""
         if self.rt._state is None:
             return
-        for side_key in self.plans:
-            self._rebuild_side(side_key)
+        for side_key in self.rows:
+            self._rebuild_rows(side_key)
+        self.rt._steps.clear()
         state = dict(self.rt._state)
         if SEQ_KEY not in state:
             state[SEQ_KEY] = jnp.int64(0)
         self.rt._state = state
 
-    def _ring_partitions(self, plan, win) -> np.ndarray:
-        """Partition id of every OCCUPIED ring slot of one side ([W]
-        int32, -1 = empty) — hashed from the ring's own buffered values,
-        host-side."""
-        total = int(np.asarray(win["total"]))
-        filled = min(total, plan.W)
-        ring_p = np.full(plan.W, -1, np.int32)
-        if filled:
-            buf = {k: np.asarray(v) for k, v in win["buf"].items()}
-            vals, mask = plan.key_fn(buf, {"xp": np})
-            vals = np.broadcast_to(np.asarray(vals), (plan.W,))
-            pr = hash_partition_np(vals, self.P).astype(np.int32)
-            if mask is not None:
-                pr = np.where(
-                    np.broadcast_to(np.asarray(mask, bool), (plan.W,)),
-                    np.int32(0), pr)
-            ring_p[:filled] = pr[:filled]
-        return ring_p
-
-    def _rebuild_side(self, side_key: str) -> None:
-        """Rebuild ONE side's directory + host mirror from its ring
-        (restore path and adaptive growth). Auto-sizes Wp up to pow2(W)
-        when the restored ring is hotter than the current sub-windows
-        (growth on); with growth off an unrepresentable ring is fatal,
-        naming the static knob."""
-        from siddhi_tpu.core.stream.junction import FatalQueryError
-
-        plan = self.plans[side_key]
-        if not plan.use_pidx or self.rt._state is None:
-            return
-        state = dict(self.rt._state)
-        win = state[plan.win_key]
-        win_h = {k: np.asarray(v) for k, v in win.items()
-                 if k in ("total", "expired_upto")}
-        total = int(np.asarray(win["total"]))
-        ring_p = self._ring_partitions(plan, win)
-        occ = np.bincount(ring_p[ring_p >= 0], minlength=self.P)
-        need = int(occ.max(initial=0))
-        if need > plan.Wp and self.grow:
-            plan.Wp = min(_pow2(2 * need), _pow2(plan.W))
-        floor = plan.live_floor_np(win_h)
-        gseqs = np.arange(floor, total, dtype=np.int64)
-        gseq_grid = np.full((self.P, plan.Wp), -1, np.int64)
-        cnt = np.zeros(self.P, np.int64)
-        if gseqs.size:
-            slots = (gseqs % plan.W).astype(np.int64)
-            p = ring_p[slots].astype(np.int64)
-            for i in range(gseqs.size):     # gseq-ascending fill
-                pi = int(p[i])
-                if cnt[pi] >= plan.Wp:
-                    raise FatalQueryError(
-                        f"query '{self.rt.name}': "
-                        f"{self.rt.overflow_knob_msg(code=4)}")
-                gseq_grid[pi, cnt[pi]] = gseqs[i]
-                cnt[pi] += 1
-        state[plan.pidx_key] = {"gseq": jnp.asarray(gseq_grid),
-                                "cnt": jnp.asarray(cnt)}
-        self.rt._state = state
-        self._mirror[side_key] = {"ring": ring_p, "total": total}
-
     # ------------------------------------------------- adaptive capacity
 
     def prepare_batch(self, side_key: str, cols) -> bool:
-        """Pre-dispatch host bookkeeping of one side's batch: advance the
-        side's ring-occupancy mirror with the batch's hashed keys and
-        GROW the sub-window capacity BEFORE the step could overflow it —
-        PanJoin's adaptive re-partitioning, keyed off exact ring
-        mechanics (slot = seq % W) with zero device pulls. A partition's
-        live members are always a subset of its ring slots, so
-        ``Wp >= max ring occupancy`` makes directory overflow impossible.
-        Returns True when capacities changed (the runtime's compiled
-        side steps were dropped; fused groups must drop theirs too)."""
+        """Pre-dispatch host bookkeeping of one side's batch (a TIMER
+        batch included): advance this side's row mirror by the step's own
+        bookkeeping, grow its ring BEFORE the step could overflow it, and
+        make sure the keyed probe's output width holds every candidate
+        pair of the batch: the live rows of the other side that share a
+        key with each trigger row, plus a row for each trigger that may
+        emit a one-sided row. The other side's mirror is exact: its state
+        does not change in this step. Returns True when capacities
+        changed (the runtime's compiled side steps were dropped; fused
+        groups must drop theirs too)."""
         plan = self.plans[side_key]
-        if not plan.use_pidx:
+        mirror = self.rows.get(side_key)
+        keyed = self.keyed[side_key]
+        if mirror is None and not keyed:
             return False
-        valid = (np.asarray(cols[VALID_KEY], bool)
-                 & (np.asarray(cols[TYPE_KEY]) == CURRENT))
-        n = int(valid.sum())
-        if n == 0:
-            return False
-        B = valid.shape[0]
-        hvals, hmask = plan.key_fn(cols, {"xp": np})
-        hvals = np.broadcast_to(np.asarray(hvals), (B,))
-        p = hash_partition_np(hvals, self.P).astype(np.int32)
-        if hmask is not None:
-            p = np.where(np.broadcast_to(np.asarray(hmask, bool), (B,)),
-                         np.int32(0), p)
-        p = p[valid]
-        mir = self._mirror.get(side_key)
-        if mir is None:
-            mir = self._mirror[side_key] = {
-                "ring": np.full(plan.W, -1, np.int32), "total": 0}
-        W = plan.W
-        ring = mir["ring"]
-        if n >= W:
-            slots = (mir["total"] + np.arange(n - W, n)) % W
-            ring[:] = -1
-            ring[slots] = p[n - W:]
-        else:
-            slots = (mir["total"] + np.arange(n)) % W
-            ring[slots] = p
-        mir["total"] += n
-        occ = np.bincount(ring[ring >= 0], minlength=self.P)
-        need = int(occ.max(initial=0))
-        if need <= plan.Wp or not self.grow:
-            # growth off: the in-step overflow check surfaces the skew as
-            # FatalQueryError naming siddhi_tpu.join_partition_slack
-            return False
-        plan.Wp = min(_pow2(2 * need), _pow2(plan.W))
-        _LOG.info(
-            "query '%s': join partition sub-windows of side %s grown to "
-            "%d (ring occupancy %d) — adaptive re-partition",
-            self.rt.name, side_key, plan.Wp, need)
-        # rebuild the directory from the PRE-batch device ring (the step
-        # inserts this batch into the grown directory), then restore the
-        # batch-advanced mirror — it is the post-dispatch truth
-        self._rebuild_side(side_key)
-        self._mirror[side_key] = mir
-        self.rt._steps.clear()
-        return True
+        cur = (np.asarray(cols[VALID_KEY], bool)
+               & (np.asarray(cols[TYPE_KEY]) == CURRENT))
+        ids = _key_ids(plan, self.vocab, cols, cur)
+        changed = False
+        gone = ids[:0]
+        if mirror is not None:
+            clock = None
+            if plan.kind == "time":
+                ck = plan.stage.ts_key if plan.stage.external else TS_KEY
+                clock = np.asarray(cols[ck], np.int64)[cur]
+            gone = mirror.advance(ids, clock, self.rt._now())
+            if plan.grows and mirror.held > plan.W:
+                # the power of two the rows need, at least _GROWTH times
+                # the ring
+                self._grow_ring(side_key, max(_pow2(mirror.held),
+                                              _GROWTH * plan.W))
+                changed = True
+        if keyed:
+            side = self.rt.sides[side_key]
+            trig, n_reset = self._triggers(side_key, cols, ids, gone)
+            other = self.rows[_other(side_key)]
+            need = n_reset
+            if side.triggers:
+                need += other.count_of(trig)
+                if side.outer:
+                    need += len(trig)
+            width = self.width[side_key]
+            if need > width:
+                # the power of two the pairs need; where the probed ring
+                # grew since, its window is filling and the pairs will
+                # keep growing: at least _GROWTH times the last width
+                ring = self.plans[_other(side_key)].W
+                filling = ring > self._width_ring[side_key]
+                self._width_ring[side_key] = ring
+                self.width[side_key] = max(
+                    _pow2(need), _GROWTH * width if filling else 0)
+                self.rt._steps.pop(side_key, None)
+                changed = True
+            self.last_width = self.width[side_key]
+        return changed
 
-    def _shrink_target(self, side_key: str) -> Optional[tuple]:
-        """(current Wp, shrink target) for one side, or None when the
-        side is already right-sized. The target keeps the same 2x
-        headroom the growth path provisions (``_pow2(2 * need)``) and
-        never drops below the configured-slack initial sizing — the
-        autopilot may only release what adaptive growth added. Host
-        mirror / drained instrument lanes only (zero device pulls)."""
+    def _triggers(self, side_key: str, cols, ids, gone):
+        """(key ids of the rows of this step's window output that probe,
+        RESET rows): its CURRENT rows, and, where anything reads the
+        joined EXPIRED rows, the rows it emits as EXPIRED (those that
+        left a length or time window; a window-less side's clones)."""
         plan = self.plans[side_key]
-        if not plan.use_pidx:
-            return None
-        occ = self.partition_occupancy(side_key)
-        need = int(occ.max(initial=0))
-        floor = _pow2((plan.W * self.slack + self.P - 1) // self.P)
-        target = max(_pow2(2 * need), floor)
-        if target >= plan.Wp:
-            return None
-        return plan.Wp, target
+        stage = plan.stage
+        parts = [ids]
+        n_reset = 0
+        if plan.kind == "none":
+            if stage.empty_window:
+                if stage.expired_needed:
+                    parts.append(ids)
+                if stage.emit_reset:
+                    n_reset = len(ids)
+        elif self.rt.expired_read:
+            parts.append(gone)
+        return np.concatenate(parts), n_reset
 
-    def shrink_candidates(self) -> Dict[str, tuple]:
-        """Read-only autopilot signal: sides whose Wp could shrink back
-        after a skew burst passed — {side: (wp, target)}."""
-        out = {}
-        for side_key in self.plans:
-            t = self._shrink_target(side_key)
-            if t is not None:
-                out[side_key] = t
-        return out
+    def _grow_ring(self, side_key: str, capacity: int) -> None:
+        """Re-lay one time side's ring into ``capacity`` slots, under a
+        ``siddhi.grow`` span, holding the grown state once: the runtime
+        lets go of the old state first, and the ring moves leaf by leaf
+        (``TimeWindowStage.grown``). Both sides' steps re-jit (the other
+        side's probe reads this ring)."""
+        from siddhi_tpu.core.stream.junction import FatalQueryError
+        from siddhi_tpu.observability.tracing import span
 
-    def shrink_partitions(self) -> Dict[str, tuple]:
-        """Release over-provisioned sub-window capacity — the reverse of
-        ``prepare_batch``'s adaptive growth, through the SAME directory
-        rebuild path (so probe membership and gseq order are identical
-        by construction, only the capacity changes). Caller holds the
-        runtime's owner lock; pipelined state futures are safe — the
-        rebuild materializes the logical current state exactly as the
-        growth path does. Returns {side: (old_wp, new_wp)}."""
-        shrunk: Dict[str, tuple] = {}
-        if self.rt._state is None:
-            return shrunk
-        for side_key in self.plans:
-            t = self._shrink_target(side_key)
-            if t is None:
-                continue
-            old_wp, target = t
-            plan = self.plans[side_key]
-            plan.Wp = target
-            # _rebuild_side auto-grows if the ring is hotter than the
-            # occupancy signal suggested — shrink can never overflow
-            self._rebuild_side(side_key)
-            shrunk[side_key] = (old_wp, plan.Wp)
-            _LOG.info(
-                "query '%s': join partition sub-windows of side %s "
-                "shrunk %d -> %d (ring occupancy fell) — autopilot "
-                "re-partition", self.rt.name, side_key, old_wp, plan.Wp)
-        if shrunk:
-            self.rt._steps.clear()
-        return shrunk
+        rt = self.rt
+        plan = self.plans[side_key]
+        before = rt.state_bytes()
+        old = rt._state
+        state = dict(old)
+        rt._state = old = None
+        win = dict(state.pop(plan.win_key))
+        slot_bytes = sum(v.dtype.itemsize for v in win["buf"].values())
+        with span("grow", query=rt.name, side=side_key, from_slots=plan.W,
+                  to_slots=capacity, bytes_before=before,
+                  bytes_after=before + (capacity - plan.W) * slot_bytes
+                  ) as sp:
+            try:
+                state[plan.win_key] = plan.stage.grown(win, capacity)
+            except Exception as e:
+                raise FatalQueryError(
+                    f"query '{rt.name}': growing the {side_key} window's "
+                    f"ring ({plan.W}->{capacity} slots) failed with its "
+                    f"state half moved: {e}") from e
+        plan.stage.capacity = plan.W = capacity
+        rt._state = state
+        rt._steps.clear()
+        rt.note_growth(sp.ms)
+        _LOG.info("query '%s': %s time window ring grown to %d slots",
+                  rt.name, side_key, capacity)
+
+    def _rebuild_rows(self, side_key: str) -> None:
+        """The row mirror of one side from the ring a restore left: its
+        live rows, oldest first (a grown ring restores at its own size)."""
+        plan = self.plans[side_key]
+        win = self.rt._state[plan.win_key]
+        buf = {k: np.asarray(v) for k, v in win["buf"].items()}
+        W = len(next(iter(buf.values())))
+        if plan.grows:
+            plan.stage.capacity = plan.W = W
+        total = int(np.asarray(win["total"]))
+        floor = plan.live_floor_np({k: np.asarray(v) for k, v in win.items()
+                                    if k != "buf"})
+        seq = np.arange(max(floor, total - W, 0), total)
+        slots = seq % W
+        rows = {k: v[slots] for k, v in buf.items()}
+        ids = _key_ids(plan, self.vocab, rows, np.ones(len(seq), bool))
+        clock = None
+        if plan.kind == "time":
+            ck = plan.stage.ts_key if plan.stage.external else TS_KEY
+            clock = rows[ck].astype(np.int64)
+        self.rows[side_key].reset_from(ids, clock, total)
 
     # -------------------------------------------------------- step build
 
     def build_side_step(self, side_key: str):
         """The fused (state, probe_cols, probe_valid, cols, now) ->
         (state', out) step of one side: transforms/filters -> window
-        insert -> post-filters -> directory insert (own side) + masked
-        partition-local probe (other side) -> selector. The signature
-        matches the legacy builder so ``process_side_batch`` stays the
-        single host driver; the probe placeholders are unused (both
-        surfaces live inside the state)."""
+        insert -> post-filters -> the keyed or the broadcast probe of the
+        other side -> selector. The
+        signature matches the legacy step's; the probe placeholders are
+        unused (both surfaces live inside the state), and the runtime
+        jits it as ``(state, cols, now)`` (``join_runtime._inert_probe``)."""
         rt = self.rt
         side = rt.sides[side_key]
-        other_key = "right" if side_key == "left" else "left"
+        other_key = _other(side_key)
         other = rt.sides[other_key]
         splan = self.plans[side_key]
         oplan = self.plans[other_key]
         sel = rt.selector_plan
         on_cond = rt.on_cond
         split = rt.keyer is not None
-        P, slack = self.P, self.slack
-        # device instruments: with the knob on, the step also ships each
-        # partitioned side's per-partition directory fill behind the
-        # sequence lane — the layout JoinQueryRuntime._step_instrument_
-        # slots declares and the drain decodes (captured at build; the
-        # step cache is cleared whenever capacities change)
+        keyed = self.keyed[side_key]
+        width = self.width[side_key]
+        kdt = self.key_dtype
+        expired_read = self.rt.expired_read
+        other_view = getattr(other.window_stage, "arrival_contents",
+                             other.window_stage.contents)
+        # device instruments: with the knob on, the step also ships the
+        # pairs it matched behind the sequence lane — the layout
+        # JoinQueryRuntime._step_instrument_slots declares and the drain
+        # decodes
         ins_on = rt._instruments_on()
 
-        def _meta_suffix(new_state, seq):
-            suffix = [seq.reshape(1)]
-            if ins_on:
-                for plan in (self.plans["left"], self.plans["right"]):
-                    if not plan.use_pidx:
-                        continue
-                    gseq = new_state[plan.pidx_key]["gseq"]
-                    floor = plan.live_floor(new_state[plan.win_key])
-                    suffix.append(jnp.sum(
-                        (gseq >= floor) & (gseq >= 0),
-                        axis=1, dtype=jnp.int64))
-            return suffix
-
-        def _pidx_insert(pidx, cols, win_before, win_after):
-            """Scatter this batch's inserted rows into the side's own
-            partition directory; returns (pidx', overflow_flag)."""
-            valid_cur = cols[VALID_KEY] & (cols[TYPE_KEY] == CURRENT)
-            B = valid_cur.shape[0]
-            total0 = win_before["total"]
-            rank = jnp.cumsum(valid_cur.astype(jnp.int64)) - 1
-            gseq = total0 + rank
-            floor_after = splan.live_floor(win_after)
-            # rows evicted/expired within this very batch never enter the
-            # directory (the legacy ring drops them the same way)
-            ins = valid_cur & (gseq >= floor_after)
-            vals, mask = splan.key_fn(cols, {"xp": jnp})
-            vals = jnp.broadcast_to(jnp.asarray(vals), (B,))
-            p = hash_partition_dev(vals, P).astype(jnp.int64)
-            if mask is not None:
-                p = jnp.where(jnp.broadcast_to(jnp.asarray(mask, bool), (B,)),
-                              jnp.int64(0), p)
-            p = jnp.where(ins, p, jnp.int64(P))          # P = dropped
-            maskp = p[None, :] == jnp.arange(P, dtype=jnp.int64)[:, None]
-            pos = jnp.cumsum(maskp.astype(jnp.int64), axis=1) - 1
-            pc = jnp.clip(p, 0, P - 1).astype(jnp.int32)
-            pos_row = jnp.take_along_axis(pos, pc[None, :], axis=0)[0]
-            n_per = jnp.sum(maskp.astype(jnp.int64), axis=1)
-            off = (pidx["cnt"][pc] + pos_row) % splan.Wp
-            flat = jnp.where(p < P, pc.astype(jnp.int64) * splan.Wp + off,
-                             jnp.int64(P * splan.Wp))
-            gflat = pidx["gseq"].reshape(-1)
-            occupant = gflat[jnp.clip(flat, 0, P * splan.Wp - 1)]
-            # overwriting a LIVE occupant (or >Wp inserts into one
-            # partition this batch) silently drops probe members — fatal,
-            # named knob (join_partition_slack / join_partitions)
-            ov = (jnp.any((flat < P * splan.Wp)
-                          & (occupant >= floor_after) & (occupant >= 0))
-                  | jnp.any(n_per > splan.Wp)).astype(jnp.int32)
-            g2 = gflat.at[flat].set(gseq, mode="drop").reshape(P, splan.Wp)
-            return {"gseq": g2, "cnt": pidx["cnt"] + n_per}, ov
+        def _meta_suffix(seq, n_match):
+            return ([seq.reshape(1), n_match.reshape(1)] if ins_on
+                    else [seq.reshape(1)])
 
         def _materialize(wout, ev, match, one_sided, N, S):
-            """Joined-row materialization shared by BOTH probe branches
-            (partition-gathered and legacy-layout): [N, S] probe
+            """Joined rows of the broadcast probe: [N, S] probe
             candidates + the one-sided column S flatten to row-major
-            [N*(S+1)] columns, the layout the legacy broadcast probe
-            emits — keep this the single source of truth so the two
-            branches cannot drift apart."""
+            [N*(S+1)] columns, the layout the legacy path emits."""
             NW = N * (S + 1)
             joined: Dict[str, jnp.ndarray] = {}
             for a in side.definition.attributes:
@@ -708,6 +776,84 @@ class DeviceJoinEngine:
             joined[FLUSH_KEY] = jnp.repeat(
                 jnp.arange(N, dtype=jnp.int32), S + 1)
             return joined
+
+        def _keys(plan, cols, n, ctx):
+            """A side's key column as the probe sorts it, and its nulls."""
+            vals, mask = plan.key_fn(cols, ctx)
+            vals = jnp.broadcast_to(jnp.asarray(vals), (n,)).astype(kdt)
+            null = (jnp.broadcast_to(jnp.asarray(mask, bool), (n,))
+                    if mask is not None else jnp.zeros((n,), bool))
+            return jnp.where(null, jnp.zeros((), kdt), vals), null
+
+        def _keyed_probe(state, wout, row_live, ctx):
+            """The keyed probe (module docstring): the other side's ring
+            sorted by (key, rank), each trigger row's range in it, and one
+            expansion into ``width`` rows, trigger row by trigger row.
+            Returns (joined columns, pairs matched, width overflow)."""
+            N = wout[VALID_KEY].shape[0]
+            M = width
+            owin = state[oplan.win_key]
+            ring = owin["buf"]
+            Wo = oplan.W
+            tkey, tnull = _keys(splan, wout, N, ctx)
+            base = jnp.where(tnull, Wo, 0).astype(jnp.int32)
+            if side.triggers:
+                seq, live = oplan.stage.ring_seq(owin)
+                rank = (seq - (owin["total"] - Wo)).astype(jnp.int32)
+                okey, onull = _keys(oplan, ring, Wo, ctx)
+                # live rows with a key, then live null keys, then the
+                # dead: a range [base, base + Wo) holds one group alone
+                rank = rank + jnp.where(live, jnp.where(onull, Wo, 0),
+                                        2 * Wo).astype(jnp.int32)
+                srank, lo, hi = _key_ranges(okey, rank, tkey, base, Wo)
+                cnt = jnp.where(row_live, hi - lo, 0)
+            else:
+                srank = jnp.zeros((1,), jnp.int32)
+                lo = cnt = jnp.zeros((N,), jnp.int32)
+            reset = wout[VALID_KEY] & (wout[TYPE_KEY] == RESET)
+            os_ = (row_live & side.outer & side.triggers) | reset
+            blk = cnt + os_.astype(jnp.int32)
+            ends = jnp.cumsum(blk)
+            m = jnp.arange(M, dtype=jnp.int32)
+            # each position's trigger row: the rows that ended at or before
+            # it, a scatter and a running sum (``searchsorted`` of a million
+            # positions took 62 ms on the chip, this 0.4)
+            r = jnp.minimum(jnp.cumsum(
+                jnp.zeros((M + 1,), jnp.int32).at[jnp.minimum(ends, M)].add(1)
+            )[:M], N - 1)
+            j = m - (ends[r] - blk[r])
+            in_range = m < ends[N - 1]
+            is_cand = in_range & (j < cnt[r])
+            is_os = in_range & (j == cnt[r]) & os_[r]
+            pos = jnp.clip(lo[r] + j, 0, srank.shape[0] - 1)
+            slot = ((owin["total"] - Wo + (srank[pos] - base[r]))
+                    % Wo).astype(jnp.int32)
+            ev: Dict[str, jnp.ndarray] = {TS_KEY: wout[TS_KEY][r]}
+            for a in side.definition.attributes:
+                ev[side.prefix + a.name] = wout[a.name][r]
+                ev[side.prefix + a.name + "?"] = wout[a.name + "?"][r]
+            for a in other.definition.attributes:
+                ev[other.prefix + a.name] = ring[a.name][slot]
+                ev[other.prefix + a.name + "?"] = ring[a.name + "?"][slot]
+            cond = (jnp.broadcast_to(on_cond(ev, ctx), (M,))
+                    if on_cond is not None else True)
+            match = is_cand & cond
+            hit = jnp.zeros((N,), jnp.int32).at[r].add(
+                match.astype(jnp.int32)) > 0
+            one_sided = (row_live & ~hit & side.outer & side.triggers) | reset
+            joined = dict(ev)
+            for a in other.definition.attributes:
+                v = joined[other.prefix + a.name]
+                joined[other.prefix + a.name] = jnp.where(
+                    is_os, jnp.zeros((), v.dtype), v)
+                joined[other.prefix + a.name + "?"] = (
+                    joined[other.prefix + a.name + "?"] | is_os)
+            joined[VALID_KEY] = match | (is_os & one_sided[r])
+            joined[TYPE_KEY] = wout[TYPE_KEY][r]
+            joined[GK_KEY] = jnp.zeros((M,), jnp.int32)
+            joined[FLUSH_KEY] = r
+            return (joined, jnp.sum(match, dtype=jnp.int64),
+                    ends[N - 1] > M)
 
         def step(state, probe_cols, probe_valid, cols, current_time):
             ctx = {"xp": jnp, "current_time": current_time}
@@ -739,80 +885,37 @@ class DeviceJoinEngine:
                 pvalid = pvalid & (f(wout, ctx) | ptimer)
             wout[VALID_KEY] = pvalid
 
-            # overflow bitmask: 1 = window ring, 4 = partition sub-window,
-            # 8 = selector (distinctCount) — decoded by
-            # JoinQueryRuntime.overflow_knob_msg into the exact knob
+            # overflow bitmask: 1 = window ring, 8 = selector
+            # (distinctCount), 16 = keyed probe width, 32 =
+            # a growing ring — decoded by JoinQueryRuntime.
+            # overflow_knob_msg into the exact knob (16 and 32 name none:
+            # the host sized both, so either is a bug)
             ovbits = jnp.int32(0)
             if overflow is not None:
                 ovbits = ovbits | jnp.where(
-                    jnp.asarray(overflow).astype(jnp.int32) > 0, 1, 0)
+                    jnp.asarray(overflow).astype(jnp.int32) > 0,
+                    32 if splan.grows else 1, 0)
 
-            with jax.named_scope(STATE_SCOPE):   # directory insert + probe
-                # ---- insert this batch into OUR OWN partition directory
-                if splan.use_pidx:
-                    new_state[splan.pidx_key], pov = _pidx_insert(
-                        state[splan.pidx_key], conformed, win_before, new_win)
-                    ovbits = ovbits | (pov * 4)
-
+            with jax.named_scope(STATE_SCOPE):   # the probe
                 N = wout[VALID_KEY].shape[0]
-                W = oplan.W if oplan.kind != "none" else None
-                row_live = wout[VALID_KEY] & (
-                    (wout[TYPE_KEY] == CURRENT) | (wout[TYPE_KEY] == EXPIRED))
-                gathered = oplan.use_pidx and side.triggers
-
-                if gathered:
-                    # ---- masked partition-local probe: gather only the
-                    # trigger row's hash partition of the other side
-                    opidx = state[oplan.pidx_key]
-                    oring = state[oplan.win_key]["buf"]
-                    ofloor = oplan.live_floor(state[oplan.win_key])
-                    vals, mask = splan.key_fn(wout, ctx)
-                    vals = jnp.broadcast_to(jnp.asarray(vals), (N,))
-                    p_i = hash_partition_dev(vals, P)
-                    if mask is not None:
-                        p_i = jnp.where(
-                            jnp.broadcast_to(jnp.asarray(mask, bool), (N,)),
-                            jnp.int32(0), p_i)
-                    cand_g = opidx["gseq"][p_i]                     # [N, Wp]
-                    cand_live = (cand_g >= ofloor) & (cand_g >= 0)
-                    cand_slot = (jnp.clip(cand_g, 0) % W).astype(jnp.int32)
-                    Wp = oplan.Wp
-                    ev: Dict[str, jnp.ndarray] = {TS_KEY: wout[TS_KEY][:, None]}
-                    for a in other.definition.attributes:
-                        ev[other.prefix + a.name] = oring[a.name][cand_slot]
-                        ev[other.prefix + a.name + "?"] = \
-                            oring[a.name + "?"][cand_slot]
-                    for a in side.definition.attributes:
-                        ev[side.prefix + a.name] = wout[a.name][:, None]
-                        ev[side.prefix + a.name + "?"] = \
-                            wout[a.name + "?"][:, None]
-                    cond = (on_cond(ev, ctx) if on_cond is not None
-                            else jnp.ones((N, Wp), bool))
-                    cond = jnp.broadcast_to(cond, (N, Wp))
-                    match = row_live[:, None] & cand_live & cond
-                    no_match = (row_live & ~jnp.any(match, axis=1)
-                                & side.outer & side.triggers)
-                    one_sided = no_match | (
-                        wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
-                    NW = N * (Wp + 1)
-                    joined = _materialize(wout, ev, match, one_sided, N, Wp)
-                    # emission-order key: (trigger row, LEGACY ring slot) —
-                    # sorting by it reproduces the [N, W+1] row-major order
-                    # of the broadcast probe exactly (one-sided rows at W)
-                    stride = jnp.int64(W + 1)
-                    slot_cols = jnp.concatenate(
-                        [cand_slot.astype(jnp.int64),
-                         jnp.full((N, 1), W, jnp.int64)], axis=1)
-                    okey = (jnp.arange(N, dtype=jnp.int64)[:, None] * stride
-                            + slot_cols).reshape(NW)
-                    okey = jnp.where(joined[VALID_KEY], okey, _BIG)
-                    order = jnp.argsort(okey, stable=True)
-                    joined = {k: v[order] for k, v in joined.items()}
+                if keyed:
+                    # EXPIRED triggers probe only where something reads
+                    # the joined EXPIRED rows (the selector drops them)
+                    live_types = ((wout[TYPE_KEY] == CURRENT)
+                                  | ((wout[TYPE_KEY] == EXPIRED)
+                                     & expired_read))
+                    with jax.named_scope(PROBE_SCOPE):
+                        joined, n_match, wov = _keyed_probe(
+                            state, wout, wout[VALID_KEY] & live_types, ctx)
+                    ovbits = ovbits | (wov.astype(jnp.int32) * 16)
                 else:
-                    # ---- legacy-layout probe (P=1 / untriggering side /
-                    # passthrough other side): identical to the broadcast path
-                    pcols, pvalid_o = other.window_stage.contents(
-                        state[oplan.win_key])
+                    # ---- broadcast probe of the other side's whole
+                    # window, oldest first (no key, a filtered or
+                    # window-less side): the legacy path's surface
+                    row_live = wout[VALID_KEY] & (
+                        (wout[TYPE_KEY] == CURRENT)
+                        | (wout[TYPE_KEY] == EXPIRED))
+                    pcols, pvalid_o = other_view(state[oplan.win_key])
                     Wo = pvalid_o.shape[0]
                     ev = {TS_KEY: wout[TS_KEY][:, None]}
                     for a in other.definition.attributes:
@@ -837,6 +940,7 @@ class DeviceJoinEngine:
                     one_sided = no_match | (
                         wout[VALID_KEY] & (wout[TYPE_KEY] == RESET))
                     joined = _materialize(wout, ev, match, one_sided, N, Wo)
+                    n_match = jnp.sum(match, dtype=jnp.int64)
 
             if strrank is not None:
                 joined[STR_RANK] = strrank
@@ -856,7 +960,7 @@ class DeviceJoinEngine:
                 with jax.named_scope(META_SCOPE):
                     out = pack_meta(joined)
                     out["__meta__"] = jnp.concatenate(
-                        [out["__meta__"]] + _meta_suffix(new_state, seq))
+                        [out["__meta__"]] + _meta_suffix(seq, n_match))
                 return new_state, out
 
             with jax.named_scope(SELECT_SCOPE):
@@ -871,7 +975,7 @@ class DeviceJoinEngine:
             with jax.named_scope(META_SCOPE):
                 out = pack_meta(out)
                 out["__meta__"] = jnp.concatenate(
-                    [out["__meta__"]] + _meta_suffix(new_state, seq))
+                    [out["__meta__"]] + _meta_suffix(seq, n_match))
             return new_state, out
 
         return step

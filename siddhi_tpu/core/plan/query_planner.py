@@ -340,6 +340,11 @@ def plan_join_query(
     # kept for non-triggering or non-aggregating cases
     _needs_reset = bool(query.selector.group_by_list) or _selector_has_aggregator(
         query.selector)
+    # joined EXPIRED rows reach nothing but an expired-events output or an
+    # aggregate that retracts them: where neither is there, a sliding
+    # length or time side emits its CURRENT rows alone (same answers, no
+    # emission sort)
+    expired_read = side_expired_needed or _needs_reset
 
     def _side_triggers(key: str) -> bool:
         return (join.trigger == EventTrigger.ALL
@@ -442,7 +447,8 @@ def plan_join_query(
                 else:
                     window_stage = create_window_stage(
                         h, ext_sdef, resolver, app_context,
-                        expired_needed=side_expired_needed)
+                        expired_needed=side_expired_needed,
+                        expired_read=expired_read)
                 if getattr(window_stage, "host_mode", False):
                     # sort/frequent/... run host-side; emissions trigger the
                     # join, contents() is the probe surface
@@ -593,10 +599,11 @@ def plan_join_query(
         group_keyer=group_keyer,
     )
     rt.index_probe = index_probe
+    rt.expired_read = expired_read
     # classify + attach the device join engine (core/join/): eligible
-    # stream-stream window joins get the PanJoin-style partitioned probe
-    # engine (pipeline/fusion-eligible); everything else keeps the legacy
-    # probe path with the reason recorded on the runtime
+    # stream-stream window joins get the engine's fused insert+probe steps
+    # (pipeline/fusion-eligible); everything else keeps the legacy probe
+    # path with the reason recorded on the runtime
     from siddhi_tpu.core.join import attach_join_engine
 
     attach_join_engine(rt, join.on_compare)

@@ -31,7 +31,8 @@ import numpy as np
 
 from siddhi_tpu.core.eligibility import ReasonCode as _RC
 from siddhi_tpu.core.eligibility import reason as _reason
-from siddhi_tpu.core.event import Event, HostBatch, LazyColumns, launch_step, pack_pool_of
+from siddhi_tpu.core.event import (Event, HostBatch, LazyColumns, _pad_len,
+                                   launch_step, pack_pool_of)
 from siddhi_tpu.core.plan.selector_plan import FLUSH_KEY, GK_KEY
 from siddhi_tpu.core.query.runtime import QueryRuntime, pack_meta
 from siddhi_tpu.core.stream.junction import FatalQueryError, Receiver
@@ -47,6 +48,7 @@ from siddhi_tpu.ops.expressions import (
     Resolver,
 )
 from siddhi_tpu.ops.windows import conform_cols
+from siddhi_tpu.ops.types import dtype_of
 from siddhi_tpu.query_api.definitions import AttrType, StreamDefinition
 from siddhi_tpu.query_api.expressions import Variable
 
@@ -214,6 +216,47 @@ class JoinResolver(Resolver):
         return self.dictionary.encode(s)
 
 
+def _side_batch(batch: HostBatch, definition) -> HostBatch:
+    """What ``receive(junction.decode_events(batch))`` packs, straight from
+    the columns: the valid rows in order, each attribute in the
+    definition's dtype (zero under its null mask), CURRENT or EXPIRED,
+    padded as a pack pads. The junction's batch is shared by its
+    receivers and may be another query's output; this one is the side's
+    own."""
+    cols = batch.cols
+    idx = np.flatnonzero(np.asarray(cols[VALID_KEY], bool))
+    n, b = len(idx), _pad_len(len(idx))
+    out = {TS_KEY: np.zeros(b, np.int64), VALID_KEY: np.zeros(b, bool),
+           TYPE_KEY: np.full(b, CURRENT, np.int8)}
+    out[VALID_KEY][:n] = True
+    out[TS_KEY][:n] = np.asarray(cols[TS_KEY])[idx]
+    out[TYPE_KEY][:n][np.asarray(cols[TYPE_KEY])[idx] == EXPIRED] = EXPIRED
+    for a in definition.attributes:
+        mask = cols.get(a.name + "?")
+        null = (np.asarray(mask, bool)[idx] if mask is not None
+                else np.zeros(n, bool))
+        vals = np.zeros(b, dtype_of(a.type))
+        vals[:n] = np.where(null, 0, np.asarray(cols[a.name])[idx])
+        out[a.name] = vals
+        out[a.name + "?"] = np.zeros(b, bool)
+        out[a.name + "?"][:n] = null
+    packed = HostBatch(out, size=n)
+    packed.journey = batch.journey
+    return packed
+
+
+def _inert_probe(step):
+    """A device-engine side step as a plain ``(state, cols, now)`` step:
+    both probe surfaces live inside the state, so the probe arguments of
+    the side-step signature are inert."""
+    placeholder = jnp.zeros((1,), bool)
+
+    def fn(state, cols, now):
+        return step(state, {}, placeholder, cols, now)
+
+    return fn
+
+
 class JoinSideProxy(Receiver):
     """Per-side receiver of a join runtime. Beyond plain delivery it
     implements the fused fan-out MEMBER protocol
@@ -332,31 +375,38 @@ class JoinSideProxy(Receiver):
 
     def overflow_knob_msg(self, code: Optional[int] = None):
         # forward the overflow bitmask: the fused drain must name the
-        # partition/selector knob, not default to window capacity
+        # selector knob, not default to window capacity
         return self.runtime.overflow_knob_msg(code)
 
     def decode_meta_suffix(self, meta):
         """Fused-member drain hook: this side's padded meta row decodes
-        by the RUNTIME's spec (seq + partition fills — the unrouted
-        runtime's instrument_slots), into the runtime's telemetry."""
+        by the RUNTIME's spec (seq + matches — the unrouted runtime's
+        instrument_slots), into the runtime's telemetry."""
         self.runtime.decode_meta_suffix(meta)
 
     def _emit(self, out: HostBatch):
         self.runtime._emit(out)
 
     def build_step_fn(self):
-        """The side's fused-member step: the engine's probe surfaces live
-        inside the state, so the probe placeholders of the side-step
-        signature are inert."""
-        step = self.runtime.build_side_step_fn(self.side_key)
-        placeholder = jnp.zeros((1,), bool)
-
-        def fn(state, cols, now):
-            return step(state, {}, placeholder, cols, now)
-
-        return fn
+        """The side's fused-member step: the engine's, as a
+        ``(state, cols, now)`` step (``_inert_probe``)."""
+        return _inert_probe(self.runtime.build_side_step_fn(self.side_key))
 
     # ---------------------------------------------------------- delivery
+
+    def receive_batch(self, batch: HostBatch, junction=None):
+        """Columnar fast path: the batch the event path would pack from
+        the junction's, with no Event objects (``_side_batch``). A
+        partition-local side keeps the event path (its rows carry their
+        producer's partition key), as does a set-valued attribute."""
+        side = self.runtime.sides[self.side_key]
+        defn = side.pack_definition
+        if side.carried_pk or any(a.type == AttrType.OBJECT
+                                  for a in defn.attributes):
+            super().receive_batch(batch, junction)
+            return
+        self.runtime.process_side_batch(self.side_key,
+                                        _side_batch(batch, defn))
 
     def receive(self, events: List[Event]):
         side = self.runtime.sides[self.side_key]
@@ -429,6 +479,10 @@ class JoinQueryRuntime(QueryRuntime):
             _RC.NOT_ATTACHED, "engine not attached")
         self.pipeline_reason: Optional[str] = _reason(
             _RC.NOT_ATTACHED, "engine not attached")
+        # does anything read the joined EXPIRED rows (an expired-events
+        # output, an aggregate that retracts)? The planner says; False
+        # lets the device engine probe with CURRENT rows alone
+        self.expired_read = True
         self._in_timer = False       # timer sweeps run synchronously
         self._drain_seq = None       # last cross-stream seq seen at drain
         self._cur_timer_cb = None    # per-side notify attribution (pump)
@@ -459,15 +513,14 @@ class JoinQueryRuntime(QueryRuntime):
                 state[wk] = (side.window_stage.init_state(self._win_keys)
                              if partitioned else side.window_stage.init_state())
         if self.engine is not None:
-            state.update(self.engine.init_pidx_state())
+            state.update(self.engine.init_state())
         return state
 
     def strip_engine_state(self, state):
-        """Snapshot canonicalization: the partition directories and the
-        cross-stream sequence are derived state — captures store only the
-        legacy ``[W]`` ring layout, so revisions cross-restore between
-        the device engine and the legacy path bit-identically (and across
-        ``siddhi_tpu.join_partitions`` values)."""
+        """Snapshot canonicalization: the cross-stream sequence is engine
+        state — captures store only the legacy ``[W]`` ring layout, so
+        revisions cross-restore between the device engine and the legacy
+        path bit-identically."""
         if state is None or self.engine is None:
             return state
         from siddhi_tpu.core.join import ENGINE_STATE_KEYS
@@ -477,10 +530,13 @@ class JoinQueryRuntime(QueryRuntime):
 
     def adopt_restored_state(self):
         """Snapshot-restore hook: the restored state is canonical (no
-        partition directories) — rebuild them from the rings and reset
-        the drain-sequence expectation."""
+        engine keys) — rebuild the engine's row mirrors from the rings
+        and reset the drain-sequence expectation."""
         self._drain_seq = None
-        if self.engine is None or self._state is None:
+        if self._state is None:
+            return
+        self._adopt_ring_capacities()
+        if self.engine is None:
             return
         from siddhi_tpu.core.join import SEQ_KEY
 
@@ -491,6 +547,21 @@ class JoinQueryRuntime(QueryRuntime):
             state[SEQ_KEY] = _jnp.int64(0)
         self._state = state
         self.engine.rebuild_probe_state()
+
+    def _adopt_ring_capacities(self) -> None:
+        """A restored time-window ring keeps the size it was captured at
+        (the device engine grows rings with their content): the stage
+        follows its leaves, and the side steps re-jit."""
+        from siddhi_tpu.ops.windows import TimeWindowStage
+
+        for side_key, wk in (("left", "lwin"), ("right", "rwin")):
+            stage = self.sides[side_key].window_stage
+            win = (self._state or {}).get(wk)
+            if isinstance(stage, TimeWindowStage) and win is not None:
+                size = int(next(iter(win["buf"].values())).shape[0])
+                if size != stage.capacity:
+                    stage.capacity = size
+                    self._steps.clear()
 
     def _seq_check(self, seq: int) -> None:
         """Drain-side verification of the engine's explicit cross-stream
@@ -525,8 +596,9 @@ class JoinQueryRuntime(QueryRuntime):
         """Join overflow naming the exact knob per the
         ``QueryRuntime.overflow_knob_msg`` convention. ``code`` is the
         step's overflow bitmask: 1 = window ring capacity, 2 = indexed
-        probe candidate window, 4 = partition sub-window, 8 = selector
-        value table (distinctCount)."""
+        probe candidate window, 8 = selector value table
+        (distinctCount), 16 = the keyed probe's output width, 32 = a
+        growing time-window ring."""
         if code is None:
             code = 1
         code = int(code)
@@ -539,13 +611,17 @@ class JoinQueryRuntime(QueryRuntime):
         if code & 2:
             parts.append("indexed join probe candidate window saturated — "
                          "raise app_context.index_probe_width")
-        if code & 4:
-            parts.append("join partition sub-window overflow — raise "
-                         "siddhi_tpu.join_partition_slack (or lower "
-                         "siddhi_tpu.join_partitions)")
         if code & 8:
             parts.append("join selector aggregation overflow — raise "
                          "app_context.distinct_values_capacity")
+        if code & 16:
+            parts.append("join probe output narrower than the batch's "
+                         "pairs: the host's count of the probed window "
+                         "was short (an engine bug; no knob)")
+        if code & 32:
+            parts.append("join time-window ring overflowed although it "
+                         "grows with its content: the host's count of its "
+                         "live rows was short (an engine bug; no knob)")
         if not parts:
             parts.append("join window capacity exceeded — raise "
                          "app_context.window_capacity")
@@ -554,8 +630,8 @@ class JoinQueryRuntime(QueryRuntime):
     def _step_instrument_slots(self):
         """Spec of the engine side step's meta suffix (must mirror
         ``DeviceJoinEngine.build_side_step`` exactly): the structural
-        cross-stream sequence, then — instruments on — each
-        partitioned side's per-partition directory fill. Routed
+        cross-stream sequence, then — instruments on — the pairs the
+        step matched. Routed
         (mesh-sharded) joins run the LEGACY side step (engine None),
         whose meta carries no inner suffix; their route slots come from
         the base ``instrument_slots``."""
@@ -565,26 +641,26 @@ class JoinQueryRuntime(QueryRuntime):
             return []
         slots = [Slot("seq", kind="check")]
         if self._instruments_on():
-            for side_key in ("left", "right"):
-                plan = self.engine.plans[side_key]
-                if plan.use_pidx:
-                    slots.append(Slot(f"fill.{side_key}",
-                                      width=self.engine.P, reduce="max"))
+            slots.append(Slot("join_matches"))
         return slots
+
+    def _record_instrument(self, slot, vals, telemetry: bool,
+                           jr=None) -> None:
+        """The pairs a step matched land on the batch's journey
+        (``join_matches``) and in the counter ``join.<query>.matches``;
+        then as any lane."""
+        if slot.name == "join_matches":
+            self.app_context.telemetry.count(f"join.{self.name}.matches",
+                                             int(vals[0]))
+            if jr is not None:
+                jr.join_matches = int(vals[0])
+        super()._record_instrument(slot, vals, telemetry, jr)
 
     def _consume_check_slot(self, name, vals) -> None:
         if name == "seq":
             self._seq_check(int(vals[0]))
             return
         super()._consume_check_slot(name, vals)
-
-    def _instrument_capacity(self, name):
-        if name.startswith("fill.") and self.engine is not None:
-            plan = self.engine.plans.get(name[len("fill."):])
-            if plan is not None:
-                # live: adaptive growth moves Wp, the gauge must follow
-                return float(plan.Wp)
-        return super()._instrument_capacity(name)
 
     def build_side_step_fn(self, side_key: str):
         if self.engine is not None:
@@ -652,7 +728,14 @@ class JoinQueryRuntime(QueryRuntime):
             with jax.named_scope(instruments.STATE_SCOPE):   # the probe
                 N = wout[VALID_KEY].shape[0]
                 if not other_external:
-                    probe_cols, probe_valid = other.window_stage.contents(state[other_key])
+                    # unpartitioned: the window oldest first, so a trigger
+                    # meets its matches in their arrival order (a
+                    # partitioned ring is read so below, key by key)
+                    contents = other.window_stage.contents
+                    if not partitioned:
+                        contents = getattr(other.window_stage,
+                                           "arrival_contents", contents)
+                    probe_cols, probe_valid = contents(state[other_key])
 
                 # joined eval dict: this side [N,1]; other side [1,W]
                 # (or, partitioned, this row's key's ring gathered to [N,W];
@@ -713,6 +796,16 @@ class JoinQueryRuntime(QueryRuntime):
                     probe_cols = {a: v[pk_rows] for a, v in probe_cols.items()}
                     probe_valid = probe_valid[pk_rows]          # [N, W]
                     W = probe_valid.shape[1]
+                    start = getattr(other.window_stage, "arrival_start", None)
+                    if start is not None:
+                        # each row's key ring read oldest first, so a
+                        # trigger meets its matches in their arrival order
+                        at = ((start(state[other_key])[pk_rows][:, None]
+                               + jnp.arange(W)) % W).astype(jnp.int32)
+                        probe_cols = {a: jnp.take_along_axis(v, at, axis=1)
+                                      for a, v in probe_cols.items()}
+                        probe_valid = jnp.take_along_axis(probe_valid, at,
+                                                          axis=1)
                     for a in other.definition.attributes:
                         ev[other.prefix + a.name] = probe_cols[a.name]
                         ev[other.prefix + a.name + "?"] = probe_cols[a.name + "?"]
@@ -856,6 +949,8 @@ class JoinQueryRuntime(QueryRuntime):
             jr = self._batch_journey = self._cur_journey = \
                 journey.begin(batch) \
                 if journey.enabled() and self.keyer is None else None
+            if jr is not None and self._in_timer:
+                jr.timer_steps = 1
             side = self.sides[side_key]
             cols = batch.cols
             partitioned = self.partition_ctx is not None
@@ -926,10 +1021,12 @@ class JoinQueryRuntime(QueryRuntime):
 
                     jitted = routed_step_for(self, side_key=side_key)
                 else:
+                    fn = self.build_side_step_fn(side_key)
+                    if self.engine is not None:
+                        fn = _inert_probe(fn)
                     jitted = self.app_context.telemetry.instrument_jit(
                         jax.jit(instruments.named_step(
-                            self.build_side_step_fn(side_key),
-                            f"device_join.{side_key}"), donate_argnums=0),
+                            fn, f"device_join.{side_key}"), donate_argnums=0),
                         f"query.{self.name}.join.{side_key}",
                         family=f"device_join.{side_key}")
                 self._steps[side_key] = jitted
@@ -1012,6 +1109,9 @@ class JoinQueryRuntime(QueryRuntime):
                             if nt is not None:
                                 notify = (nt if notify is None
                                           else min(notify, nt))
+                    elif self.engine is not None:
+                        notify = self._finish_device_batch(
+                            jitted, cols, _ovf_msg)
                     else:
                         def call(st, cols, now):
                             return jitted(st, probe_cols, probe_valid,
@@ -1098,6 +1198,12 @@ class JoinQueryRuntime(QueryRuntime):
             self.dictionary,
         )
         batch.cols[TYPE_KEY][...] = TIMER_TYPE
+        # fired by a send's clock advance (playback), the sweep is that
+        # send's work: its journey takes the send's batch id
+        self.app_context.telemetry.count(f"window.{self.name}.timer_steps")
+        sender = journey.sending_batch()
+        if sender is not None and getattr(batch, "journey", None) is not None:
+            batch.journey.batch = sender
         # timer sweeps run synchronously over a drained timeline, exactly
         # like process_timer: in-flight pipelined batches were dispatched
         # BEFORE this timer fired, and the sweep's own notify must re-arm

@@ -98,8 +98,6 @@ KNOBS: Dict[str, Knob] = _declare(
     Knob("pipeline_depth", "int", attr="pipeline_depth"),
     Knob("agg_shards", "int", attr="agg_shards"),
     Knob("agg_shard_wal", "int", attr="agg_shard_wal"),
-    Knob("join_partitions", "int", attr="join_partitions"),
-    Knob("join_partition_slack", "int", attr="join_partition_slack"),
     Knob("index_probe_width", "int", attr="index_probe_width"),
     # multicore ingest front door (core/stream/input/pack_pool.py):
     # ingest_pool = pack-pool worker count (0 = today's inline
@@ -109,7 +107,6 @@ KNOBS: Dict[str, Knob] = _declare(
     Knob("ingest_pool", "int", attr="ingest_pool"),
     Knob("ingest_split", "int", attr="ingest_split"),
     # booleans (each previously had its own — or no — spelling parser)
-    Knob("join_partition_grow", "bool", attr="join_partition_grow"),
     Knob("fuse_fanout", "bool", attr="fuse_fanout"),
     # critical-path profiler (observability/journey.py, costmodel.py):
     # both flip PROCESS-wide collectors (refcounted per app runtime) —

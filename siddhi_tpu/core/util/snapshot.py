@@ -124,11 +124,10 @@ class SnapshotService:
                     win_keys = q._win_keys
                 strip = getattr(q, "strip_engine_state", None)
                 if strip is not None and state is not None:
-                    # join engine (core/join/): the partition directories
-                    # and cross-stream sequence are derived state — the
-                    # snapshot stores the canonical [W] ring layout only,
-                    # so revisions cross-restore engine<->legacy and
-                    # across join_partitions values
+                    # join engine (core/join/): the cross-stream sequence
+                    # is engine state — the snapshot stores the canonical
+                    # [W] ring layout only, so revisions cross-restore
+                    # engine<->legacy
                     state = strip(state)
                 queries[name] = {
                     "state": _to_host(state) if state is not None else None,

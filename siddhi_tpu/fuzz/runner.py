@@ -69,14 +69,13 @@ class StrategyCombo:
     depth: int = 1
     shards: int = 1
     join_engine: str = "legacy"
-    join_partitions: int = 1
     pool: int = 0
     autopilot: bool = False
 
     def label(self) -> str:
         lbl = (f"fuse={int(self.fuse)},depth={self.depth},"
-               f"shards={self.shards},join={self.join_engine}"
-               f"/{self.join_partitions},pool={self.pool}")
+               f"shards={self.shards},join={self.join_engine},"
+               f"pool={self.pool}")
         return lbl + ",ap" if self.autopilot else lbl
 
     def config(self) -> Dict[str, str]:
@@ -84,7 +83,6 @@ class StrategyCombo:
             "siddhi_tpu.fuse_fanout": "true" if self.fuse else "false",
             "siddhi_tpu.pipeline_depth": str(self.depth),
             "siddhi_tpu.join_engine": self.join_engine,
-            "siddhi_tpu.join_partitions": str(self.join_partitions),
             "siddhi_tpu.ingest_pool": str(self.pool),
             # small sub-batches so the fuzzer's modest chunks still
             # split across pool workers (>= 2 sub-batch eligibility)
@@ -182,19 +180,18 @@ def enumerate_matrix(case: CaseSpec, max_combos: Optional[int] = None,
     shard_axis = [s for s in shard_axis if s <= max_shards]
     if not route_live:
         collapsed.append("shards (no route-eligible query)")
-    join_axis = [("legacy", 1)]
+    join_axis = ["legacy"]
     if has_join:
-        join_axis += [("device", 1), ("device", 8)]
+        join_axis.append("device")
     else:
         collapsed.append("join (no join query)")
     pool_axis = [0, 2]
 
     combos = []
-    for fuse, depth, shards, (je, jp), pool in itertools.product(
+    for fuse, depth, shards, je, pool in itertools.product(
             fuse_axis, depth_axis, shard_axis, join_axis, pool_axis):
         combos.append(StrategyCombo(fuse=fuse, depth=depth, shards=shards,
-                                    join_engine=je, join_partitions=jp,
-                                    pool=pool))
+                                    join_engine=je, pool=pool))
     combos = [c for c in combos if c != BASELINE]
     dropped = 0
     if max_combos is not None and len(combos) > max_combos:
@@ -208,7 +205,7 @@ def enumerate_matrix(case: CaseSpec, max_combos: Optional[int] = None,
         def covers(c: StrategyCombo):
             return {("fuse", c.fuse), ("depth", c.depth),
                     ("shards", c.shards),
-                    ("join", (c.join_engine, c.join_partitions)),
+                    ("join", c.join_engine),
                     ("pool", c.pool)}
 
         needed = set()

@@ -286,9 +286,6 @@ def _combo_candidates(combo: StrategyCombo):
         yield (StrategyCombo(**{**asdict(combo),
                                 "shards": combo.shards // 2}),
                f"shards -> {combo.shards // 2}")
-    if combo.join_partitions > 1:
-        yield (StrategyCombo(**{**asdict(combo), "join_partitions": 1}),
-               "join_partitions -> 1")
     if combo.depth > 2:
         yield (StrategyCombo(**{**asdict(combo), "depth": 2}),
                "depth -> 2")
@@ -298,8 +295,7 @@ def _combo_candidates(combo: StrategyCombo):
         yield (StrategyCombo(**{**asdict(combo), "fuse": False}),
                "fuse -> off")
     if combo.join_engine == "device":
-        yield (StrategyCombo(**{**asdict(combo), "join_engine": "legacy",
-                                "join_partitions": 1}),
+        yield (StrategyCombo(**{**asdict(combo), "join_engine": "legacy"}),
                "join_engine -> legacy")
 
 

@@ -42,7 +42,7 @@ TELEMETRY_PREFIXES = (
     "pipeline",      # CompletionPump depth + metas/pulls/stalls
     "aggregation",   # rollup buckets, shards, shard WALs, flush_ms
     "shard",         # routed-row skew gauges + exchange_ms
-    "join",          # device-join partition occupancy, probe/insert_ms
+    "join",          # device-join window rows, probe/insert_ms
     "serving",       # admission pool, scatter-gather latency
     "quota",         # overload quota-utilization gauges
     "overload",      # always-on overload counters (generic family)
@@ -102,10 +102,9 @@ DEVICE_SLOTS = (
     "groups",          # distinct group keys touched by the batch
     "nfa_runs",        # live NFA partial-match slots
     "nfa_rounds",      # the generic NFA engine's rounds for the batch
+    "join_matches",    # the pairs a device-engine join step matched
     "shard_rows",      # per-shard routed rows (device-routed exchange)
     "route_residual",  # receive capacity left on the fullest shard
-    "fill.left",       # join build directory fill per partition
-    "fill.right",
 )
 # Structural (kind='check') slots: consumed by a runtime's
 # _consume_check_slot hook at drain, never rendered as telemetry.
@@ -124,7 +123,10 @@ PROCESS_LIFETIME_GAUGES = (
     "wal.*",                # app registry — registered at WAL attach
     "aggregation.*",        # app registry — both rollup paths register
     "quota.*",              # app registry — overload registration
-    "join.partition_rows.*",  # app registry — device-join attach
+    "join.window_rows.*",   # app registry — device-join attach
+    "join.hot_key_rows.*",  # app registry — device-join attach
+    "join.*.out_width",     # app registry — device-join attach
+    "window.*.capacity",    # app registry — device-join attach
     "shard.rows.*",         # app + process registry (legacy host-router
                             # scope "host" is a deprecated shim)
     "cluster.outstanding_pulls",  # process registry, process-lifetime
@@ -241,10 +243,11 @@ _SERVING_QUERY_HIST = re.compile(r"^serving\.query\.(?P<dur>[a-z]+)_ms$")
 # (scope = query name)
 _SHARD_ROWS = re.compile(r"^shard\.rows\.(?P<scope>.+)\.(?P<shard>\d+)$")
 _SHARD_EXCHANGE_HIST = re.compile(r"^shard\.exchange_ms\.(?P<scope>.+)$")
-# device join engine (core/join/): per-partition build-side occupancy
-# gauges + probe/insert host-latency histograms per join query
-_JOIN_PART_ROWS = re.compile(r"^join\.partition_rows\.(?P<query>.+)"
-                             r"\.(?P<side>left|right)\.(?P<part>\d+)$")
+# device join engine (core/join/): each mirrored side's live rows and
+# its hottest key's rows + probe/insert host-latency histograms per join
+# query
+_JOIN_SIDE_ROWS = re.compile(r"^join\.(?P<kind>window_rows|hot_key_rows)"
+                             r"\.(?P<query>.+)\.(?P<side>left|right)$")
 _JOIN_HIST = re.compile(r"^join\.(?P<kind>probe|insert)_ms\.(?P<query>.+)$")
 # critical-path profiler (observability/journey.py): per-query per-stage
 # service-time and queueing-time histograms of the batch journey
@@ -534,14 +537,19 @@ def _add_telemetry(fams: _Families, tel_snapshot: dict, app: str):
                              "batch; skew shows as imbalance)",
                              {**base, "query": m.group("scope"),
                               "shard": m.group("shard")}, v)
-                elif _JOIN_PART_ROWS.match(name):
-                    m = _JOIN_PART_ROWS.match(name)
-                    fams.add("siddhi_join_partition_rows", "gauge",
-                             "live build-side rows per join hash "
-                             "partition (skew shows as imbalance)",
-                             {**base, "query": m.group("query"),
-                              "side": m.group("side"),
-                              "partition": m.group("part")}, v)
+                elif _JOIN_SIDE_ROWS.match(name):
+                    m = _JOIN_SIDE_ROWS.match(name)
+                    labels = {**base, "query": m.group("query"),
+                              "side": m.group("side")}
+                    if m.group("kind") == "window_rows":
+                        fams.add("siddhi_join_window_rows", "gauge",
+                                 "live rows of a join side's window",
+                                 labels, v)
+                    else:
+                        fams.add("siddhi_join_hot_key_rows", "gauge",
+                                 "live rows of the most frequent key of "
+                                 "a join side's window (key skew)",
+                                 labels, v)
                 elif _QUOTA_GAUGE.match(name):
                     m = _QUOTA_GAUGE.match(name)
                     labels = {**base, "resource": m.group("resource")}

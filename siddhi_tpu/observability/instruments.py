@@ -6,11 +6,9 @@ prefix, and PR 9 taught the join engine to append its cross-stream
 sequence number — two ad-hoc suffix layouts, each with its own
 hand-written drain decoder (``runtime._routed_meta_check``,
 ``join_runtime._seq_check``). Meanwhile every device-resident signal the
-adaptive loops need next — window ring occupancy, per-partition join
-directory fill, NFA active-run counts, routed-row skew — was either
-invisible or reconstructed by host mirrors, and the one device-truth
-scrape surface (``JoinEngineState.partition_occupancy``) pulled device
-state per scrape behind a 0.25 s cache.
+adaptive loops need next — window ring occupancy, NFA active-run
+counts, routed-row skew — was either invisible or reconstructed by host
+mirrors.
 
 This module generalizes both mechanisms into ONE declarative spec:
 
@@ -85,15 +83,13 @@ def named_step(fn, family: str):
 
 # data slot name -> human-readable structure label, used by
 # journey.critical_path_report to NAME the saturated device structure
-# ("join right side partition fill p99 = 0.97 of Wp")
+# ("window ring fill p99 = 0.97 of window capacity")
 SLOT_LABELS: Dict[str, str] = {
     "win_fill": "window ring fill",
     "groups": "distinct groups touched",
     "nfa_runs": "NFA active runs",
     "shard_rows": "shard routed rows",
     "route_residual": "exchange residual capacity",
-    "fill.left": "join left side partition fill",
-    "fill.right": "join right side partition fill",
 }
 
 # data slot name -> the name of its capacity denominator (the knob-ish
@@ -104,8 +100,6 @@ SLOT_CAP_NAMES: Dict[str, str] = {
     "nfa_runs": "nfa slots",
     "shard_rows": "rows_per_shard",
     "route_residual": "rows_per_shard",
-    "fill.left": "Wp",
-    "fill.right": "Wp",
 }
 
 # slots where saturation means the value approaches ZERO (a residual),
@@ -203,8 +197,7 @@ def record(runtime, slot: Slot, vals: np.ndarray,
     """Drain-side sink of one data slot: feed the per-query
     ``device.<query>.<slot>`` histogram, lazily register the last-value
     (and capacity) gauges, and remember the raw lanes on the runtime
-    (``_instr_last``) for zero-pull scrape surfaces like
-    ``partition_occupancy``. Called once per drained batch per slot —
+    (``_instr_last``) for zero-pull scrape surfaces. Called once per drained batch per slot —
     a handful of dict writes and one O(1) histogram record."""
     tel = getattr(runtime.app_context, "telemetry", None)
     if tel is None:

@@ -103,7 +103,9 @@ more, ``None`` for every other query: ``nfa_rounds``, the rounds the
 generic NFA engine ran for the batch (the most rows one partition key
 had in it), and ``nfa_tiles``, the iterations its loop took to run them
 (a round wider than a tile takes several); both 0 where the two-step
-kernel ran, both from the meta's ``nfa_rounds`` lane at the drain.
+kernel ran, both from the meta's ``nfa_rounds`` lane at the drain. A
+join on the device engine stamps ``join_matches``, the pairs its step
+matched (the meta's ``join_matches`` lane).
 
 Every query stamps three more: ``grow_ms``, the ``siddhi.grow`` spans
 of the key-capacity growths this batch forced (``None``: none), and
@@ -287,7 +289,8 @@ class Journey:
                  "route_prep_ms", "route_pieces", "shard_rows_max",
                  "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
                  "state_bytes", "state_slots", "key_ms", "launch_ms",
-                 "h2d_bytes", "nfa_rounds", "nfa_tiles", "_rec")
+                 "h2d_bytes", "nfa_rounds", "nfa_tiles", "join_matches",
+                 "_rec")
 
     def __init__(self, pack_ms: Optional[float] = None,
                  batch: Optional[int] = None):
@@ -319,6 +322,7 @@ class Journey:
         self.h2d_bytes: Optional[int] = None
         self.nfa_rounds: Optional[int] = None
         self.nfa_tiles: Optional[int] = None
+        self.join_matches: Optional[int] = None
         self._rec: Optional[dict] = None     # the ring record, once finished
 
     # one journey object is stamped on the batch at pack time; each
@@ -497,6 +501,9 @@ class Journey:
                 # every other
                 "nfa_rounds": self.nfa_rounds,
                 "nfa_tiles": self.nfa_tiles,
+                # a device-engine join's: the pairs its step matched (the
+                # meta's ``join_matches`` lane); None for every other
+                "join_matches": self.join_matches,
             }
             _RING.append(self._rec)
 

@@ -259,6 +259,12 @@ class KeyedLengthWindowStage(WindowStage):
         valid = j < jnp.minimum(state["total"], W)[:, None]
         return cols, valid
 
+    def arrival_start(self, state):
+        """[K] slot of each key's oldest row in ``contents``: read from
+        there on, a key's ring is in arrival order."""
+        total = state["total"]
+        return jnp.where(total >= self.length, total % self.length, 0)
+
     def reset_keys(self, state, ids):
         """@purge: restart purged keys' windows (rows become unreachable
         as soon as total is zeroed)."""
@@ -503,6 +509,11 @@ class KeyedTimeWindowStage(WindowStage):
         live = state["total"][:, None] - exp0
         valid = ((j - exp0 % Wc) % Wc) < live
         return cols, valid
+
+    def arrival_start(self, state):
+        """[K] slot of each key's oldest live row in ``contents``: read
+        from there on, a key's ring is in arrival order."""
+        return state["expired_upto"] % self.capacity
 
     def reset_keys(self, state, ids):
         return {"buf": state["buf"],

@@ -43,8 +43,10 @@ these stages over the partition-key axis).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -267,11 +269,15 @@ def _expect_arity(window: Window, low: int, high: int):
 class LengthWindowStage(WindowStage):
     """Sliding length window."""
 
-    def __init__(self, length: int, col_specs: Dict[str, np.dtype]):
+    def __init__(self, length: int, col_specs: Dict[str, np.dtype],
+                 expired_needed: bool = True):
         if length <= 0:
             raise CompileError("length window needs a positive length")
         self.length = length
         self.col_specs = col_specs
+        # False: the window emits its CURRENT rows alone, as they came (a
+        # join side whose joined EXPIRED rows nothing reads)
+        self.expired_needed = expired_needed
 
     def init_state(self, num_keys: int = 1) -> dict:
         W = self.length
@@ -300,6 +306,17 @@ class LengthWindowStage(WindowStage):
         rank, n_ins = _insert_ranks(valid_cur)
         seq = total0 + rank  # global per-window sequence of each inserted row
 
+        # ring update: write the last min(W, n_ins) inserted rows (unique slots)
+        write = valid_cur & (rank >= n_ins - W)
+        slot = jnp.where(write, (seq % W).astype(jnp.int32), W)
+        new_buf = {k: state["buf"][k].at[slot].set(cols[k], mode="drop") for k in state["buf"]}
+        new_state = {"buf": new_buf, "total": total0 + n_ins}
+        if not self.expired_needed:
+            out = {k: cols[k] for k in keys}
+            out[TYPE_KEY] = cols[TYPE_KEY]
+            out[VALID_KEY] = valid_cur
+            return new_state, out
+
         # rank -> original row index (for evictees inserted earlier this batch)
         rank_to_row = jnp.zeros((B,), jnp.int32).at[
             jnp.where(valid_cur, rank, B).astype(jnp.int32)
@@ -317,22 +334,24 @@ class LengthWindowStage(WindowStage):
             expired[k] = jnp.where(from_batch, cols[k][batch_row], ring_v)
         expired[TS_KEY] = jnp.broadcast_to(now, (B,))  # LengthWindowProcessor:120
 
-        # ring update: write the last min(W, n_ins) inserted rows (unique slots)
-        write = valid_cur & (rank >= n_ins - W)
-        slot = jnp.where(write, (seq % W).astype(jnp.int32), W)
-        new_buf = {k: state["buf"][k].at[slot].set(cols[k], mode="drop") for k in state["buf"]}
-
         idx = jnp.arange(B, dtype=jnp.int64)
         parts = [
             (expired, jnp.full((B,), EXPIRED, jnp.int8), evicts, 2 * idx),
             ({k: cols[k] for k in keys}, cols[TYPE_KEY], valid_cur, 2 * idx + 1),
         ]
         out, _ = _order_emit(parts)
-        return {"buf": new_buf, "total": total0 + n_ins}, out
+        return new_state, out
 
     def contents(self, state):
         valid = jnp.arange(self.length, dtype=jnp.int64) < state["total"]
         return dict(state["buf"]), valid
+
+    def ring_seq(self, state):
+        """(seq, live) of every ring slot: ``_ring_seq``."""
+        return _ring_seq(state["total"], self.length, jnp.int64(0))
+
+    def arrival_contents(self, state):
+        return _arrival_contents(state, self.length, jnp.int64(0))
 
 
 # -------------------------------------------------------------------- time
@@ -342,11 +361,15 @@ class TimeWindowStage(WindowStage):
     timestamps (externalTime) instead of the runtime clock."""
 
     def __init__(self, time_ms: int, col_specs: Dict[str, np.dtype], capacity: int,
-                 external: bool = False, ts_key: str = TS_KEY):
+                 external: bool = False, ts_key: str = TS_KEY,
+                 expired_needed: bool = True):
         self.time_ms = time_ms
         self.capacity = capacity
         self.col_specs = col_specs
         self.external = external
+        # False: the window emits its CURRENT rows alone (a join side
+        # whose joined EXPIRED rows nothing reads: ``apply_current``)
+        self.expired_needed = expired_needed
         # externalTime clock column: the named timestamp ATTRIBUTE (falls
         # back to the event timestamp) — expiry cutoffs read this column,
         # expired emissions keep the original event timestamps
@@ -370,6 +393,8 @@ class TimeWindowStage(WindowStage):
                            jnp.int64(0))
 
     def apply(self, state, cols, ctx):
+        if not self.expired_needed:
+            return self.apply_current(state, cols, ctx)
         Wc = self.capacity
         t = jnp.int64(self.time_ms)
         keys = _data_keys(cols)
@@ -437,7 +462,18 @@ class TimeWindowStage(WindowStage):
         ]
         out, _ = _order_emit(parts)
 
-        # ring update: append inserted rows, advance the expired prefix
+        new_state = self._advance(state, cols, valid_cur, n_exp_ring,
+                                  batch_exp, out)
+        return new_state, out
+
+    def _advance(self, state, cols, valid_cur, n_exp_ring, batch_exp, out):
+        """Ring update shared by both emissions: append the inserted rows,
+        advance the expired prefix, and set ``out``'s overflow and next
+        wake time."""
+        Wc = self.capacity
+        t = jnp.int64(self.time_ms)
+        total0 = state["total"]
+        exp0 = state["expired_upto"]
         rank, n_ins = _insert_ranks(valid_cur)
         seq = total0 + rank
         write = valid_cur & (rank >= n_ins - Wc)
@@ -452,13 +488,55 @@ class TimeWindowStage(WindowStage):
         if self.external:
             out[NOTIFY_KEY] = jnp.int64(-1)
         else:
-            fifo2 = new_exp + jnp.arange(Wc, dtype=jnp.int64)
-            occ2 = fifo2 < new_total
-            ts2 = new_buf[TS_KEY][(fifo2 % Wc).astype(jnp.int32)]
-            nxt_notify = jnp.min(jnp.where(occ2, ts2 + t, _BIG))
+            # the oldest live row's wake time, read where each slot's row
+            # lies (no ring-wide gather)
+            occ2 = (new_exp + (jnp.arange(Wc, dtype=jnp.int64) - new_exp) % Wc
+                    < new_total)
+            nxt_notify = jnp.min(jnp.where(occ2, new_buf[TS_KEY] + t, _BIG))
             out[NOTIFY_KEY] = jnp.where(jnp.any(occ2), nxt_notify, jnp.int64(-1))
 
-        return {"buf": new_buf, "total": new_total, "expired_upto": new_exp}, out
+        return {"buf": new_buf, "total": new_total, "expired_upto": new_exp}
+
+    def apply_current(self, state, cols, ctx):
+        """``apply`` for a window whose EXPIRED rows nothing reads: the
+        same ring, expiry and wake time, and the batch's CURRENT rows as
+        they came, with no emission sort. Each FIFO item is read in the
+        slot that holds it (item ``exp0 + ((slot - exp0) mod Wc)``), so
+        no ring-wide gather either, and no cumulative min or max over the
+        batch (on the TPU its lowering compiles for minutes at 65,536
+        rows): the same expiries, from a max and a suffix scan."""
+        Wc = self.capacity
+        t = jnp.int64(self.time_ms)
+        B = cols[VALID_KEY].shape[0]
+        valid_cur = cols[VALID_KEY] & (cols[TYPE_KEY] == CURRENT)
+        now = jnp.int64(ctx["current_time"])
+        exp0 = state["expired_upto"]
+        slot_seq = exp0 + (jnp.arange(Wc, dtype=jnp.int64) - exp0) % Wc
+        occupied = slot_seq < state["total"]
+        if self.external:
+            # a row expires in the batch where a later valid row's clock
+            # covers it: the suffix max of the later clocks does
+            ck = jnp.where(valid_cur, cols[self.ts_key], jnp.int64(-(2**62)))
+            later = jnp.concatenate([
+                lax.associative_scan(jnp.maximum, ck, reverse=True)[1:],
+                jnp.full((1,), -(2**62), jnp.int64)])
+            expire_ring = occupied & (state["buf"][self.ts_key]
+                                      <= jnp.max(ck) - t)
+            batch_exp = valid_cur & (later >= ck + t)
+        else:
+            # ... and a row past the cutoff expires where a valid row
+            # follows it in the batch: any row before the last valid one
+            idx = jnp.arange(B, dtype=jnp.int64)
+            last = jnp.max(jnp.where(valid_cur, idx, jnp.int64(-1)))
+            expire_ring = occupied & (state["buf"][TS_KEY] + t <= now)
+            batch_exp = valid_cur & (cols[TS_KEY] + t <= now) & (idx < last)
+        out = {k: cols[k] for k in _data_keys(cols)}
+        out[TYPE_KEY] = cols[TYPE_KEY]
+        out[VALID_KEY] = valid_cur
+        new_state = self._advance(
+            state, cols, valid_cur,
+            jnp.sum(expire_ring.astype(jnp.int64)), batch_exp, out)
+        return new_state, out
 
     def contents(self, state):
         Wc = self.capacity
@@ -468,6 +546,59 @@ class TimeWindowStage(WindowStage):
         s_j = total - 1 - ((total - 1 - j) % Wc)
         valid = (total > 0) & (s_j >= 0) & (s_j >= state["expired_upto"])
         return dict(state["buf"]), valid
+
+    def ring_seq(self, state):
+        """(seq, live) of every ring slot: ``_ring_seq``."""
+        return _ring_seq(state["total"], self.capacity, state["expired_upto"])
+
+    def arrival_contents(self, state):
+        return _arrival_contents(state, self.capacity, state["expired_upto"])
+
+    def grown(self, state, capacity: int) -> dict:
+        """The window state in a ring of ``capacity`` slots (at least the
+        live rows), every live row in the slot its sequence number gives
+        there (``seq % capacity``), built leaf by leaf: ``state`` is the
+        caller's only reference, and each old leaf is let go as soon as
+        its successor exists, so the ring is never held twice. Each leaf
+        is one small program (``_relaid_leaf``) that waits for its
+        result. The caller sets ``self.capacity`` and re-jits."""
+        buf = state.pop("buf")
+        exp, total = state["expired_upto"], state["total"]
+        new_buf = {}
+        for name in sorted(buf):
+            new_buf[name] = jax.block_until_ready(
+                _relaid_leaf(buf.pop(name), exp, total, capacity))
+        return {"buf": new_buf, "total": total, "expired_upto": exp}
+
+
+def _ring_seq(total, W: int, floor):
+    """The arrival sequence number of the row each of a ring's ``W``
+    slots holds (slot j: the newest ``s < total`` with ``s % W == j``) and
+    whether that row is still in the window (``s >= floor``, ``s >= 0``)."""
+    j = jnp.arange(W, dtype=jnp.int64)
+    s = total - 1 - ((total - 1 - j) % W)
+    return s, (s >= 0) & (s >= floor)
+
+
+def _arrival_contents(state, W: int, floor):
+    """``contents`` oldest first: view row i is sequence ``total - W + i``,
+    so a probe of it meets the window's rows in their arrival order."""
+    total = state["total"]
+    idx = ((total + jnp.arange(W, dtype=jnp.int64)) % W).astype(jnp.int32)
+    seq = total - W + jnp.arange(W, dtype=jnp.int64)
+    return ({k: v[idx] for k, v in state["buf"].items()},
+            (seq >= 0) & (seq >= floor))
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _relaid_leaf(old, exp, total, capacity: int):
+    """One ring leaf re-laid into ``capacity`` slots: new slot j holds
+    the FIFO item ``exp + ((j - exp) mod capacity)``, read from its old
+    slot where it is live, zero where it is not."""
+    j = jnp.arange(capacity, dtype=jnp.int64)
+    s = exp + (j - exp) % capacity
+    src = (s % old.shape[0]).astype(jnp.int32)
+    return jnp.where(s < total, old[src], jnp.zeros((), old.dtype))
 
 
 def _next_valid_index(valid):
@@ -1174,6 +1305,9 @@ class TimeLengthWindowStage(WindowStage):
         valid = (total > 0) & (s_j >= 0) & (s_j >= state["expired_upto"])
         return dict(state["buf"]), valid
 
+    def arrival_contents(self, state):
+        return _arrival_contents(state, self.capacity, state["expired_upto"])
+
 
 # ------------------------------------------------------------------- delay
 
@@ -1475,7 +1609,8 @@ def window_col_specs(input_def, extra: Tuple[str, ...] = ()) -> Dict[str, np.dty
 
 
 def create_window_stage(window: Window, input_def, resolver, app_context,
-                        expired_needed: bool = True) -> WindowStage:
+                        expired_needed: bool = True,
+                        expired_read: bool = True) -> WindowStage:
     """Build a window stage from a ``#window.<name>(params)`` handler — the
     factory role of reference ``SingleInputStreamParser.generateProcessor``
     plus each window's ``init`` validation.
@@ -1483,7 +1618,10 @@ def create_window_stage(window: Window, input_def, resolver, app_context,
     ``expired_needed=False`` mirrors the reference's
     outputExpectsExpiredEvents=false: batch windows skip expired emission
     and their findable queue is never drained by empty flushes (join sides
-    of `insert into` queries keep probing the last non-empty batch)."""
+    of `insert into` queries keep probing the last non-empty batch).
+    ``expired_read=False`` says that nothing reads a sliding length or
+    time window's EXPIRED rows (a join side with no expired-events output
+    and no aggregate): it then emits its CURRENT rows alone."""
     name = window.name.lower()
     col_specs = window_col_specs(input_def)
 
@@ -1491,7 +1629,8 @@ def create_window_stage(window: Window, input_def, resolver, app_context,
 
     if name == "length":
         _expect_arity(window, 1, 1)
-        return LengthWindowStage(_int_const_param(window, 0, "length"), col_specs)
+        return LengthWindowStage(_int_const_param(window, 0, "length"), col_specs,
+                                 expired_needed=expired_read)
     if name == "lengthbatch":
         # lengthBatch(length[, streamCurrentEvents])
         _expect_arity(window, 1, 2)
@@ -1503,14 +1642,16 @@ def create_window_stage(window: Window, input_def, resolver, app_context,
                                       stream_current=stream_current)
     if name == "time":
         _expect_arity(window, 1, 1)
-        return TimeWindowStage(_int_const_param(window, 0, "time"), col_specs, capacity)
+        return TimeWindowStage(_int_const_param(window, 0, "time"), col_specs, capacity,
+                               expired_needed=expired_read)
     if name == "externaltime":
         # externalTime(tsAttr, time) — expiry driven by the named
         # long timestamp attribute
         _expect_arity(window, 2, 2)
         ts_key = _external_ts_key(window, input_def)
         return TimeWindowStage(_int_const_param(window, 1, "time"), col_specs, capacity,
-                               external=True, ts_key=ts_key)
+                               external=True, ts_key=ts_key,
+                               expired_needed=expired_read)
     if name == "timebatch":
         # overloads (TimeBatchWindowProcessor.init): (time),
         # (time, startTime int/long), (time, streamCurrentEvents bool),

@@ -9,8 +9,6 @@ The load-bearing claims, each tested here:
 - LIVE actuation safety — depth / ingest-pool / fusion / shard knobs
   flipped at batch boundaries under live ingest stay bit-identical, and
   a persist/restore straddling a reshard actuation is exactly-once;
-- device-join Wp shrink releases over-provisioned sub-windows after a
-  skew burst, bit-identically;
 - the decision log, ``siddhi_autopilot_*`` metric families and
   ``GET /autopilot`` agree about what happened.
 """
@@ -392,63 +390,6 @@ def test_route_shards_actuation_and_straddling_restore_exactly_once():
     _route_feed(rt2, 300, 400)
     m2.shutdown()
     assert c2.rows == ref[head:]
-
-
-JOIN_SKEW_APP = """
-@app:name('apjoin')
-define stream L (sym string, lv long);
-define stream R (sym string, rv long);
-@info(name='jq') from L#window.length(32) join R#window.length(32)
-  on L.sym == R.sym
-  select L.sym as sym, L.lv as lv, R.rv as rv insert into Out;
-"""
-
-
-def test_join_partition_shrink_after_skew_bit_identical():
-    """A hot-key burst grows Wp (the engine's own pre-dispatch growth);
-    once diverse traffic evicts the hot rows, the autopilot's shrink
-    actuation releases the over-provisioned sub-windows — outputs stay
-    bit-identical to a never-shrunk run."""
-    def run(actuate):
-        m = SiddhiManager()
-        m.set_config_manager(InMemoryConfigManager({
-            "siddhi_tpu.join_engine": "device",
-            "siddhi_tpu.join_partitions": "8",
-            "siddhi_tpu.join_partition_slack": "1",
-        }))
-        rt = m.create_siddhi_app_runtime(JOIN_SKEW_APP)
-        c = Collector()
-        rt.add_callback("Out", c)
-        rt.start()
-        hl, hr = rt.get_input_handler("L"), rt.get_input_handler("R")
-        rng = np.random.default_rng(17)
-        for i in range(120):                     # ~70% one hot key
-            sym = "HOT" if rng.random() < .7 else f"S{rng.integers(0, 4)}"
-            (hl if rng.random() < .5 else hr).send([sym, int(i)])
-        eng = rt.query_runtimes["jq"].engine
-        grown = max(p.Wp for p in eng.plans.values())
-        assert grown > 4, f"sub-windows never grew (Wp={grown})"
-        for i in range(120, 280):                # diverse: hot rows evict
-            # 16 distinct keys spread the ring across every partition,
-            # so per-partition occupancy falls well under the grown Wp
-            sym = f"S{rng.integers(0, 16)}"
-            (hl if rng.random() < .5 else hr).send([sym, int(i)])
-        shrunk = None
-        if actuate:
-            shrunk = ACTUATORS["join_partitions"].apply(rt, DOWN)
-            assert shrunk is not None, "nothing shrank after the burst"
-            assert shrunk[1] < shrunk[0]
-            # at least one side released sub-windows; a side whose live
-            # occupancy still demands the grown Wp legitimately holds
-            assert any(p.Wp < grown for p in eng.plans.values())
-        for i in range(280, 400):
-            sym = "HOT" if rng.random() < .8 else f"S{rng.integers(0, 4)}"
-            (hl if rng.random() < .5 else hr).send([sym, int(i)])
-        rows = list(c.rows)
-        m.shutdown()
-        return rows
-
-    assert run(True) == run(False)
 
 
 # --------------------------------------------------- export + REST

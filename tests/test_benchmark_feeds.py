@@ -17,6 +17,7 @@ from benchmarks import generator, manifest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmarks", "tests")
 WALK = "sequence_peak_10k.hot20_walk16k"
+JOIN = "join_time10s_len100.hot20_tweets1k"
 
 
 def _load(name):
@@ -63,12 +64,58 @@ test_two_stream_has_a_schema_a_key_attribute_and_strings_a_side = \
 
 def test_every_cell_before_the_sequence_cell_is_recorded():
     """The record holds the six cells that were there before kinds were
-    files; the Zipf cell and the sequence cell came after it (their feeds
-    are pinned below and in ``test_feeds.py``)."""
+    files; the Zipf cell, the sequence cell and the join cell came after
+    it (their feeds are pinned below and in ``test_feeds.py``)."""
     cells = {w["name"] for w in manifest.Cell(WALK).bench["workloads"]}
     recorded = {r.split("|")[0] for r in _feeds.RECORDED}
-    assert recorded == cells - {_feeds.ZIPF, WALK}
+    assert recorded == cells - {_feeds.ZIPF, WALK, JOIN}
     assert len(_feeds.RECORDED) == 6 * 2 * 2
+
+
+# ------------------------------------------------------- two_stream_rows
+
+JOIN_DIGESTS = {   # rehearsal sizes, by seed: ``_feeds.digest``
+    5: "7e58b6539e030bae6f3b5281897c0b67ccb3107a555bea84cfb1fcd2d5db6fb5",
+    3_000_000_017:
+        "143f335d10818465655280d0f9f984094f47db8d0d8c0a7df596a136f3f8d66e",
+}
+
+
+def _join(rehearsal, seed):
+    cell = manifest.Cell(JOIN)
+    sizes, traffic = cell.sized(rehearsal)
+    return generator.make_feed(cell.config, sizes, traffic, seed), traffic
+
+
+@pytest.mark.parametrize("seed", sorted(JOIN_DIGESTS))
+def test_the_join_cells_feed_is_bit_for_bit_what_it_was(seed):
+    feed, traffic = _join(True, seed)
+    assert manifest.feed_kind(traffic["kind"]).__name__.endswith(
+        "feeds.two_stream_rows")
+    assert _feeds.digest(feed) == JOIN_DIGESTS[seed]
+
+
+def test_two_stream_rows_gives_each_stream_its_rows_and_a_stamp_a_row():
+    feed, traffic = _join(True, 5)
+    ticks, tweets = traffic["rows_by_stream"]
+    assert (ticks, tweets) == (1024, 32) and feed.rows == ticks
+    assert [len(b.keys) for b in feed.warm] == [ticks, tweets]
+    assert [b.stream for b in feed.pool[:5]] == [0, 0, 0, 0, 1]
+    for i in range(len(feed.warm) + len(feed.pool) + 3):
+        b = feed.batch(i)
+        assert len(b.keys) == (ticks, tweets)[b.stream]
+        assert all(len(c) == len(b.keys) for c in b.cols.values())
+        ts = feed.timestamps(i)
+        assert len(ts) == len(b.keys) and len(set(ts.tolist())) == 1
+        # a batch every 200 ms of event time: four of ticks, one of tweets
+        assert int(ts[0]) == traffic["first_ms"] + 200 * i
+    hist = feed.history(0, 7)
+    assert len(hist["cols"]["cseEventStream"]["price"]) == 5 * ticks
+    assert len(hist["cols"]["twitterStream"]["tweet"]) == 2 * tweets
+    # at real sizes: 65,536 ticks and 1,024 tweets a batch
+    real, _ = _join(False, 5)
+    assert [len(real.batch(i).keys) for i in (0, 1, 2, 6)] == [
+        65536, 1024, 65536, 1024]
 
 
 # ------------------------------------------------------------------- walk

@@ -2,15 +2,14 @@
 slots ride the meta vector — zero extra pulls.
 
 The load-bearing acceptance set: per-batch device truth (window ring
-fill, join partition fill, NFA runs, routed-row skew) lands in
+fill, join matches, NFA runs, routed-row skew) lands in
 ``device.<query>.<slot>`` telemetry off the meta pull that already
 happens; a /metrics scrape performs ZERO device pulls
-(transfer-guard-verified, including the join partition-occupancy gauges
-that used to pull the directory per scrape); with the knob off the meta
-layouts are bit-for-bit the pre-round-9 ones; and
-``journey.critical_path_report()`` names the saturated device structure
-for a PLANTED bottleneck (hot join partition at growth-off slack,
-near-full keyed window)."""
+(transfer-guard-verified, including the join window gauges, which read
+the engine's host mirrors); with the knob off the meta layouts are
+bit-for-bit the pre-round-9 ones; and ``journey.critical_path_report()``
+names the saturated device structure for a PLANTED bottleneck
+(near-full keyed window)."""
 
 import jax
 import numpy as np
@@ -64,27 +63,20 @@ def _feed_join(rt, n=24, keys=5):
 # --------------------------------------------------- slots ride the meta
 
 
-def test_join_fill_instrument_feeds_gauges_and_occupancy():
-    """The per-partition directory fill rides the meta; the
-    partition-occupancy gauges read the LAST DRAINED lanes — no device
-    state is touched at scrape time."""
-    m = _manager({"siddhi_tpu.join_partitions": "8"})
+def test_join_matches_instrument_feeds_counter_and_histogram():
+    """The pairs each join step matched ride the meta: the drained lane,
+    the ``device.<query>.join_matches`` histogram and the
+    ``join.<query>.matches`` counter agree with the rows emitted."""
+    m = _manager()
     rt = m.create_siddhi_app_runtime(JOIN_APP)
-    rt.add_callback("JOut", Collector())
+    c = Collector()
+    rt.add_callback("JOut", c)
     _feed_join(rt)
     q = rt.query_runtimes["jq"]
-    last = q._instr_last
-    assert "fill.left" in last and "fill.right" in last
-    assert last["fill.left"].shape == (8,)
-    assert int(last["fill.left"].sum()) > 0
-    # the occupancy gauge backend IS the drained lanes
-    occ = q.engine.partition_occupancy("left")
-    assert occ.tolist() == last["fill.left"].tolist()
+    assert "join_matches" in q._instr_last
     snap = rt.app_context.telemetry.snapshot()
-    assert "device.jq.fill.left" in snap["gauges"]
-    assert snap["gauges"]["device.jq.fill.left.capacity"] == \
-        q.engine.plans["left"].Wp
-    assert "device.jq.fill.right" in snap.get("histograms", {})
+    assert snap["counters"]["join.jq.matches"] == len(c.rows) > 0
+    assert "device.jq.join_matches" in snap.get("histograms", {})
     m.shutdown()
 
 
@@ -93,17 +85,17 @@ def test_scrape_zero_device_pulls_under_transfer_guard():
     NO device pull: it completes under jax's transfer guard and the
     guarded families read real numbers, not the NaN a guarded gauge
     closure would produce."""
-    m = _manager({"siddhi_tpu.join_partitions": "8"})
+    m = _manager()
     rt = m.create_siddhi_app_runtime(JOIN_APP)
     rt.add_callback("JOut", Collector())
     _feed_join(rt)
     with jax.transfer_guard("disallow"):
         text = export.prometheus_text(m)
-    assert "siddhi_join_partition_rows" in text
+    assert "siddhi_join_window_rows" in text
     assert "siddhi_device_instrument" in text
     values = []
     for line in text.splitlines():
-        if line.startswith(("siddhi_join_partition_rows",
+        if line.startswith(("siddhi_join_window_rows",
                             "siddhi_device_instrument{")):
             assert not line.endswith("NaN"), f"guarded gauge pulled: {line}"
             values.append(float(line.rsplit(" ", 1)[1]))
@@ -112,18 +104,19 @@ def test_scrape_zero_device_pulls_under_transfer_guard():
 
 
 def test_occupancy_host_mirror_fallback_with_knob_off():
-    """Instruments off: partition_occupancy answers from the host ring
-    mirror (still zero device pulls; exact for length rings)."""
-    m = _manager({"siddhi_tpu.join_partitions": "8",
-                  "siddhi_tpu.profile_device_instruments": "false"})
+    """Instruments off: the join window gauges answer from the host row
+    mirrors (zero device pulls; exact for length rings)."""
+    m = _manager({"siddhi_tpu.profile_device_instruments": "false"})
     rt = m.create_siddhi_app_runtime(JOIN_APP)
     rt.add_callback("JOut", Collector())
     _feed_join(rt)
     q = rt.query_runtimes["jq"]
     assert not q._instr_last        # nothing drained
     with jax.transfer_guard("disallow"):
-        occ = q.engine.partition_occupancy("left")
-    assert int(occ.sum()) == 24     # every inserted row is live (W=64)
+        rows = q.engine.window_rows("left")
+        hot = q.engine.hot_key_rows("left")
+    assert rows == 24               # every inserted row is live (W=64)
+    assert hot == 5                 # S0..S3 hold 5 rows each, S4 four
     m.shutdown()
 
 
@@ -133,8 +126,7 @@ def test_knob_off_meta_layouts_bit_for_bit():
     from siddhi_tpu.core.plan.selector_plan import GK_KEY
     from siddhi_tpu.ops.expressions import TS_KEY, TYPE_KEY, VALID_KEY
 
-    m = _manager({"siddhi_tpu.join_partitions": "8",
-                  "siddhi_tpu.profile_device_instruments": "false"})
+    m = _manager({"siddhi_tpu.profile_device_instruments": "false"})
     rt = m.create_siddhi_app_runtime(JOIN_APP + """
 @info(name='pq') from L#window.length(8) select sym, lv insert into POut;
 """)
@@ -197,38 +189,6 @@ define stream S (sym string, v long);
 # ------------------------------------------- planted saturated structures
 
 
-def test_report_names_hot_join_partition_at_growth_off_slack():
-    """Growth OFF + one hot key: the join directory's hot partition
-    approaches Wp and critical_path_report names 'join right side
-    partition fill' with the fill/Wp ratio."""
-    m = _manager({"siddhi_tpu.join_partitions": "8",
-                  "siddhi_tpu.join_partition_grow": "false",
-                  "siddhi_tpu.join_partition_slack": "2"})
-    rt = m.create_siddhi_app_runtime(JOIN_APP)
-    rt.add_callback("JOut", Collector())
-    hl, hr = rt.get_input_handler("L"), rt.get_input_handler("R")
-    hl.send(["HOT", 0])           # warm compile outside the measurement
-    journey.enable()
-    try:
-        # Wp = pow2(64 * 2 / 8) = 16; 14 hot-key rows on the RIGHT side
-        # fill one partition to 14/16 without tripping the static-slack
-        # overflow
-        for i in range(14):
-            hr.send(["HOT", 100 + i])
-        hl.send(["HOT", 1])       # trigger a probe so the left drains too
-        rep = journey.critical_path_report(m)
-        q = rep["apps"][rt.name]["queries"]["jq"]
-        st = q.get("device_structure")
-        assert st is not None, q
-        assert st["slot"] == "fill.right", st
-        assert st["ratio"] >= 0.8, st
-        assert "join right side partition fill" in st["text"]
-        assert "of Wp" in st["text"]
-    finally:
-        journey.disable(force=True)
-    m.shutdown()
-
-
 def test_report_names_near_full_keyed_window():
     """A keyed length window fed past W rows per key reports win_fill
     == W — the report names the window ring at ratio 1.0."""
@@ -264,8 +224,8 @@ def test_device_bottleneck_verdict_carries_structure():
     """When the device stage IS the bottleneck, the verdict line names
     the saturated structure (unit-level: synthetic stage histograms +
     instrument signals through _query_report)."""
-    dev = {"fill.right": {"snap": {"p99": 15.5, "count": 10, "sum": 150.0},
-                          "capacity": 16.0}}
+    dev = {"win_fill": {"snap": {"p99": 15.5, "count": 10, "sum": 150.0},
+                        "capacity": 16.0}}
     stages = {
         "pack": {"service": {"sum": 5.0, "count": 10, "p99": 0.6}},
         "device": {"service": {"sum": 400.0, "count": 10, "p99": 45.0}},
@@ -273,8 +233,8 @@ def test_device_bottleneck_verdict_carries_structure():
     }
     rep = journey._query_report("app", "jq", stages, device_slots=dev)
     assert rep["bottleneck"]["stage"] == "device"
-    assert "join right side partition fill" in rep["bottleneck"]["structure"]
-    assert "0.97 of Wp" in rep["bottleneck"]["structure"]
+    assert "window ring fill" in rep["bottleneck"]["structure"]
+    assert "0.97 of window capacity" in rep["bottleneck"]["structure"]
     assert rep["device_structure"]["ratio"] == pytest.approx(15.5 / 16.0,
                                                              abs=1e-3)
 

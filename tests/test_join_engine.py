@@ -1,12 +1,11 @@
-"""Device-resident partitioned join engine (siddhi_tpu/core/join/).
+"""Device-resident join engine (siddhi_tpu/core/join/).
 
-ISSUE-9 acceptance set: eligible stream-stream window joins attach the
-PanJoin-style engine and become pipeline-eligible (entries ride the
-CompletionPump at depth >= 2 with per-side notify attribution), join
-checkpoint/restore is exactly-once with a NON-empty pipeline, snapshots
-cross-restore between the partitioned build-state layout and the legacy
-``[W]`` ring at any ``join_partitions`` value, join overflow errors name
-the exact config knob, join sides fuse into fan-out groups, and
+Eligible stream-stream window joins attach the engine and become
+pipeline-eligible (entries ride the CompletionPump at depth >= 2 with
+per-side notify attribution), join checkpoint/restore is exactly-once
+with a NON-empty pipeline, snapshots cross-restore between the engine
+and the legacy ``[W]`` ring at any pipeline depth, join overflow errors
+name the exact config knob, join sides fuse into fan-out groups, and
 partitioned keyed joins run mesh-sharded bit-identically.
 
 Direct ``process_side_batch`` calls are the deterministic way to park
@@ -43,20 +42,19 @@ define stream R (sym string, rv long);
 """
 
 
-def _manager(depth=2, mode="device", P=8, extra=None):
+def _manager(depth=2, mode="device", extra=None):
     m = SiddhiManager()
     cfg = {
         "siddhi_tpu.pipeline_depth": str(depth),
         "siddhi_tpu.join_engine": mode,
-        "siddhi_tpu.join_partitions": str(P),
     }
     cfg.update(extra or {})
     m.set_config_manager(InMemoryConfigManager(cfg))
     return m
 
 
-def _build(depth=2, mode="device", P=8, extra=None, store=None, app=APP):
-    m = _manager(depth, mode, P, extra)
+def _build(depth=2, mode="device", extra=None, store=None, app=APP):
+    m = _manager(depth, mode, extra)
     if store is not None:
         m.set_persistence_store(store)
     rt = m.create_siddhi_app_runtime(app)
@@ -92,9 +90,9 @@ def test_eligible_join_attaches_engine_and_is_pipeline_ok():
     q = rt.query_runtimes["jq"]
     assert q.engine is not None and q.engine_reason is None
     assert q.pipeline_reason is None and q._pipeline_ok
-    # the equality conjunct engaged the partitioned probe on both sides
-    assert q.engine.partitioned_probe
-    assert all(p.use_pidx for p in q.engine.plans.values())
+    # the equality conjunct engaged the keyed probe on both sides
+    assert all(q.engine.keyed.values())
+    assert set(q.engine.rows) == {"left", "right"}
     m.shutdown()
 
 
@@ -133,9 +131,9 @@ define stream R (k double, rv long);
     m = _manager()
     rt = m.create_siddhi_app_runtime(app)
     q = rt.query_runtimes["fj"]
-    # float equality must not hash-partition (-0.0 == 0.0, NaN), but the
+    # float equality must not key the probe (-0.0 == 0.0, NaN), but the
     # fused in-state step still attaches and pipelines
-    assert q.engine is not None and not q.engine.partitioned_probe
+    assert q.engine is not None and not any(q.engine.keyed.values())
     assert q._pipeline_ok
     m.shutdown()
 
@@ -186,13 +184,14 @@ def test_checkpoint_restore_with_nonempty_pipeline_exactly_once():
 
 # ------------------------------------------------------ snapshot layouts
 
-@pytest.mark.parametrize("dst_mode,dst_p", [
+@pytest.mark.parametrize("dst_mode,dst_depth", [
     ("device", 1), ("device", 4), ("device", 8), ("legacy", 8)])
-def test_cross_restore_partitioned_and_legacy_layouts(dst_mode, dst_p):
-    """A revision captured under the partitioned engine (P=8) restores
-    into P in {1, 4, 8} AND into the legacy path — and the continuation
-    is bit-identical to an uninterrupted run (snapshots store only the
-    canonical [W] ring layout; directories rebuild at restore)."""
+def test_cross_restore_partitioned_and_legacy_layouts(dst_mode, dst_depth):
+    """A revision captured under the engine (depth 2) restores into the
+    engine at pipeline depths 1, 4 and 8 AND into the legacy path — and
+    the continuation is bit-identical to an uninterrupted run (snapshots
+    store only the canonical [W] ring layout; the host row mirrors
+    rebuild at restore)."""
     m, rt, c = _build()
     _feed(rt, 0, 120)
     ref = list(c.rows)
@@ -205,7 +204,7 @@ def test_cross_restore_partitioned_and_legacy_layouts(dst_mode, dst_p):
     head = list(c1.rows)
     m1.shutdown()
 
-    m2, rt2, c2 = _build(mode=dst_mode, P=dst_p, store=store)
+    m2, rt2, c2 = _build(depth=dst_depth, mode=dst_mode, store=store)
     rt2.restore_revision(rev)
     _feed(rt2, 60, 120)
     m2.shutdown()
@@ -232,47 +231,28 @@ def test_legacy_snapshot_restores_into_engine():
 
 # ------------------------------------------------------- overflow knobs
 
-def test_partition_subwindow_overflow_names_slack_knob():
-    # growth OFF = static provisioning: skew past the Wp sub-window is a
-    # FatalQueryError naming the slack knob (the adaptive default grows
-    # Wp instead — covered by the test below)
-    m, rt, _c = _build(extra={"siddhi_tpu.join_partition_slack": "1",
-                              "siddhi_tpu.join_partition_grow": "0"},
-                       app=APP.replace("length(64)", "length(32)"))
-    q = rt.query_runtimes["jq"]
-    assert q.engine is not None and q.engine.partitioned_probe
-    assert not q.engine.grow
-    h = rt.get_input_handler("L")
-    with pytest.raises(FatalQueryError,
-                       match="join_partition_slack"):
-        # 20 rows of ONE key into a Wp = 32/8 = 4 sub-window
-        h.send_columns(
-            {"sym": np.array(["A"] * 20, dtype=object),
-             "lv": np.arange(20, dtype=np.int64)},
-            timestamps=np.arange(20, dtype=np.int64))
-        rt.app_context.completion_pump.flush()
-        h.send(["A", 99])     # pipelined overflow surfaces on next send
-    m.shutdown()
-
-
 def test_adaptive_growth_absorbs_skew_bit_identically():
-    """Default (growth ON): a hot key overflowing its sub-window grows Wp
-    pre-dispatch instead of dying — PanJoin's adaptive re-partitioning —
-    and the output stays bit-identical to the legacy path."""
+    """A hot key that holds most of both windows grows the keyed probe's
+    output width pre-dispatch (a width its pairs need) and the output
+    stays bit-identical to the legacy path."""
     skew_app = APP.replace("length(64)", "length(32)")
 
     def run(mode):
-        m, rt, c = _build(extra={"siddhi_tpu.join_partition_slack": "1"},
-                          app=skew_app, mode=mode)
+        m, rt, c = _build(app=skew_app, mode=mode)
         hl, hr = rt.get_input_handler("L"), rt.get_input_handler("R")
         rng = np.random.default_rng(17)
         for i in range(120):                     # ~70% one hot key
             sym = "HOT" if rng.random() < .7 else f"S{rng.integers(0, 4)}"
             (hl if rng.random() < .5 else hr).send([sym, int(i)])
+        # a batch of 64 hot rows meets ~20 hot rows of the other side
+        hl.send_columns(
+            {"sym": np.array(["HOT"] * 64, dtype=object),
+             "lv": np.arange(64, dtype=np.int64)})
+        rt.app_context.completion_pump.flush()
         if mode == "device":
             q = rt.query_runtimes["jq"]
-            grown = max(p.Wp for p in q.engine.plans.values())
-            assert grown > 4, f"sub-windows never grew (Wp={grown})"
+            assert q.engine.hot_key_rows("right") > 16
+            assert q.engine.width["left"] > 1024, q.engine.width
         m.shutdown()
         return c.rows
 
@@ -287,18 +267,30 @@ define stream R (sym string, rv long);
   on L.sym == R.sym
   select L.sym as sym, R.rv as rv insert into Out;
 """
-    m, rt, _c = _build(extra={"siddhi_tpu.window_capacity": "16"}, app=app)
-    q = rt.query_runtimes["jq"]
-    assert q.engine is not None
+    rows = {"sym": np.array([f"S{i}" for i in range(40)], dtype=object),
+            "lv": np.arange(40, dtype=np.int64)}
+    # the legacy path's ring is the knob's size: the 40 rows (wall-clock
+    # timestamps, all live inside the 10 s window) overflow its 16 slots
+    m, rt, _c = _build(extra={"siddhi_tpu.window_capacity": "16"}, app=app,
+                       mode="legacy")
     h = rt.get_input_handler("L")
     with pytest.raises(FatalQueryError, match="window_capacity"):
-        # wall-clock timestamps: the 40 rows stay live inside the 10 s
-        # window, overflowing the 16-slot ring
-        h.send_columns(
-            {"sym": np.array([f"S{i}" for i in range(40)], dtype=object),
-             "lv": np.arange(40, dtype=np.int64)})
+        h.send_columns(rows)
         rt.app_context.completion_pump.flush()
         h.send(["A", 99])
+    m.shutdown()
+    # the device engine's time ring grows with its content instead, to
+    # the power of two that holds it and at least eight times its size,
+    # and names no knob
+    m, rt, c = _build(extra={"siddhi_tpu.window_capacity": "16"}, app=app)
+    q = rt.query_runtimes["jq"]
+    assert q.engine is not None and q.engine.plans["left"].grows
+    rt.get_input_handler("L").send_columns(rows)
+    rt.get_input_handler("R").send(["S3", 7])
+    rt.app_context.completion_pump.flush()
+    assert q.sides["left"].window_stage.capacity == 128
+    assert c.rows == [("S3", 7)]
+    assert "window_capacity" not in q.overflow_knob_msg(32)
     m.shutdown()
 
 
@@ -307,10 +299,13 @@ def test_overflow_knob_msg_decodes_bitmask():
     q = rt.query_runtimes["jq"]
     assert "window_capacity" in q.overflow_knob_msg(1)
     assert "index_probe_width" in q.overflow_knob_msg(2)
-    assert "join_partition_slack" in q.overflow_knob_msg(4)
     assert "distinct_values_capacity" in q.overflow_knob_msg(8)
-    both = q.overflow_knob_msg(5)
-    assert "window_capacity" in both and "join_partition_slack" in both
+    # the engine sizes these two itself: a bug, and no knob to name
+    for code in (16, 32):
+        msg = q.overflow_knob_msg(code)
+        assert "engine bug" in msg and "raise" not in msg, msg
+    both = q.overflow_knob_msg(9)
+    assert "window_capacity" in both and "distinct_values_capacity" in both
     m.shutdown()
 
 
@@ -356,40 +351,30 @@ def test_join_side_fuses_on_shared_junction_bit_identical():
 
 def test_fused_join_side_overflow_names_partition_knob():
     """The fused drain decodes the join side's overflow BITMASK: a
-    partition sub-window overflow inside a fan-out group must name
-    join_partition_slack, not default to window capacity."""
-    app = FANOUT_APP.replace("length(16)", "length(32)")
-    m = _manager(extra={"siddhi_tpu.fuse_fanout": "1",
-                        "siddhi_tpu.join_partition_slack": "1",
-                        "siddhi_tpu.join_partition_grow": "false"})
+    selector value-table overflow (distinctCount) inside a fan-out group
+    must name distinct_values_capacity, not default to window
+    capacity."""
+    app = FANOUT_APP.replace(
+        "select L.sym as sym, L.lv as lv, R.rv as rv insert into Out;",
+        "select distinctCount(L.lv) as d insert into Out;")
+    m = _manager(extra={"siddhi_tpu.fuse_fanout": "1"})
     rt = m.create_siddhi_app_runtime(app)
     rt.start()
     assert rt.fused_fanout_groups
+    q = rt.query_runtimes["jq"]
+    assert q.engine is not None
+    for spec in q.selector_plan.specs:
+        spec.distinct_capacity = 4   # shrink the value table
+    rt.get_input_handler("R").send(["A", 1])
     h = rt.get_input_handler("L")
-    with pytest.raises(FatalQueryError, match="join_partition_slack"):
-        # 20 rows of ONE key into a Wp = 32/8 = 4 sub-window
+    with pytest.raises(FatalQueryError, match="distinct_values_capacity"):
+        # 10 distinct values of one key meet the right row: > 4 slots
         h.send_columns(
-            {"sym": np.array(["A"] * 20, dtype=object),
-             "lv": np.arange(20, dtype=np.int64)},
-            timestamps=np.arange(20, dtype=np.int64))
+            {"sym": np.array(["A"] * 10, dtype=object),
+             "lv": np.arange(10, dtype=np.int64)},
+            timestamps=np.arange(10, dtype=np.int64))
         rt.app_context.completion_pump.flush()
         h.send(["A", 99])
-    m.shutdown()
-
-
-def test_join_partition_grow_accepts_boolean_spellings():
-    for spelling, want in (("false", False), ("true", True), ("0", False),
-                           ("on", True)):
-        m, rt, _c = _build(
-            extra={"siddhi_tpu.join_partition_grow": spelling})
-        assert rt.query_runtimes["jq"].engine.grow is want
-        m.shutdown()
-    from siddhi_tpu.compiler.errors import SiddhiAppValidationException
-
-    m = _manager(extra={"siddhi_tpu.join_partition_grow": "maybe"})
-    with pytest.raises(SiddhiAppValidationException,
-                       match="join_partition_grow"):
-        m.create_siddhi_app_runtime(APP)
     m.shutdown()
 
 
@@ -493,19 +478,22 @@ def test_join_metrics_families_on_prometheus_surface():
     m, rt, _c = _build()
     rt.get_input_handler("L").send(["A", 1])
     rt.get_input_handler("R").send(["A", 2])
+    rt.get_input_handler("R").send(["A", 3])
     text = prometheus_text(m)
-    assert "siddhi_join_partition_rows{" in text
-    assert 'side="left"' in text and 'partition="0"' in text
+    assert "siddhi_join_window_rows{" in text
+    assert "siddhi_join_hot_key_rows{" in text
     assert "siddhi_join_probe_ms{" in text
     assert "siddhi_join_insert_ms{" in text
-    # one live build row per side across the partitions
+    # the live rows of each side, and of its hottest key, from the host
+    # mirrors
     import re
 
     rows = {}
     for line in text.splitlines():
-        mm = re.match(r'siddhi_join_partition_rows\{.*side="(\w+)".*\} (\d+)',
-                      line)
+        mm = re.match(r'siddhi_join_(window|hot_key)_rows\{.*side="(\w+)"'
+                      r'.*\} (\S+)', line)
         if mm:
-            rows[mm.group(1)] = rows.get(mm.group(1), 0) + int(mm.group(2))
-    assert rows == {"left": 1, "right": 1}
+            rows[(mm.group(1), mm.group(2))] = float(mm.group(3))
+    assert rows == {("window", "left"): 1, ("window", "right"): 2,
+                    ("hot_key", "left"): 1, ("hot_key", "right"): 2}
     m.shutdown()

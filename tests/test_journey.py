@@ -347,13 +347,12 @@ def test_routed_query_stage_attribution(n_dev):
     m.shutdown()
 
 
-@pytest.mark.parametrize("n_parts", [2, 4])
-def test_device_join_stage_attribution(n_parts):
-    """Device-join batches (engine meta carries seq + partition fills)
+@pytest.mark.parametrize("n_keys", [2, 4])
+def test_device_join_stage_attribution(n_keys):
+    """Device-join batches (engine meta carries seq + the pairs matched)
     get stage attribution at depth 4, stay pump-compatible (no seq
     breaks), and both sides' journeys land under the join query."""
-    m = _manager(4, {"siddhi_tpu.join_partitions": str(n_parts),
-                     "siddhi_tpu.join_partition_slack": "8"})
+    m = _manager(4)
     rt = m.create_siddhi_app_runtime(JOIN_APP)
     c = Collector()
     rt.add_callback("JOut", c)
@@ -365,8 +364,8 @@ def test_device_join_stage_attribution(n_parts):
     journey.enable()
     rt.app_context.telemetry.reset()
     for i in range(24):
-        hl.send([f"S{i % 3}", i])
-        hr.send([f"S{i % 3}", 100 + i])
+        hl.send([f"S{i % n_keys}", i])
+        hr.send([f"S{i % n_keys}", 100 + i])
     qrep = _bottleneck(m, rt, query="jq")
     for stage in ("pack", "dispatch", "device", "emit"):
         assert qrep["stages"].get(stage, {}).get("batches", 0) > 0, \
@@ -593,7 +592,7 @@ def test_scrape_self_histogram_and_no_barrier():
     assert "siddhi_junction_queue_depth" in result["text"]
     for line in result["text"].splitlines():
         if line.startswith(("siddhi_device_instrument",
-                            "siddhi_join_partition_rows")):
+                            "siddhi_join_window_rows")):
             assert not line.endswith("NaN"), \
                 f"scrape gauge pulled device state: {line}"
     inj.release()

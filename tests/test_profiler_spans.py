@@ -26,7 +26,7 @@ RING_KEYS = {"app", "queries", "pack_ms", "queue_ms", "dispatch_ms",
              "route_prep_ms", "route_pieces", "shard_rows_max",
              "shard_capacity", "flush_rows", "timer_steps", "grow_ms",
              "state_bytes", "state_slots", "key_ms", "launch_ms",
-             "h2d_bytes", "nfa_rounds", "nfa_tiles"}
+             "h2d_bytes", "nfa_rounds", "nfa_tiles", "join_matches"}
 FLUSH_KEYS = ("flush_rows", "timer_steps")
 ROUTE_KEYS = ("route_prep_ms", "route_pieces", "shard_rows_max",
               "shard_capacity")

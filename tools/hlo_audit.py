@@ -240,7 +240,6 @@ def _audit_device_join(ctx):
 
     from siddhi_tpu import SiddhiManager
     from siddhi_tpu.core.plan.selector_plan import GK_KEY
-    from siddhi_tpu.core.util.config import InMemoryConfigManager
     from siddhi_tpu.ops.expressions import TS_KEY, TYPE_KEY, VALID_KEY
 
     _JOIN_APP = """
@@ -251,10 +250,6 @@ define stream R (sym string, rv long);
   select L.sym as sym, L.lv as lv, R.rv as rv insert into JOut;
 """
     m = SiddhiManager()
-    # explicit P: the CPU-fallback auto default is P=1 (full-surface
-    # probe) — audit the PARTITIONED insert+gather step's lowering
-    m.set_config_manager(InMemoryConfigManager(
-        {"siddhi_tpu.join_partitions": "8"}))
     rt = m.create_siddhi_app_runtime(_JOIN_APP)
     rt.start()
     q = rt.query_runtimes["jq"]
@@ -279,15 +274,15 @@ define stream R (sym string, rv long);
                       np.int64(0)).compile().as_text()
     n = _assert_one_module(hlo, "device join side step")
     _assert_no_host_transfers(hlo, "device join side step")
-    # instrumented meta: seq + both sides' per-partition fills ride the
-    # same module's meta output
+    # instrumented meta: seq + the pairs matched ride the same module's
+    # meta output
     _st2, out = jstep(q._init_state(), {}, jnp.zeros((1,), bool), jcols,
                       np.int64(0))
     slots = _assert_instrumented_meta(q, out, "device join side step")
-    assert "seq" in slots and any(s.startswith("fill.") for s in slots), \
+    assert "seq" in slots and "join_matches" in slots, \
         f"join instrument spec incomplete: {slots}"
     report = {
-        "partitions": q.engine.P,
+        "keyed_probe": q.engine.keyed["left"],
         "hlo_modules": n,
         "collectives": _count_collectives(hlo),
         "host_transfers": 0,
@@ -402,12 +397,10 @@ define aggregation TradeAgg
 def _scrape_zero_pulls() -> dict:
     """A full /metrics scrape must perform ZERO device pulls — verified
     under jax's transfer guard with live device-instrument state (the
-    join partition gauges used to pull the directory per scrape; they
-    now read the last drained fill instrument / host mirror)."""
+    join window gauges read the engine's host mirrors)."""
     import jax
 
     from siddhi_tpu import SiddhiManager
-    from siddhi_tpu.core.util.config import InMemoryConfigManager
     from siddhi_tpu.observability import export
 
     _JOIN_APP = """
@@ -418,8 +411,6 @@ define stream R (sym string, rv long);
   select L.sym as sym, L.lv as lv, R.rv as rv insert into JOut;
 """
     m = SiddhiManager()
-    m.set_config_manager(InMemoryConfigManager(
-        {"siddhi_tpu.join_partitions": "8"}))
     rt = m.create_siddhi_app_runtime(_JOIN_APP)
     rt.start()
     hl, hr = rt.get_input_handler("L"), rt.get_input_handler("R")
@@ -430,7 +421,7 @@ define stream R (sym string, rv long);
         text = export.prometheus_text(m)
     # family literals below assert on exposition OUTPUT, they declare
     # nothing (R3's central-declaration rule targets registrations)
-    want = ("siddhi_join_partition_rows",   # graftlint: disable=R3
+    want = ("siddhi_join_window_rows",      # graftlint: disable=R3
             "siddhi_device_instrument")     # graftlint: disable=R3
     for fam in want:
         assert fam in text, f"family {fam} missing from scrape"

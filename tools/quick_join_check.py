@@ -79,7 +79,6 @@ def run(mode: str, depth: int):
     m.set_config_manager(InMemoryConfigManager({
         "siddhi_tpu.join_engine": mode,
         "siddhi_tpu.pipeline_depth": str(depth),
-        "siddhi_tpu.join_partitions": "4",
     }))
     rt = m.create_siddhi_app_runtime(APP)
     outs = {s: Collector() for s in OUT_STREAMS}

@@ -133,8 +133,6 @@ def _shape_run(instruments_on: bool, shape: str):
 
     cfg = {"siddhi_tpu.profile_device_instruments":
            "true" if instruments_on else "false"}
-    if shape == "join":
-        cfg["siddhi_tpu.join_partitions"] = "8"
     m = SiddhiManager()
     m.set_config_manager(InMemoryConfigManager(cfg))
     if shape == "join":
